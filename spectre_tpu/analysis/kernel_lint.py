@@ -85,7 +85,7 @@ class _Lint:
 
 
 def _atom_bound(atom, env):
-    import jax.core as jcore
+    from jax.extend import core as jcore
     if isinstance(atom, jcore.Literal):
         v = atom.val
         arr = np.asarray(v)
@@ -144,7 +144,7 @@ def _interp_jaxpr(jaxpr, consts, in_bounds, lint: _Lint, check: bool,
 
 def _const_value(atom, cvals):
     """Integer numpy value of an atom when statically known, else None."""
-    import jax.core as jcore
+    from jax.extend import core as jcore
     if isinstance(atom, jcore.Literal):
         arr = np.asarray(atom.val)
         return arr if np.issubdtype(arr.dtype, np.integer) else None
@@ -339,7 +339,7 @@ def _eval_eqn(eqn, ei, env, eqns, outvar_set, lint: _Lint, check: bool,
               f"integer_pow^{y}")
         return [_cap(min(true, dmax))]
     if prim == "shift_left":
-        import jax.core as jcore
+        from jax.extend import core as jcore
         s_atom = eqn.invars[1]
         if isinstance(s_atom, jcore.Literal):
             s = int(np.asarray(s_atom.val).max())
@@ -349,7 +349,7 @@ def _eval_eqn(eqn, ei, env, eqns, outvar_set, lint: _Lint, check: bool,
             return [_cap(min(true, dmax))]
         return [dmax]  # data-dependent shift: cannot prove overflow
     if prim in ("shift_right_logical", "shift_right_arithmetic"):
-        import jax.core as jcore
+        from jax.extend import core as jcore
         s_atom = eqn.invars[1]
         if isinstance(s_atom, jcore.Literal):
             return [ins[0] >> int(np.asarray(s_atom.val).min())]
@@ -362,7 +362,7 @@ def _eval_eqn(eqn, ei, env, eqns, outvar_set, lint: _Lint, check: bool,
     if prim == "not":
         return [dmax]
     if prim == "rem":
-        import jax.core as jcore
+        from jax.extend import core as jcore
         if isinstance(eqn.invars[1], jcore.Literal):
             return [min(ins[0], max(ins[1] - 1, 0))]
         return [ins[0]]

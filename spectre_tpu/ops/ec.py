@@ -35,15 +35,31 @@ def encode_points(points) -> jax.Array:
     return jnp.stack([ctx.encode(xs), ctx.encode(ys), ctx.encode(zs)], axis=-2)
 
 
+# module-level jitted entry point (trace-cache hygiene lint root)
+TRACE_JIT_ROOTS = ("_affine_mont",)
+
+
+@jax.jit
+def _affine_mont(arr):
+    """[m, 3, 16] projective Montgomery -> (x/z, y/z) Montgomery limbs.
+
+    Jitted so it is ONE stable program per m: run eagerly, the Fermat-
+    inversion loop and each CIOS multiply are top-level `lax.scan`s whose
+    bodies retrace into fresh jaxprs on every call, and every decoded MSM
+    result recompiles a handful of programs (~600 backend compiles in one
+    tiny keygen; a warm prove never reaches `compile.count == 0`)."""
+    ctx = _fq()
+    zinv = F.inv(ctx, arr[:, 2])
+    return (F.mont_mul(ctx, arr[:, 0], zinv),
+            F.mont_mul(ctx, arr[:, 1], zinv))
+
+
 def decode_points(arr) -> list:
     """Device projective -> list of affine (x:int, y:int) | None."""
     ctx = _fq()
-    arr = arr.reshape(-1, 3, F.NLIMBS)
-    zs = arr[:, 2]
-    zinv = F.inv(ctx, zs)
-    xs = ctx.decode(F.mont_mul(ctx, arr[:, 0], zinv))
-    ys = ctx.decode(F.mont_mul(ctx, arr[:, 1], zinv))
-    z_int = ctx.decode(zs)
+    arr = jnp.asarray(arr).reshape(-1, 3, F.NLIMBS)
+    xm, ym = _affine_mont(arr)
+    xs, ys, z_int = ctx.decode(xm), ctx.decode(ym), ctx.decode(arr[:, 2])
     return [None if z == 0 else (x, y) for x, y, z in zip(xs, ys, z_int)]
 
 

@@ -14,7 +14,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from ._compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..observability import compilelog

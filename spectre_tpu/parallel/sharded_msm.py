@@ -32,8 +32,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ._compat import shard_map
 
 from ..observability import compilelog
 from ..ops import ec, msm as MSM

@@ -29,7 +29,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from ._compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..fields import bn254

@@ -48,12 +48,12 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..fields import bn254
 from ..observability import compilelog
 from ..ops import field_ops as F, ntt as NTT
-from ._compat import shard_map
 from .plan import ShardingPlan
 
 R = bn254.R

@@ -9,6 +9,8 @@ class.
 
 from __future__ import annotations
 
+import os
+
 import numpy as np
 
 from ..fields import bn254
@@ -55,25 +57,32 @@ def _host_fingerprint() -> str:
     return hashlib.blake2s(ident.encode(), digest_size=4).hexdigest()
 
 
+# the persistent compile cache lives INSIDE the checkout (git-ignored): a
+# path built from a temp name, pid or time never hits — the directory is
+# part of the cache key
+_CACHE_ROOT = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", ".jax_cache"))
+
+
 def setup_compile_cache():
-    """Per-platform, per-host-feature persistent JAX compile cache (shared
-    policy for bench, backends, tests, and entry points).
+    """Persistent JAX compile cache (shared policy for bench, backends,
+    tests, and entry points).
 
-    `SPECTRE_COMPILE_CACHE_DIR` overrides the /tmp default so CI/bench runs
-    can mount a durable cache across containers — the multichip SPMD
-    programs are the expensive entries (8-way lowering on a 1-core host)
-    and should compile once per image, not once per run. The host
-    fingerprint still keys a subdirectory: foreign AOT entries must stay
-    unreachable (see _host_fingerprint)."""
-    import os
-
+    `JAX_COMPILATION_CACHE_DIR` places the cache from outside: when it is
+    set JAX reads it itself and this function sets NO directory in code.
+    Otherwise the cache is `<checkout>/.jax_cache/<backend>_<host
+    fingerprint>` — fixed per host, so the expensive entries (multichip
+    SPMD programs, per-shape prover kernels) compile once per checkout,
+    and foreign XLA:CPU AOT entries stay unreachable (see
+    _host_fingerprint)."""
     import jax
-    if not jax.config.jax_compilation_cache_dir:
-        root = os.environ.get("SPECTRE_COMPILE_CACHE_DIR", "").strip()
-        tag = f"jax_cache_{jax.default_backend()}_{_host_fingerprint()}"
-        path = os.path.join(root, tag) if root else f"/tmp/{tag}"
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR") \
+            or jax.config.jax_compilation_cache_dir:
+        return
+    jax.config.update(
+        "jax_compilation_cache_dir",
+        os.path.join(_CACHE_ROOT,
+                     f"{jax.default_backend()}_{_host_fingerprint()}"))
 
 
 def to_arr(vals) -> np.ndarray:
@@ -203,7 +212,6 @@ class TpuBackend(CpuBackend):
         # the sharded MSM path previously re-ran endo expansion and
         # re-device_put the full base onto the mesh EVERY call
         self._mesh_base_cache: dict = {}
-        import os
         self._shard_min_logn = int(os.environ.get(
             "SPECTRE_SHARD_MSM_MIN_LOGN", str(self.SHARD_MSM_MIN_LOGN)))
         self._shard_ntt_min_logn = int(os.environ.get(
@@ -474,10 +482,11 @@ class TpuBackend(CpuBackend):
         if self._use_mesh(evals.shape[0], self._shard_ntt_min_logn):
             n = evals.shape[0]
             res = self._ntt_sharded(evals, pow(omega, -1, R), mont_out=True)
-            from ..ops import field_ops as Fo
-            ctx = Fo.fr_ctx()
-            ninv = ctx.encode([pow(n, -1, R)])[0]
-            out = Fo.mont_mul(ctx, res, jnp.asarray(ninv)[None])
+            ninv = F.fr_ctx().encode([pow(n, -1, R)])[0]
+            # through a cached jit: an eager mont_mul is a top-level
+            # lax.scan that recompiles on EVERY call
+            from .quotient_device import _helpers
+            out = _helpers()["mul_s"](res, jnp.asarray(ninv))
             return _mont16_to_u64_std(np.asarray(out))
         mont = _u64_std_to_mont16(evals)
         out = NTT.intt(jnp.asarray(mont), omega)

@@ -23,6 +23,24 @@ def _spec(name):
     return spec_mod.SPECS[name]
 
 
+def _require_tpu():
+    """`--backend tpu` means the chip. TpuBackend itself runs on whatever
+    platform JAX has (the tests drive it on XLA:CPU through
+    `get_backend("tpu")`), so the operator's entry point is where a
+    missing accelerator must stop the program: proving on XLA:CPU under
+    the name "tpu" is a wrong answer to the question the flag asks."""
+    try:
+        import jax
+        platform = jax.devices()[0].platform
+    except Exception as exc:       # backend failed to initialise at all
+        raise SystemExit(f"--backend tpu: JAX found no usable device "
+                         f"({type(exc).__name__}: {exc})")
+    if platform != "tpu":
+        raise SystemExit(f"--backend tpu: JAX is running on platform "
+                         f"{platform!r}, not a TPU; use --backend cpu for "
+                         f"the native host prover")
+
+
 def main(argv=None):
     p = argparse.ArgumentParser(prog="spectre-tpu")
     p.add_argument("--spec", default="minimal", choices=list(spec_mod.SPECS))  # incl. "tiny" demo net
@@ -188,6 +206,8 @@ def main(argv=None):
 
     args = p.parse_args(argv)
     spec = _spec(args.spec)
+    if args.backend == "tpu":
+        _require_tpu()
 
     if args.cmd == "circuit":
         _circuit_cmd(args, spec)

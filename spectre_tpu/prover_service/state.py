@@ -1,4 +1,4 @@
-"""ProverState: SRS + proving keys loaded once at boot.
+"""ProverState: SRS loaded at boot, each circuit's proving key on first use.
 
 Reference parity: `prover/src/prover.rs:43-117` (`ProverState::new`: SRS map
 by degree, pkeys for step/committee circuits created from default witnesses)
@@ -9,6 +9,12 @@ PR 3: every prove routes through `backend.prove_with_fallback` — a device
 OOM / Mosaic compile failure retries once on the CPU backend instead of
 failing the request — and `params_dir` additionally hosts the async job
 journal (`jobs.ensure_jobs` attaches the queue lazily at serve time).
+
+PR 25: a circuit's proving key is built (or loaded from the pk cache) by
+the first request that needs it, not at construction — a committee-only
+server no longer pays the step circuit's witness build + keygen (minutes
+even at Minimal) before it answers anything. `state.step_pk` /
+`state.committee_pk` / `state.*_agg_pk` read the same as before.
 """
 
 from __future__ import annotations
@@ -22,7 +28,24 @@ from ..utils.profiling import phase
 from ..witness import default_committee_update_args, default_sync_step_args
 
 
+def _lazy_pk(name: str):
+    """Proving key `name`, built once by the first reader (under a lock:
+    concurrent first requests must not keygen the same circuit twice)."""
+    def get(self):
+        with self._pk_lock:
+            if name not in self._pks:
+                with phase(f"state/create_pk_{name}"):
+                    self._pks[name] = self._pk_builders[name]()
+            return self._pks[name]
+    return property(get)
+
+
 class ProverState:
+    step_pk = _lazy_pk("step")
+    committee_pk = _lazy_pk("committee")
+    step_agg_pk = _lazy_pk("step_agg")
+    committee_agg_pk = _lazy_pk("committee_agg")
+
     def __init__(self, spec, k_step: int, k_committee: int,
                  concurrency: int = 1, backend: str = "cpu",
                  params_dir: str | None = None, compress: bool = False,
@@ -48,35 +71,39 @@ class ProverState:
         for k in {k_step, k_committee}:
             self.srs[k] = SRS.load_or_setup(k, params_dir)
         self.k_step, self.k_committee = k_step, k_committee
-        self.step_pk = StepCircuit.create_pk(
-            self.srs[k_step], spec, k_step,
-            default_sync_step_args(spec), self.backend)
-        self.committee_pk = CommitteeUpdateCircuit.create_pk(
-            self.srs[k_committee], spec, k_committee,
-            default_committee_update_args(spec), self.backend)
+        self._pk_lock = threading.RLock()   # agg builders read the app pk
+        self._pks: dict = {}
+        self._pk_builders = {
+            "step": lambda: StepCircuit.create_pk(
+                self.srs[k_step], spec, k_step,
+                default_sync_step_args(spec), self.backend),
+            "committee": lambda: CommitteeUpdateCircuit.create_pk(
+                self.srs[k_committee], spec, k_committee,
+                default_committee_update_args(spec), self.backend),
+        }
         self.compress = compress
         if compress:
-            from ..models import AggregationArgs, AggregationCircuit
-            from ..plonk.transcript import PoseidonTranscript
+            from ..models import AggregationCircuit
             self.k_agg = k_agg
             self.srs[k_agg] = SRS.load_or_setup(k_agg, params_dir)
             self.step_agg = AggregationCircuit.variant("sync_step")
             self.committee_agg = AggregationCircuit.variant("committee_update")
-            # lazy thunks: a dummy inner proof is only generated when the
-            # aggregation pk is not already cached
-            self.step_agg_pk = self.step_agg.create_pk(
+            # the dummy inner proof is a thunk: only generated when the
+            # aggregation pk is not already cached on disk
+            self._pk_builders["step_agg"] = lambda: self.step_agg.create_pk(
                 self.srs[k_agg], spec, k_agg,
                 lambda: self._dummy_agg_args(StepCircuit, self.step_pk,
                                              self.k_step,
                                              default_sync_step_args(spec)),
                 self.backend)
-            self.committee_agg_pk = self.committee_agg.create_pk(
-                self.srs[k_agg], spec, k_agg,
-                lambda: self._dummy_agg_args(CommitteeUpdateCircuit,
-                                             self.committee_pk,
-                                             self.k_committee,
-                                             default_committee_update_args(spec)),
-                self.backend)
+            self._pk_builders["committee_agg"] = \
+                lambda: self.committee_agg.create_pk(
+                    self.srs[k_agg], spec, k_agg,
+                    lambda: self._dummy_agg_args(
+                        CommitteeUpdateCircuit, self.committee_pk,
+                        self.k_committee,
+                        default_committee_update_args(spec)),
+                    self.backend)
         # readiness self-check (ISSUE 9): prove+verify a tiny cached
         # circuit before the box reports ready — GET /healthz stays 503
         # until it passes, and it re-runs after every SDC retry
@@ -119,10 +146,8 @@ class ProverState:
         ones about to prove: the per-pk caches are GBs at production degrees
         and would otherwise stack across circuit families (all four pks
         resident), raising the service's peak RSS well above one prove's."""
-        for pk in (self.step_pk, self.committee_pk,
-                   getattr(self, "step_agg_pk", None),
-                   getattr(self, "committee_agg_pk", None)):
-            if pk is not None and all(pk is not a for a in active_pks):
+        for pk in list(self._pks.values()):    # built keys only
+            if all(pk is not a for a in active_pks):
                 pk.release_ext_cache()
 
     def prove_step(self, args, heartbeat=None,
@@ -136,8 +161,8 @@ class ProverState:
         bk0 = backend if backend is not None else self.backend
         with self.semaphore:
             hb()                     # phase: permit acquired, prove starts
-            self._release_idle_ext_caches(self.step_pk,
-                                          getattr(self, "step_agg_pk", None))
+            self._release_idle_ext_caches(
+                self.step_pk, self.step_agg_pk if self.compress else None)
             if self.compress:
                 return B.prove_with_fallback(
                     lambda bk: self._compressed(StepCircuit, self.step_pk,
@@ -178,7 +203,8 @@ class ProverState:
         with self.semaphore:
             hb()
             self._release_idle_ext_caches(
-                self.committee_pk, getattr(self, "committee_agg_pk", None))
+                self.committee_pk,
+                self.committee_agg_pk if self.compress else None)
             if self.compress:
                 return B.prove_with_fallback(
                     lambda bk: self._compressed(CommitteeUpdateCircuit,

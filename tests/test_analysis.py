@@ -264,7 +264,7 @@ _FRESH_SHARD_SRC = '''\
 import functools
 
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 

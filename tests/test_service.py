@@ -189,10 +189,11 @@ class TestSpectreContract:
 class _FakeState:
     """Canned prover for RPC plumbing tests (real proving is minutes)."""
 
-    def __init__(self, spec, concurrency=1, delay=0.0):
+    def __init__(self, spec, concurrency=1, delay=0.0, gate=None):
         self.spec = spec
         self.concurrency = concurrency
         self.delay = delay
+        self.gate = gate        # zero-arg callable run while "proving"
         self.active = 0
         self.max_active = 0
         self._lock = threading.Lock()
@@ -209,6 +210,8 @@ class _FakeState:
             try:
                 if self.delay:
                     time.sleep(self.delay)
+                if self.gate is not None:
+                    self.gate()
                 yield
             finally:
                 with self._lock:
@@ -398,8 +401,20 @@ class TestAsyncRPC:
     def test_concurrent_submits_respect_cap(self):
         """N async submissions drain at the configured concurrency: the
         worker-pool size mirrors ProverState's semaphore cap."""
+        import itertools
+
         from spectre_tpu.prover_service.jobs import ensure_jobs
-        state = _FakeState(TINY, concurrency=2, delay=0.05)
+        # the first two proves meet at a barrier INSIDE the tracked
+        # section, so max_active == 2 is decided by the pool size and not
+        # by how a sleep interleaves under a loaded scheduler
+        both_in = threading.Barrier(2)
+        arrivals = itertools.count()
+
+        def gate():
+            if next(arrivals) < 2:
+                both_in.wait(timeout=30)
+
+        state = _FakeState(TINY, concurrency=2, gate=gate)
         runner_calls = []
 
         def runner(method, params):
