@@ -278,8 +278,10 @@ def _module_toplevel(tree):
 
 def _store_names(fn, mod_names, _cache={}) -> frozenset:
     """Module-level dict names this function's subtree subscript-stores
-    into (`_RUNNERS[key] = fn` — the runner-cache discipline)."""
-    hit = _cache.get(id(fn))
+    into (`_RUNNERS[key] = fn` — the runner-cache discipline). Memoised
+    on the node itself, not its id(): a freed tree's ids are reused by the
+    next parse, and a stale hit reads as another module's cache."""
+    hit = _cache.get(fn)
     if hit is not None:
         return hit
     out = set()
@@ -291,7 +293,7 @@ def _store_names(fn, mod_names, _cache={}) -> frozenset:
                         and t.value.id in mod_names):
                     out.add(t.value.id)
     out = frozenset(out)
-    _cache[id(fn)] = out
+    _cache[fn] = out
     return out
 
 

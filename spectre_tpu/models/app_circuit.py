@@ -12,6 +12,7 @@ import os
 import pickle
 
 from ..builder import Context
+from ..observability.tracing import span
 from ..plonk import backend as B
 from ..plonk.keygen import ProvingKey, keygen
 from ..plonk.mock import mock_prove
@@ -107,8 +108,9 @@ class AppCircuit:
         """transcript: None = Blake2b; pass PoseidonTranscript() for
         aggregation-bound snarks, KeccakTranscript() for the EVM path
         (reference: gen_snark_shplonk vs gen_evm_proof_shplonk)."""
-        ctx = cls.build_context(args, spec)
-        asg = ctx.assignment(pk.vk.config)
+        with span("job/witness"):
+            ctx = cls.build_context(args, spec)
+            asg = ctx.assignment(pk.vk.config)
         return plonk_prove(pk, srs, asg, bk, transcript=transcript)
 
     @classmethod

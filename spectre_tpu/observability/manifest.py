@@ -175,8 +175,9 @@ def build(*, job_id: str, method: str, witness_digest: str | None = None,
           result_digest: str | None = None,
           error: str | None = None) -> dict:
     """Assemble the manifest dict. `trace` is an observability.tracing
-    Trace (phase seconds are derived from the same tree `getTrace`
-    serves, so the two agree by construction); `compile_events` is the
+    Trace or None (phase seconds, span counts and transfer bytes are
+    derived from the same tree `getTrace` serves, so they agree by
+    construction); `compile_events` is the
     compilelog.capture output; `events` the collect_events output."""
     from . import compilelog, tracing
     prove_s = None
@@ -197,8 +198,7 @@ def build(*, job_id: str, method: str, witness_digest: str | None = None,
         "events": list(events),
         "compile": compilelog.summarize(compile_events),
         "lru_delta": lru_delta(lru_before, lru_after),
-        "phase_seconds": (tracing.phase_seconds(trace)
-                          if trace is not None else {}),
+        **tracing.summary(trace),
         "peak_rss_mb": peak_rss_mb,
         "result_digest": result_digest,
         "error": error,

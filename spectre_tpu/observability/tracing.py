@@ -14,8 +14,30 @@ is set — in `<dir>/<trace_id>.trace.json` files in Chrome trace-event
 format (load via chrome://tracing or https://ui.perfetto.dev). The file
 sink is best-effort: a full disk never fails a prove.
 
-No trace active => `span(...)` is a no-op; the tracer costs nothing on
-untraced paths (a thread-local read and a None check).
+Below the phases, the device boundary (ISSUE 29): every `TpuBackend`
+operation and the device quotient open one span for the call and, inside
+it, child spans whose LAST name segment says what the host is doing:
+
+    .../encode    host work before the device has anything of the call:
+                  limb split, stacking, padding, the upload (`bytes` up)
+    .../dispatch  calls that enqueue device programs and return
+    .../wait      the call's blocking read: the host waits for the device
+                  and copies the result (`bytes` down)
+    .../decode    host work after the last read
+
+A call is IN FLIGHT from the start of a `dispatch` to the end of the
+`wait` that follows it; `summary` counts the spans and sums their bytes
+for the manifest (`span_counts`, `transfer_bytes`).
+
+`span(...)` also writes a `jax.profiler.TraceAnnotation` of the same name,
+with or without a trace on the thread, so that a profiler session holds
+the program's stages beside the device's events on one clock. jax is
+looked up in `sys.modules`, never imported from here: a process that has
+not imported it has no profiler session to write to.
+
+No trace active and no jax => `span(...)` is a no-op; with jax and no
+profiler session the annotation adds a fraction of a microsecond to the
+two a span costs anyway (some 3,300 spans a served committee proof).
 """
 
 from __future__ import annotations
@@ -24,12 +46,16 @@ import collections
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 
 TRACE_DIR_ENV = "SPECTRE_TRACE_DIR"          # file sink (off when unset)
 TRACE_KEEP_ENV = "SPECTRE_TRACE_KEEP"        # in-memory ring size
 TRACE_KEEP_DEFAULT = 128
+
+# the last name segment of a device-boundary stage span (module docstring)
+ENCODE, DISPATCH, WAIT, DECODE = "encode", "dispatch", "wait", "decode"
 
 
 class Span:
@@ -40,7 +66,9 @@ class Span:
         self.t0 = t0                 # perf_counter timestamps
         self.t1: float | None = None
         self.children: list[Span] = []
-        self.meta: dict = {}
+        # allocated on first use: a proof has some 3,300 spans, the ring
+        # keeps 128 proofs, and most of them carry no metadata
+        self.meta: dict | None = None
 
     def seconds(self) -> float | None:
         return None if self.t1 is None else self.t1 - self.t0
@@ -103,19 +131,45 @@ def active() -> Trace | None:
     return _local.trace
 
 
+_annotation = None      # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _trace_annotation(name: str):
+    global _annotation
+    if _annotation is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        _annotation = jax.profiler.TraceAnnotation
+    return _annotation(name)
+
+
 @contextlib.contextmanager
-def span(name: str):
-    """Child span of the innermost open span; no-op without a trace."""
+def span(name: str, **meta):
+    """Child span of the innermost open span (yields it; None without a
+    trace), and an annotation of the same name on the profiler's clock.
+    `meta` lands in the span's metadata (Chrome `args`)."""
+    ann = _trace_annotation(name)
     tr = _local.trace
     if tr is None:
-        yield None
+        if ann is None:
+            yield None
+        else:
+            with ann:
+                yield None
         return
     s = Span(name, time.perf_counter())
+    if meta:
+        s.meta = meta
     _local.stack[-1].children.append(s)
     _local.stack.append(s)
+    if ann is not None:
+        ann.__enter__()
     try:
         yield s
     finally:
+        if ann is not None:
+            ann.__exit__(None, None, None)
         s.t1 = time.perf_counter()
         if _local.stack and _local.stack[-1] is s:
             _local.stack.pop()
@@ -145,7 +199,7 @@ def add_completed_span(name: str, seconds: float, **meta):
     s = Span(name, t1 - max(0.0, float(seconds)))
     s.t1 = t1
     if meta:
-        s.meta.update(meta)
+        s.meta = meta
     _local.stack[-1].children.append(s)
     return s
 
@@ -155,7 +209,10 @@ def annotate(**kw):
     `args`) — e.g. the CPU-fallback path stamps its oom/compile kind."""
     tr = _local.trace
     if tr is not None and _local.stack:
-        _local.stack[-1].meta.update(kw)
+        top = _local.stack[-1]
+        if top.meta is None:
+            top.meta = {}
+        top.meta.update(kw)
 
 
 def get_trace(trace_id: str) -> Trace | None:
@@ -209,19 +266,39 @@ def chrome_trace(tr: Trace) -> dict:
             "otherData": {"trace_id": tr.trace_id}}
 
 
-def phase_seconds(tr: Trace) -> dict[str, float]:
-    """Total seconds per span name (root excluded) — the shared schema
-    between production traces and bench.py's `phase_seconds` key."""
-    out: dict[str, float] = {}
+def summary(tr: Trace | None) -> dict:
+    """One walk of the tree (root excluded; no trace, nothing) for the
+    manifest:
+    `phase_seconds`, total seconds per span name — the shared schema
+    between production traces and bench.py's `phase_seconds` key;
+    `span_counts`, spans per name; `transfer_bytes`, the `bytes` metadata
+    summed over the `encode` spans (`h2d`) and the `wait` spans (`d2h`)."""
+    seconds: dict[str, float] = {}
+    counts: dict[str, int] = {}
+    moved = {"h2d": 0, "d2h": 0}
+    way = {ENCODE: "h2d", WAIT: "d2h"}
 
     def walk(s: Span):
         for c in s.children:
+            counts[c.name] = counts.get(c.name, 0) + 1
             if c.t1 is not None:
-                out[c.name] = out.get(c.name, 0.0) + (c.t1 - c.t0)
+                seconds[c.name] = seconds.get(c.name, 0.0) + (c.t1 - c.t0)
+            if c.meta and "bytes" in c.meta:
+                direction = way.get(c.name.rsplit("/", 1)[-1])
+                if direction:
+                    moved[direction] += int(c.meta["bytes"])
             walk(c)
 
-    walk(tr.root)
-    return {k: round(v, 6) for k, v in sorted(out.items())}
+    if tr is not None:
+        walk(tr.root)
+    return {"phase_seconds": {k: round(v, 6)
+                              for k, v in sorted(seconds.items())},
+            "span_counts": dict(sorted(counts.items())),
+            "transfer_bytes": moved}
+
+
+def phase_seconds(tr: Trace) -> dict[str, float]:
+    return summary(tr)["phase_seconds"]
 
 
 def reset():

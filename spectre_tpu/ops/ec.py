@@ -15,7 +15,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from ..observability.tracing import span
 from . import field_ops as F
 
 
@@ -54,13 +56,24 @@ def _affine_mont(arr):
             F.mont_mul(ctx, arr[:, 1], zinv))
 
 
-def decode_points(arr) -> list:
-    """Device projective -> list of affine (x:int, y:int) | None."""
+def decode_points(arr, call: str = "ec/decode_points") -> list:
+    """Device projective -> list of affine (x:int, y:int) | None.
+
+    Three stage spans named for `call` (the backend passes its own call's
+    name; observability/tracing.py): `dispatch` enqueues the affine
+    conversion, `wait` holds the three reads (the first is the one that
+    blocks), `decode` the host's Montgomery decoding."""
     ctx = _fq()
-    arr = jnp.asarray(arr).reshape(-1, 3, F.NLIMBS)
-    xm, ym = _affine_mont(arr)
-    xs, ys, z_int = ctx.decode(xm), ctx.decode(ym), ctx.decode(arr[:, 2])
-    return [None if z == 0 else (x, y) for x, y, z in zip(xs, ys, z_int)]
+    with span(call + "/dispatch"):
+        arr = jnp.asarray(arr).reshape(-1, 3, F.NLIMBS)
+        xm, ym = _affine_mont(arr)
+        z = arr[:, 2]
+    with span(call + "/wait", bytes=xm.nbytes + ym.nbytes + z.nbytes):
+        xm, ym, z = np.asarray(xm), np.asarray(ym), np.asarray(z)
+    with span(call + "/decode"):
+        xs, ys, z_int = ctx.decode(xm), ctx.decode(ym), ctx.decode(z)
+        return [None if z == 0 else (x, y)
+                for x, y, z in zip(xs, ys, z_int)]
 
 
 def inf_point(shape=()) -> jax.Array:

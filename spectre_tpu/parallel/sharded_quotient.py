@@ -53,6 +53,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..fields import bn254
 from ..observability import compilelog
+from ..observability.tracing import span
 from ..ops import field_ops as F, ntt as NTT
 from .plan import ShardingPlan
 
@@ -327,10 +328,14 @@ def _inv_apply(plan: ShardingPlan, acc, logm: int, omega: int, g,
     scb, twb, outb = _inv_tables(plan, logm, omega, g, vinv_vals)
     sh = NamedSharding(plan.batch_mesh, P(plan.batch_axis, None, None))
     # A[jr, jc] = acc[jc*rr + jr], rows (jr) sharded
-    a = jax.device_put(acc.reshape(cc, rr, 16).transpose(1, 0, 2), sh)
-    with compilelog.entry_point("parallel.sharded_quotient.inverse"):
+    with span("quotient/inverse/encode", bytes=acc.nbytes):
+        a = jax.device_put(acc.reshape(cc, rr, 16).transpose(1, 0, 2), sh)
+    with span("quotient/inverse/dispatch"), \
+            compilelog.entry_point("parallel.sharded_quotient.inverse"):
         out = run(a, scb, twb, outb)                 # [cc, rr, 16]
-    return np.asarray(out).transpose(1, 0, 2).reshape(1 << logm, 16)
+    with span("quotient/inverse/wait", bytes=out.nbytes):
+        out = np.asarray(out)
+    return out.transpose(1, 0, 2).reshape(1 << logm, 16)
 
 
 # --- eligibility + the expression-evaluation context ------------------------
@@ -484,5 +489,10 @@ class MeshQuotientEngine:
         + std output, sharded. vinv_vals None = identity pre-scale (the
         SPECTRE_QUOTIENT_FUSED_VINV=0 oracle path multiplies explicitly
         before calling in)."""
-        return _inv_apply(self.plan, np.asarray(acc), self._logm,
+        # the mesh path crosses twice: the accumulator comes to the host
+        # whole (the queue drains here, as in the local engine) and goes
+        # back up re-laid-out for the sharded inverse (`quotient/inverse/*`)
+        with span("quotient/wait", bytes=acc.nbytes):
+            acc = np.asarray(acc)
+        return _inv_apply(self.plan, acc, self._logm,
                           self.dom.omega_ext, self._g(), vinv_vals)

@@ -15,6 +15,7 @@ import numpy as np
 
 from ..fields import bn254
 from ..native import host
+from ..observability.tracing import annotate, span
 
 R = bn254.R
 
@@ -196,7 +197,15 @@ class TpuBackend(CpuBackend):
 
     The commitment base (SRS tau powers) is encoded + shipped to device ONCE
     per distinct base array and cached — per-column commits were previously
-    re-transferring the same 2^k-point base every call."""
+    re-transferring the same 2^k-point base every call.
+
+    Every operation that reaches the device opens one span named for the
+    call (`backend/msm`, `backend/ntt`, ...) and inside it the stages
+    `encode`, `dispatch`, `wait`, `decode` (observability/tracing.py). A
+    `wait` closes on the read that blocked anyway; no stage adds a
+    synchronisation. The NTT kinds cross the boundary twice a call (the
+    transform's result comes to the host and goes straight back for
+    `from_mont`): their spans show both crossings."""
 
     name = "tpu"
     # quotient phase as one device-resident XLA program (quotient_device.py)
@@ -228,7 +237,10 @@ class TpuBackend(CpuBackend):
         x16 = L16.u64limbs_to_u16limbs(points[:, :4])
         y16 = L16.u64limbs_to_u16limbs(points[:, 4:])
         if "toq" not in _mont_jits:
-            _mont_jits["toq"] = jax.jit(lambda v: F.to_mont(ctxq, v))
+            def to_mont_fq(v):
+                return F.to_mont(ctxq, v)
+
+            _mont_jits["toq"] = jax.jit(to_mont_fq)
         to_mont = _mont_jits["toq"]
         xm, ym = to_mont(jnp.asarray(x16)), to_mont(jnp.asarray(y16))
         inf_mask = jnp.asarray(
@@ -272,11 +284,14 @@ class TpuBackend(CpuBackend):
         m = min(points.shape[0], scalars.shape[0])
         if self._use_mesh(m, self._shard_min_logn):
             return self._msm_sharded(points, scalars, m, base_key=base_key)
-        pts = self._base_points(points, m)
-        sc16 = jnp.asarray(L16.u64limbs_to_u16limbs(scalars[:m]))
-        res = MSM.msm(pts, sc16, base_key=base_key)
-        out = ec.decode_points(res[None])[0]
-        return out
+        with span("backend/msm", n=m):
+            with span("backend/msm/encode"):
+                pts = self._base_points(points, m)
+                sc16 = jnp.asarray(L16.u64limbs_to_u16limbs(scalars[:m]))
+                annotate(bytes=sc16.nbytes)
+            with span("backend/msm/dispatch"):
+                res = MSM.msm(pts, sc16, base_key=base_key)
+            return ec.decode_points(res[None], call="backend/msm")[0]
 
     def _mesh_base(self, points, m: int, plan, expand: bool):
         """Mesh-resident commitment base: encoded, optionally endomorphism-
@@ -341,56 +356,77 @@ class TpuBackend(CpuBackend):
             MSM._record_pallas_degrade(mode, m, None,
                                        "backend._msm_sharded")
         plan = current_plan()
-        sc16 = L16.u64limbs_to_u16limbs(scalars[:m])
-        nbits, signed = 254, False
-        if mode != "vanilla":
-            from ..ops import glv
-            a1, a2, n1, n2 = glv.decompose_limbs16(sc16)
-            sc16 = np.concatenate([a1, a2], axis=0)
-            neg_np = np.concatenate([n1, n2], axis=0)
-            nbits = glv.glv_bits()
-            signed = mode in ("glv+signed", "fixed")
-            m2 = 2 * m
-        else:
-            neg_np = np.zeros(m, dtype=bool)
-            m2 = m
-        mp = plan.pad_rows(m2)
-        sc = np.zeros((mp, sc16.shape[1]), dtype=np.uint32)
-        sc[:m2] = sc16
-        ng = np.zeros(mp, dtype=bool)
-        ng[:m2] = neg_np
+        call = "backend/msm_sharded"
 
-        if mode == "fixed":
-            c = MSM.default_window_fixed(mp)
-            nwin = (nbits + c) // c
-            if not SM._degrade_fixed_mesh(mp, c, nbits, plan):
-                base = self._mesh_base(points, m, plan, expand=True)
-                tab = SM.sharded_fixed_table(base, c, nwin, plan,
-                                             base_key=base_key)
+        def read(res):
+            # the mesh result comes to the host whole and goes back up for
+            # `_affine_mont`: a second crossing, inside decode_points
+            with span(call + "/wait", bytes=res.nbytes):
+                proj = np.asarray(res)
+            return ec.decode_points(proj[None], call=call)[0]
+
+        with span(call, n=m):
+            with span(call + "/encode"):
+                sc16 = L16.u64limbs_to_u16limbs(scalars[:m])
+                nbits, signed = 254, False
+                if mode != "vanilla":
+                    from ..ops import glv
+                    a1, a2, n1, n2 = glv.decompose_limbs16(sc16)
+                    sc16 = np.concatenate([a1, a2], axis=0)
+                    neg_np = np.concatenate([n1, n2], axis=0)
+                    nbits = glv.glv_bits()
+                    signed = mode in ("glv+signed", "fixed")
+                    m2 = 2 * m
+                else:
+                    neg_np = np.zeros(m, dtype=bool)
+                    m2 = m
+                mp = plan.pad_rows(m2)
+                sc = np.zeros((mp, sc16.shape[1]), dtype=np.uint32)
+                sc[:m2] = sc16
+                ng = np.zeros(mp, dtype=bool)
+                ng[:m2] = neg_np
+                annotate(bytes=sc.nbytes + ng.nbytes)
+
+            if mode == "fixed":
+                c = MSM.default_window_fixed(mp)
+                nwin = (nbits + c) // c
+                if not SM._degrade_fixed_mesh(mp, c, nbits, plan):
+                    with span(call + "/dispatch"):
+                        base = self._mesh_base(points, m, plan, expand=True)
+                        tab = SM.sharded_fixed_table(base, c, nwin, plan,
+                                                     base_key=base_key)
+                        sd = plan.place(jnp.asarray(sc), plan.scalar_spec)
+                        ngd = plan.place(jnp.asarray(ng), plan.sign_spec)
+                        res = SM.sharded_msm_fixed(tab, sd, ngd, c, plan,
+                                                   nbits)
+                    return read(res)
+                # per-device table slice over budget: glv+signed fallback
+                # below
+
+            with span(call + "/dispatch"):
+                base = self._mesh_base(points, m, plan,
+                                       expand=(mode != "vanilla"))
+                if mode != "vanilla" and not signed:
+                    # unsigned glv folds the sign into the points — scalar-
+                    # dependent, so applied on device against the resident
+                    # base
+                    base = MSM._apply_sign(
+                        base, plan.place(jnp.asarray(ng), plan.sign_spec))
+                    ng = np.zeros_like(ng)
+                if mode == "vanilla":
+                    # mesh-tuned static window; SPECTRE_MSM_WINDOW still
+                    # wins so a sweep (bench.py --sweep-window) exercises
+                    # the sharded path too
+                    c = MSM.window_override() or (
+                        13 if mp >= (1 << 18) else 10)
+                else:
+                    c = MSM.default_window(mp, signed=signed)
                 sd = plan.place(jnp.asarray(sc), plan.scalar_spec)
-                ngd = plan.place(jnp.asarray(ng), plan.sign_spec)
-                res = SM.sharded_msm_fixed(tab, sd, ngd, c, plan, nbits)
-                return ec.decode_points(np.asarray(res)[None])[0]
-            # per-device table slice over budget: glv+signed fallback below
-
-        base = self._mesh_base(points, m, plan, expand=(mode != "vanilla"))
-        if mode != "vanilla" and not signed:
-            # unsigned glv folds the sign into the points — scalar-
-            # dependent, so applied on device against the resident base
-            base = MSM._apply_sign(
-                base, plan.place(jnp.asarray(ng), plan.sign_spec))
-            ng = np.zeros_like(ng)
-        if mode == "vanilla":
-            # mesh-tuned static window; SPECTRE_MSM_WINDOW still wins so a
-            # sweep (bench.py --sweep-window) exercises the sharded path too
-            c = MSM.window_override() or (13 if mp >= (1 << 18) else 10)
-        else:
-            c = MSM.default_window(mp, signed=signed)
-        sd = plan.place(jnp.asarray(sc), plan.scalar_spec)
-        ngd = plan.place(jnp.asarray(ng), plan.sign_spec) if signed else None
-        res = SM.sharded_msm(base, sd, c, plan.mesh, nbits=nbits,
-                             signed=signed, neg=ngd, plan=plan)
-        return ec.decode_points(np.asarray(res)[None])[0]
+                ngd = plan.place(jnp.asarray(ng), plan.sign_spec) \
+                    if signed else None
+                res = SM.sharded_msm(base, sd, c, plan.mesh, nbits=nbits,
+                                     signed=signed, neg=ngd, plan=plan)
+            return read(res)
 
     def msm_many(self, points, scalars_list, base_key=None):
         """Commit several scalar vectors against one cached device base.
@@ -416,35 +452,47 @@ class TpuBackend(CpuBackend):
         if plan.n_devices > 1 and batch > 1:
             from ..parallel.batch_msm import batch_msm_dp
             bmesh = plan.batch_mesh
+            call = "backend/msm_many"
             # uniform batch length: pad shorter scalar vectors with zeros
             # (zero scalars select the empty bucket — identity contribution)
             mmax = min(points.shape[0],
                        max(s.shape[0] for s in scalars_list))
-            pts = self._base_points(points, mmax)
             mode = MSM.msm_mode()
-            if mode == "vanilla":
-                sc = np.zeros((batch, mmax, 16), dtype=np.uint32)
-                for i, s in enumerate(scalars_list):
-                    mi = min(mmax, s.shape[0])
-                    sc[i, :mi] = np.asarray(L16.u64limbs_to_u16limbs(s[:mi]))
-                res = batch_msm_dp(pts, sc, mesh=bmesh)    # [B, 3, 16]
-                return list(ec.decode_points(np.asarray(res)))
-            from ..ops import glv
-            signed = mode in ("glv+signed", "fixed")
-            pts2 = MSM._expand_endo(pts)
-            sc = np.zeros((batch, 2 * mmax, glv.HALF_LIMBS), dtype=np.uint32)
-            ng = np.zeros((batch, 2 * mmax), dtype=bool)
-            for i, s in enumerate(scalars_list):
-                mi = min(mmax, s.shape[0])
-                sc64 = np.zeros((mmax, 4), dtype=np.uint64)
-                sc64[:mi] = s[:mi]
-                a1, a2, n1, n2 = glv.decompose_limbs16(
-                    L16.u64limbs_to_u16limbs(sc64))
-                sc[i] = np.concatenate([a1, a2], axis=0)
-                ng[i] = np.concatenate([n1, n2], axis=0)
-            res = batch_msm_dp(pts2, sc, mesh=bmesh, neg_batch=ng,
-                               nbits=glv.glv_bits(), signed=signed)
-            return list(ec.decode_points(np.asarray(res)))
+            with span(call, batch=batch, n=mmax):
+                with span(call + "/encode"):
+                    pts = self._base_points(points, mmax)
+                    if mode == "vanilla":
+                        sc = np.zeros((batch, mmax, 16), dtype=np.uint32)
+                        for i, s in enumerate(scalars_list):
+                            mi = min(mmax, s.shape[0])
+                            sc[i, :mi] = np.asarray(
+                                L16.u64limbs_to_u16limbs(s[:mi]))
+                        kw = {}
+                    else:
+                        from ..ops import glv
+                        sc = np.zeros((batch, 2 * mmax, glv.HALF_LIMBS),
+                                      dtype=np.uint32)
+                        ng = np.zeros((batch, 2 * mmax), dtype=bool)
+                        for i, s in enumerate(scalars_list):
+                            mi = min(mmax, s.shape[0])
+                            sc64 = np.zeros((mmax, 4), dtype=np.uint64)
+                            sc64[:mi] = s[:mi]
+                            a1, a2, n1, n2 = glv.decompose_limbs16(
+                                L16.u64limbs_to_u16limbs(sc64))
+                            sc[i] = np.concatenate([a1, a2], axis=0)
+                            ng[i] = np.concatenate([n1, n2], axis=0)
+                        kw = dict(neg_batch=ng, nbits=glv.glv_bits(),
+                                  signed=mode in ("glv+signed", "fixed"))
+                    annotate(bytes=sc.nbytes)
+                with span(call + "/dispatch"):
+                    if kw:
+                        pts = MSM._expand_endo(pts)
+                    res = batch_msm_dp(pts, sc, mesh=bmesh, **kw)  # [B,3,16]
+                # the mesh result comes to the host whole and goes back up
+                # for `_affine_mont`: a second crossing, in decode_points
+                with span(call + "/wait", bytes=res.nbytes):
+                    proj = np.asarray(res)
+                return list(ec.decode_points(proj, call=call))
         return [self.msm(points, s, base_key=base_key)
                 for s in scalars_list]
 
@@ -463,49 +511,57 @@ class TpuBackend(CpuBackend):
         return current_plan().n_devices > 1 and n >= (1 << min_logn)
 
     def ntt(self, coeffs, omega: int):
-        import jax.numpy as jnp
-
-        from ..ops import field_ops as F, limbs as L16, ntt as NTT
+        from ..ops import ntt as NTT
 
         if self._use_mesh(coeffs.shape[0], self._shard_ntt_min_logn):
             return self._ntt_sharded(coeffs, omega)
-        ctx = F.fr_ctx()
-        mont = _u64_std_to_mont16(coeffs)
-        out = NTT.ntt(jnp.asarray(mont), omega)
-        return _mont16_to_u64_std(np.asarray(out))
+        call = "backend/ntt"
+        with span(call, n=coeffs.shape[0]):
+            std16 = _ship_std16(coeffs, call)
+            with span(call + "/dispatch"):
+                out = NTT.ntt(_mont_fns()["to"](std16), omega)
+            return _fetch_u64_std(out, call)
 
     def intt(self, evals, omega: int):
         import jax.numpy as jnp
 
-        from ..ops import field_ops as F, limbs as L16, ntt as NTT
+        from ..ops import field_ops as F, ntt as NTT
 
+        call = "backend/intt"
         if self._use_mesh(evals.shape[0], self._shard_ntt_min_logn):
             n = evals.shape[0]
-            res = self._ntt_sharded(evals, pow(omega, -1, R), mont_out=True)
-            ninv = F.fr_ctx().encode([pow(n, -1, R)])[0]
-            # through a cached jit: an eager mont_mul is a top-level
-            # lax.scan that recompiles on EVERY call
-            from .quotient_device import _helpers
-            out = _helpers()["mul_s"](res, jnp.asarray(ninv))
-            return _mont16_to_u64_std(np.asarray(out))
-        mont = _u64_std_to_mont16(evals)
-        out = NTT.intt(jnp.asarray(mont), omega)
-        return _mont16_to_u64_std(np.asarray(out))
+            with span(call, n=n):
+                res = self._ntt_sharded(evals, pow(omega, -1, R),
+                                        mont_out=True)
+                with span(call + "/dispatch"):
+                    ninv = F.fr_ctx().encode([pow(n, -1, R)])[0]
+                    # through a cached jit: an eager mont_mul is a top-level
+                    # lax.scan that recompiles on EVERY call
+                    from .quotient_device import _helpers
+                    out = _helpers()["mul_s"](res, jnp.asarray(ninv))
+                return _fetch_u64_std(out, call)
+        with span(call, n=evals.shape[0]):
+            std16 = _ship_std16(evals, call)
+            with span(call + "/dispatch"):
+                out = NTT.intt(_mont_fns()["to"](std16), omega)
+            return _fetch_u64_std(out, call)
 
     def _ntt_sharded(self, arr_u64, omega: int, mont_out: bool = False):
         """One NTT over the ("data",) mesh axis; exact same result as the
         single-device kernel (pinned by tests/test_parallel.py)."""
-        import jax.numpy as jnp
-
         from ..parallel.plan import current_plan
         from ..parallel.sharded_ntt import sharded_ntt
 
         plan = current_plan()
-        mont = _u64_std_to_mont16(arr_u64)
-        res = sharded_ntt(jnp.asarray(mont), omega, plan.mesh, plan=plan)
-        if mont_out:
-            return res
-        return _mont16_to_u64_std(np.asarray(res))
+        call = "backend/ntt_sharded"
+        with span(call, n=arr_u64.shape[0]):
+            std16 = _ship_std16(arr_u64, call)
+            with span(call + "/dispatch"):
+                res = sharded_ntt(_mont_fns()["to"](std16), omega, plan.mesh,
+                                  plan=plan)
+            if mont_out:
+                return res
+            return _fetch_u64_std(res, call)
 
     # batch sizes are padded up to a power of two (zero columns transform
     # to zero columns and are sliced off) so the jitted [B, n, 16] kernels
@@ -524,18 +580,19 @@ class TpuBackend(CpuBackend):
 
     def _ntt_many_device(self, arrs, omega: int, inverse: bool) -> list:
         """[B, n, 16] batched kernel path (single device, any NTT mode)."""
-        import jax.numpy as jnp
-
         from ..ops import ntt as NTT
 
         b, n = len(arrs), arrs[0].shape[0]
-        stack = self._pad_batch(np.stack(arrs))
-        mont = _u64_std_to_mont16(stack.reshape(-1, 4)).reshape(
-            stack.shape[0], n, 16)
-        fn = NTT.intt_many if inverse else NTT.ntt_many
-        out = fn(jnp.asarray(mont), omega)
-        std = _mont16_to_u64_std(np.asarray(out).reshape(-1, 16))
-        return list(std.reshape(stack.shape[0], n, 4)[:b])
+        call = "backend/intt_many" if inverse else "backend/ntt_many"
+        with span(call, batch=b, n=n):
+            std16 = _ship_std16(
+                lambda: self._pad_batch(np.stack(arrs)).reshape(-1, 4), call)
+            with span(call + "/dispatch"):
+                mont = _mont_fns()["to"](std16).reshape(-1, n, 16)
+                fn = NTT.intt_many if inverse else NTT.ntt_many
+                out = fn(mont, omega)
+            return _fetch_u64_std(
+                out, call, lambda std: list(std.reshape(-1, n, 4)[:b]))
 
     def ntt_many(self, coeffs_list, omega: int) -> list:
         if not coeffs_list:
@@ -562,9 +619,7 @@ class TpuBackend(CpuBackend):
         fold into stage 0 of the batched NTT (ops/ntt.py:coset_lde_std),
         so the whole extension is a single device program with no separate
         scale pass and no intermediate Montgomery array."""
-        import jax.numpy as jnp
-
-        from ..ops import limbs as L16, ntt as NTT
+        from ..ops import ntt as NTT
 
         if not coeffs_list:
             return []
@@ -573,15 +628,19 @@ class TpuBackend(CpuBackend):
             return super().coset_lde_many(coeffs_list, omega, g, n_out,
                                           powers=powers)
         b = len(coeffs_list)
-        stack = np.zeros((b, n_out, 4), dtype=np.uint64)
-        for i, cf in enumerate(coeffs_list):
-            stack[i, :cf.shape[0]] = cf
-        stack = self._pad_batch(stack)
-        std16 = L16.u64limbs_to_u16limbs(stack.reshape(-1, 4)).reshape(
-            stack.shape[0], n_out, 16)
-        out = NTT.coset_lde_std(jnp.asarray(std16), omega, g)
-        std = _mont16_to_u64_std(np.asarray(out).reshape(-1, 16))
-        return list(std.reshape(stack.shape[0], n_out, 4)[:b])
+        call = "backend/coset_lde_many"
+        def padded_stack():
+            stack = np.zeros((b, n_out, 4), dtype=np.uint64)
+            for i, cf in enumerate(coeffs_list):
+                stack[i, :cf.shape[0]] = cf
+            return self._pad_batch(stack)
+
+        with span(call, batch=b, n=n_out):
+            std16 = _ship_std16(padded_stack, call)
+            with span(call + "/dispatch"):
+                out = NTT.coset_lde_std(std16, omega, g)
+            return _fetch_u64_std(
+                out, call, lambda std: list(std.reshape(-1, n_out, 4)[:b]))
 
 
 # stable jitted boundary converters: a fresh `jax.jit(lambda ...)` per
@@ -607,28 +666,56 @@ def _mont_fns():
         from ..ops import field_ops as F
 
         ctx = F.fr_ctx()
-        _mont_jits["to"] = jax.jit(lambda v: F.to_mont(ctx, v))
-        _mont_jits["from"] = jax.jit(lambda v: F.from_mont(ctx, v))
+
+        def to_mont_fr(v):
+            return F.to_mont(ctx, v)
+
+        def from_mont_fr(v):
+            return F.from_mont(ctx, v)
+
+        _mont_jits["to"] = jax.jit(to_mont_fr)
+        _mont_jits["from"] = jax.jit(from_mont_fr)
     return _mont_jits
 
 
-def _u64_std_to_mont16(arr):
-    """[n,4] u64 standard -> [n,16] u32 Montgomery, via device to_mont."""
+def _ship_std16(arr, call: str):
+    """Stage `encode` of `call`: [..., 4] u64 standard (or a function that
+    stacks and pads it first) -> device [..., 16] u32 standard limbs; the
+    caller's `dispatch` takes it from there."""
     import jax.numpy as jnp
 
     from ..ops import limbs as L16
 
-    std16 = L16.u64limbs_to_u16limbs(arr)
-    return _mont_fns()["to"](jnp.asarray(std16))
+    with span(call + "/encode"):
+        if callable(arr):
+            arr = arr()
+        std16 = jnp.asarray(L16.u64limbs_to_u16limbs(
+            arr.reshape(-1, 4)).reshape(arr.shape[:-1] + (16,)))
+        annotate(bytes=std16.nbytes)
+    return std16
 
 
-def _mont16_to_u64_std(arr):
+def _fetch_u64_std(out, call: str, unstack=None):
+    """Device [..., 16] u32 Montgomery -> host [n,4] u64 standard, in two
+    crossings (the spans show them, nothing repairs them yet): the
+    Montgomery result is read to the host (`wait`), shipped straight back
+    (`encode`) for from_mont (`dispatch`), read again (`wait`) and its
+    limbs joined (`decode`; `unstack` then cuts a batch into its list)."""
     import jax.numpy as jnp
 
     from ..ops import limbs as L16
 
-    std16 = _mont_fns()["from"](jnp.asarray(arr))
-    return L16.u16limbs_to_u64limbs(np.asarray(std16))
+    with span(call + "/wait", bytes=out.nbytes):
+        mont = np.asarray(out).reshape(-1, 16)
+    with span(call + "/encode", bytes=mont.nbytes):
+        back = jnp.asarray(mont)
+    with span(call + "/dispatch"):
+        std16 = _mont_fns()["from"](back)
+    with span(call + "/wait", bytes=std16.nbytes):
+        std16 = np.asarray(std16)
+    with span(call + "/decode"):
+        std = L16.u16limbs_to_u64limbs(std16)
+        return unstack(std) if unstack else std
 
 
 _backends = {}

@@ -15,6 +15,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from ..fields import bn254
+from ..observability.tracing import span
 from . import backend as B
 from .domain import Domain
 from .srs import SRS
@@ -108,7 +109,13 @@ def _eval_small_poly_on_domain(domain: Domain, coeffs: list[int], bk) -> np.ndar
 
 def shplonk_open(srs: SRS, domain: Domain, entries: list[OpenEntry], transcript, bk=None):
     """Prover: BDFG20 two-commitment multiopen. Evals must already be absorbed
-    into the transcript by the caller; this writes W1, W2."""
+    into the transcript by the caller; this writes W1, W2.
+
+    Most of it is host arithmetic between a few backend calls; its three
+    loops are spans of their own (`multiopen/h_poly`, with each entry's
+    low-degree remainder on the domain as `multiopen/h_poly/remainder`;
+    `multiopen/linearisation`; `multiopen/w2_division`), with the backend's
+    calls as their children."""
     bk = bk or B.get_backend()
     v = transcript.challenge()
 
@@ -120,53 +127,59 @@ def shplonk_open(srs: SRS, domain: Domain, entries: list[OpenEntry], transcript,
             if p not in all_points:
                 all_points.append(p)
 
-    h_evals = B.zeros(n)
-    vk = 1
-    lagrange_cache = {}
-    zinv_cache = {}
-    for e in entries:
-        key = e.points
-        if key not in zinv_cache:
-            zinv_cache[key] = bk.inv(_domain_linear_factors(domain, e.points, bk))
-        if e.coeffs.shape[0] < n:
-            padded = np.zeros((n, 4), dtype=np.uint64)
-            padded[:e.coeffs.shape[0]] = e.coeffs
-        else:
-            padded = e.coeffs
-        p_evals = domain.coeff_to_lagrange(padded, bk)
-        r_coeffs = _interp(e.points, e.evals)
-        r_evals = _eval_small_poly_on_domain(domain, r_coeffs, bk)
-        term = bk.mul(bk.sub(p_evals, r_evals), zinv_cache[key])
-        h_evals = bk.add(h_evals, bk.scale(term, vk))
-        lagrange_cache[id(e)] = (p_evals, r_coeffs)
-        vk = vk * v % R
+    with span("multiopen/h_poly", entries=len(entries)):
+        h_evals = B.zeros(n)
+        vk = 1
+        lagrange_cache = {}
+        zinv_cache = {}
+        for e in entries:
+            key = e.points
+            if key not in zinv_cache:
+                zinv_cache[key] = bk.inv(
+                    _domain_linear_factors(domain, e.points, bk))
+            if e.coeffs.shape[0] < n:
+                padded = np.zeros((n, 4), dtype=np.uint64)
+                padded[:e.coeffs.shape[0]] = e.coeffs
+            else:
+                padded = e.coeffs
+            p_evals = domain.coeff_to_lagrange(padded, bk)
+            with span("multiopen/h_poly/remainder"):
+                r_coeffs = _interp(e.points, e.evals)
+                r_evals = _eval_small_poly_on_domain(domain, r_coeffs, bk)
+            term = bk.mul(bk.sub(p_evals, r_evals), zinv_cache[key])
+            h_evals = bk.add(h_evals, bk.scale(term, vk))
+            lagrange_cache[id(e)] = (p_evals, r_coeffs)
+            vk = vk * v % R
 
-    h_coeffs = domain.lagrange_to_coeff(h_evals, bk)
+        h_coeffs = domain.lagrange_to_coeff(h_evals, bk)
     w1 = commit(srs, h_coeffs, bk)
     transcript.write_point(w1)
     u = transcript.challenge()
 
     # L(X) = sum v^k Z_{T \ S_k}(u) (p_k(X) - r_k(u)) - Z_T(u) h(X)
-    l_evals = B.zeros(n)
-    vk = 1
-    for e in entries:
-        p_evals, r_coeffs = lagrange_cache[id(e)]
-        z_rest = _z_eval([p for p in all_points if p not in e.points], u)
-        r_u = 0
-        for c in reversed(r_coeffs):
-            r_u = (r_u * u + c) % R
-        term = bk.sub(p_evals, B.to_arr([r_u] * n))
-        l_evals = bk.add(l_evals, bk.scale(term, vk * z_rest % R))
-        vk = vk * v % R
-    z_t_u = _z_eval(all_points, u)
-    l_evals = bk.sub(l_evals, bk.scale(domain.coeff_to_lagrange(
-        _pad(h_coeffs, n), bk), z_t_u))
+    with span("multiopen/linearisation"):
+        l_evals = B.zeros(n)
+        vk = 1
+        for e in entries:
+            p_evals, r_coeffs = lagrange_cache[id(e)]
+            z_rest = _z_eval([p for p in all_points if p not in e.points], u)
+            r_u = 0
+            for c in reversed(r_coeffs):
+                r_u = (r_u * u + c) % R
+            term = bk.sub(p_evals, B.to_arr([r_u] * n))
+            l_evals = bk.add(l_evals, bk.scale(term, vk * z_rest % R))
+            vk = vk * v % R
+        z_t_u = _z_eval(all_points, u)
+        l_evals = bk.sub(l_evals, bk.scale(domain.coeff_to_lagrange(
+            _pad(h_coeffs, n), bk), z_t_u))
 
     # W2 = commit(L / (X - u)) via pointwise division on the domain
-    omegas = bk.powers(domain.omega, n)
-    denom_inv = bk.inv(bk.sub(omegas, B.to_arr([u] * n)))
-    w2_evals = bk.mul(l_evals, denom_inv)
-    w2 = commit(srs, domain.lagrange_to_coeff(w2_evals, bk), bk)
+    with span("multiopen/w2_division"):
+        omegas = bk.powers(domain.omega, n)
+        denom_inv = bk.inv(bk.sub(omegas, B.to_arr([u] * n)))
+        w2_evals = bk.mul(l_evals, denom_inv)
+        w2_coeffs = domain.lagrange_to_coeff(w2_evals, bk)
+    w2 = commit(srs, w2_coeffs, bk)
     transcript.write_point(w2)
 
 

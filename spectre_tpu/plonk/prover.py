@@ -14,6 +14,7 @@ import numpy as np
 
 from ..fields import bn254
 from ..native import host
+from ..observability.tracing import span
 from ..utils.profiling import phase
 from . import backend as B, kzg
 from .constraint_system import (Assignment, NUM_H_CHUNKS, PERM_CHUNK,
@@ -117,13 +118,17 @@ def prove(pk: ProvingKey, srs: SRS, assignment: Assignment,
             out[i] = rand()
         return out
 
-    adv_vals = [blind(v) for v in assignment.advice]
-    ladv_vals = [blind(v) for v in assignment.lookup_advice]
-    shb_vals = [blind(assignment.sha_bit[j].tolist())
-                for j in range(cfg.num_sha_bit)]
-    shw_vals = [blind(assignment.sha_word[j].tolist())
-                for j in range(cfg.num_sha_word)]
-    inst_vals = [assignment.instance_column(j) for j in range(cfg.num_instance)]
+    # spans below a phase use `span`, not `phase` (utils/profiling.py), and
+    # none is named `prove/...`: those are the twelve phases
+    with span("job/blind"):
+        adv_vals = [blind(v) for v in assignment.advice]
+        ladv_vals = [blind(v) for v in assignment.lookup_advice]
+        shb_vals = [blind(assignment.sha_bit[j].tolist())
+                    for j in range(cfg.num_sha_bit)]
+        shw_vals = [blind(assignment.sha_word[j].tolist())
+                    for j in range(cfg.num_sha_word)]
+        inst_vals = [assignment.instance_column(j)
+                     for j in range(cfg.num_instance)]
 
     polys: dict = {}      # key -> coefficient form
     values: dict = {}     # key -> int list (lagrange values)
@@ -161,8 +166,9 @@ def prove(pk: ProvingKey, srs: SRS, assignment: Assignment,
                                min(base + 2 * COMMIT_CHUNK, len(item_list))):
                     if j not in futs:
                         futs[j] = ex.submit(B.to_arr, item_list[j][1])
-                arrs = [futs.pop(base + off).result()
-                        for off in range(len(chunk))]
+                with span("commit/marshal"):
+                    arrs = [futs.pop(base + off).result()
+                            for off in range(len(chunk))]
                 coeffs = dom.lagrange_to_coeff_many(arrs, bk)
                 for (key, vals), c in zip(chunk, coeffs):
                     values[key] = vals
@@ -212,48 +218,53 @@ def prove(pk: ProvingKey, srs: SRS, assignment: Assignment,
         nch = cfg.num_perm_chunks
         gp_items = []    # pz + lz columns, committed in one batched call
         for ch in range(nch):
-            cols = list(enumerate(col_keys))[ch * PERM_CHUNK:
-                                             (ch + 1) * PERM_CHUNK]
-            num = B.to_arr([1] * n)
-            den = B.to_arr([1] * n)
-            for gidx, key in cols:
-                v_arr = B.to_arr(col_values(key))
-                dj = pow(DELTA, gidx, R)
-                id_term = bk.add_scalar(
-                    bk.add(v_arr, bk.scale(omega_pows, beta * dj % R)), gamma)
-                sig_term = bk.add_scalar(
-                    bk.add(v_arr, bk.scale(B.to_arr(pk.sigma_values[gidx]),
-                                           beta)),
-                    gamma)
-                num = bk.mul(num, id_term)
-                den = bk.mul(den, sig_term)
-            ratio = bk.mul(num, bk.inv(den))
-            # deactivate blinding rows
-            ratio_ints = B.arr_to_ints(ratio)
-            for i in range(u, n):
-                ratio_ints[i] = 1
-            prefix = bk.prefix_prod(B.to_arr(ratio_ints))
-            prefix_ints = B.arr_to_ints(prefix)
-            z = [prev_end] + [prev_end * p % R for p in prefix_ints[:-1]]
-            prev_end = prev_end * prefix_ints[u - 1] % R if u >= 1 \
-                else prev_end
-            # Blind the tail: every constraint touching z is inactive on rows
-            # u+1..n-1 (act excludes them, llast hits row u, ROT_LAST reads
-            # row u), but z is opened at x and omega*x — deterministic tail
-            # rows would leak witness information halo2 hides. Randomize them.
-            for i in range(u + 1, n):
-                z[i] = rand()
+            with span("grand_products/perm_chunk"):
+                cols = list(enumerate(col_keys))[ch * PERM_CHUNK:
+                                                 (ch + 1) * PERM_CHUNK]
+                num = B.to_arr([1] * n)
+                den = B.to_arr([1] * n)
+                for gidx, key in cols:
+                    v_arr = B.to_arr(col_values(key))
+                    dj = pow(DELTA, gidx, R)
+                    id_term = bk.add_scalar(
+                        bk.add(v_arr, bk.scale(omega_pows, beta * dj % R)),
+                        gamma)
+                    sig_term = bk.add_scalar(
+                        bk.add(v_arr,
+                               bk.scale(B.to_arr(pk.sigma_values[gidx]),
+                                        beta)),
+                        gamma)
+                    num = bk.mul(num, id_term)
+                    den = bk.mul(den, sig_term)
+                ratio = bk.mul(num, bk.inv(den))
+                # deactivate blinding rows
+                ratio_ints = B.arr_to_ints(ratio)
+                for i in range(u, n):
+                    ratio_ints[i] = 1
+                prefix = bk.prefix_prod(B.to_arr(ratio_ints))
+                prefix_ints = B.arr_to_ints(prefix)
+                z = [prev_end] + [prev_end * p % R for p in prefix_ints[:-1]]
+                prev_end = prev_end * prefix_ints[u - 1] % R if u >= 1 \
+                    else prev_end
+                # Blind the tail: every constraint touching z is inactive on
+                # rows u+1..n-1 (act excludes them, llast hits row u, ROT_LAST
+                # reads row u), but z is opened at x and omega*x —
+                # deterministic tail rows would leak witness information
+                # halo2 hides. Randomize them.
+                for i in range(u + 1, n):
+                    z[i] = rand()
             gp_items.append((("pz", ch), z))
         assert prev_end == 1, \
             "permutation product != 1 (copy constraints unsatisfiable)"
 
         # --- 4. lookup grand products ---
         for j in range(cfg.num_lookup_advice):
-            z = lookup_grand_product(
-                bk, n, u, values[("ladv", j)], values[("pA", j)],
-                values[("pT", j)], pk.table_values[j], beta, gamma)
-            for i in range(u + 1, n):        # blind tail rows (see pz above)
-                z[i] = rand()
+            with span("grand_products/lookup"):
+                z = lookup_grand_product(
+                    bk, n, u, values[("ladv", j)], values[("pA", j)],
+                    values[("pT", j)], pk.table_values[j], beta, gamma)
+                for i in range(u + 1, n):    # blind tail rows (see pz above)
+                    z[i] = rand()
             gp_items.append((("lz", j), z))
         # no challenge between pz and lz commits: one batched call
         commit_cols_batched(gp_items)
@@ -316,7 +327,7 @@ def prove(pk: ProvingKey, srs: SRS, assignment: Assignment,
     # --- 6. evaluations per the query plan ---
     plan = pk.vk.query_plan()
 
-    with phase("prove/evals"):
+    with phase("prove/evals"), span("evals/horner", queries=len(plan)):
         evals = {}
         for key, rot in plan:
             pt = pk.vk.rotation_point(x, rot)

@@ -34,6 +34,15 @@ therefore dispatched EAGERLY through a small set of jitted primitives
 the device win lives (each op is HBM-bandwidth-bound either way), and
 compile cost stays bounded per primitive shape.
 
+Spans (observability/tracing.py): `quotient/extend` holds the coefficient
+fetch, the packing (`quotient/extend/encode`, with the bytes the engine's
+`lde` then ships) and the LDE dispatches (`quotient/extend/dispatch`);
+`quotient/expressions` the expression tree's dispatches; `quotient/wait`
+the one blocking read, in the engine's `inverse_std`; `quotient/decode` the
+limb join. The device is busy with this queue from the first
+`quotient/extend/dispatch` to the end of `quotient/wait`: that stretch is
+the device working, not the host.
+
 Parity: the device path produces EXACTLY the host path's u64 coefficient
 arrays, compared in-situ during real proves
 (tests/test_plonk.py::TestDeviceQuotient, gate+lookup and wide-SHA shapes;
@@ -47,6 +56,7 @@ import os
 import numpy as np
 
 from ..fields import bn254
+from ..observability.tracing import span
 from ..ops.msm import _TableLRU, _record_event
 from .constraint_system import CircuitConfig
 from .domain import COSET_GEN, Domain
@@ -80,17 +90,40 @@ def _helpers():
         from ..ops import field_ops as F
 
         fctx = F.fr_ctx()
-        _jit_helpers["to_mont"] = jax.jit(lambda v: F.to_mont(fctx, v))
-        _jit_helpers["from_mont"] = jax.jit(lambda v: F.from_mont(fctx, v))
-        _jit_helpers["mul"] = jax.jit(lambda a, b: F.mont_mul(fctx, a, b))
-        _jit_helpers["add"] = jax.jit(lambda a, b: F.add(fctx, a, b))
-        _jit_helpers["sub"] = jax.jit(lambda a, b: F.sub(fctx, a, b))
-        _jit_helpers["mul_s"] = jax.jit(
-            lambda a, s: F.mont_mul(fctx, a, s[None, :]))
-        _jit_helpers["add_s"] = jax.jit(
-            lambda a, s: F.add(fctx, a, s[None, :].repeat(a.shape[0], 0)))
-        _jit_helpers["fold"] = jax.jit(
-            lambda acc, y, e: F.add(fctx, F.mont_mul(fctx, acc, y[None, :]), e))
+
+        # named, so that a device trace tells one program from another
+        def quotient_to_mont(v):
+            return F.to_mont(fctx, v)
+
+        def quotient_from_mont(v):
+            return F.from_mont(fctx, v)
+
+        def quotient_mul(a, b):
+            return F.mont_mul(fctx, a, b)
+
+        def quotient_add(a, b):
+            return F.add(fctx, a, b)
+
+        def quotient_sub(a, b):
+            return F.sub(fctx, a, b)
+
+        def quotient_mul_scalar(a, s):
+            return F.mont_mul(fctx, a, s[None, :])
+
+        def quotient_add_scalar(a, s):
+            return F.add(fctx, a, s[None, :].repeat(a.shape[0], 0))
+
+        def quotient_fold(acc, y, e):
+            return F.add(fctx, F.mont_mul(fctx, acc, y[None, :]), e)
+
+        for key, fn in (("to_mont", quotient_to_mont),
+                        ("from_mont", quotient_from_mont),
+                        ("mul", quotient_mul), ("add", quotient_add),
+                        ("sub", quotient_sub),
+                        ("mul_s", quotient_mul_scalar),
+                        ("add_s", quotient_add_scalar),
+                        ("fold", quotient_fold)):
+            _jit_helpers[key] = jax.jit(fn)
     return _jit_helpers
 
 
@@ -219,7 +252,9 @@ class _LocalEngine:
         else:
             std = NTT.coset_intt_std_vinv(acc, self.dom.omega_ext,
                                           COSET_GEN, vinv_vals)
-        return np.asarray(std)
+        # the quotient's one blocking read: the whole queue drains here
+        with span("quotient/wait", bytes=std.nbytes):
+            return np.asarray(std)
 
 
 def _shard_min_logn() -> int:
@@ -336,36 +371,40 @@ def _quotient_impl(cfg: CircuitConfig, dom: Domain, fetch_coeffs,
         """Pack a coefficient-array list into ONE standard-form [B, m, 16]
         stack and extend it through the engine's batched LDE."""
         b = len(arrs_u64)
-        stack = np.zeros((b, m, 4), dtype=np.uint64)
-        for i, cf in enumerate(arrs_u64):
-            stack[i, :cf.shape[0]] = cf
-        std16 = L16.u64limbs_to_u16limbs(stack.reshape(-1, 4)).reshape(
-            b, m, 16)
-        return engine.lde(std16)
+        with span("quotient/extend/encode", bytes=b * m * 64):
+            stack = np.zeros((b, m, 4), dtype=np.uint64)
+            for i, cf in enumerate(arrs_u64):
+                stack[i, :cf.shape[0]] = cf
+            std16 = L16.u64limbs_to_u16limbs(stack.reshape(-1, 4)).reshape(
+                b, m, 16)
+        with span("quotient/extend/dispatch"):
+            return engine.lde(std16)
 
     def ext_of_coeffs(arr_u64):
         return ext_of_many([arr_u64])[0]
 
-    # synthetic rows extend as one batched call; real columns prefetch in
-    # fixed-size chunks enumerated from the expression tree
-    l0_e, llast_e, lblind_e = ext_of_many(
-        [st["l0"], st["llast"], st["lblind"]])
-    cols: dict = {
-        ("_l0",): l0_e,
-        ("_llast",): llast_e,
-        ("_lblind",): lblind_e,
-        ("_xcol",): engine.device_col(st["xcol"]),
-    }
-    plan = [k for k in referenced_keys(cfg) if k not in cols]
-    chunk_sz = engine.chunk(_ext_chunk(m))
-    for base in range(0, len(plan), chunk_sz):
-        chunk = plan[base:base + chunk_sz]
-        # pad the tail chunk with the first key so the kernel sees one
-        # batch shape per domain (duplicates are free — same NTT, sliced)
-        padded = chunk + [chunk[0]] * (chunk_sz - len(chunk))
-        outs = ext_of_many([fetch_coeffs(k) for k in padded])
-        for k_, o in zip(chunk, outs):
-            cols[k_] = o
+    with span("quotient/extend", n_ext=m):
+        # synthetic rows extend as one batched call; real columns prefetch
+        # in fixed-size chunks enumerated from the expression tree
+        l0_e, llast_e, lblind_e = ext_of_many(
+            [st["l0"], st["llast"], st["lblind"]])
+        cols: dict = {
+            ("_l0",): l0_e,
+            ("_llast",): llast_e,
+            ("_lblind",): lblind_e,
+            ("_xcol",): engine.device_col(st["xcol"]),
+        }
+        plan = [k for k in referenced_keys(cfg) if k not in cols]
+        chunk_sz = engine.chunk(_ext_chunk(m))
+        for base in range(0, len(plan), chunk_sz):
+            chunk = plan[base:base + chunk_sz]
+            # pad the tail chunk with the first key so the kernel sees one
+            # batch shape per domain (duplicates are free — same NTT,
+            # sliced)
+            padded = chunk + [chunk[0]] * (chunk_sz - len(chunk))
+            outs = ext_of_many([fetch_coeffs(k) for k in padded])
+            for k_, o in zip(chunk, outs):
+                cols[k_] = o
 
     class LazyCols(dict):
         # safety net: any key the recorder missed still materializes
@@ -374,16 +413,18 @@ def _quotient_impl(cfg: CircuitConfig, dom: Domain, fetch_coeffs,
             self[key] = arr
             return arr
 
-    ctx = engine.ctx(LazyCols(cols), cfg.last_row, mont_scalar)
-    acc = None
-    for e in all_expressions(cfg, ctx, beta, gamma):
-        acc = e if acc is None else ctx.fold(acc, y, e)
-    if acc is None:
-        raise ValueError("config yields no constraint expressions — "
-                         "nothing to fold into a quotient")
+    with span("quotient/expressions"):
+        ctx = engine.ctx(LazyCols(cols), cfg.last_row, mont_scalar)
+        acc = None
+        for e in all_expressions(cfg, ctx, beta, gamma):
+            acc = e if acc is None else ctx.fold(acc, y, e)
+        if acc is None:
+            raise ValueError("config yields no constraint expressions — "
+                             "nothing to fold into a quotient")
     # h = acc / Z_H on the coset, then the fused inverse path: ONE kernel —
     # the 1/Z_H stage-0 pre-scale, the iNTT, and the combined
-    # g^{-i}·n^{-1}·(mont→std) output table all ride a single transform
+    # g^{-i}·n^{-1}·(mont→std) output table all ride a single transform.
+    # The engine reads the result inside `quotient/wait`
     if _fused_vinv():
         std = engine.inverse_std(acc, dom.vanishing_inv_period_vals())
     else:
@@ -393,4 +434,5 @@ def _quotient_impl(cfg: CircuitConfig, dom: Domain, fetch_coeffs,
                 L16.u64limbs_to_u16limbs(dom.vanishing_inv_on_extended())))
         hacc = ctx.mul(acc, engine.device_col(vinv))
         std = engine.inverse_std(hacc, None)
-    return L16.u16limbs_to_u64limbs(np.asarray(std))
+    with span("quotient/decode"):
+        return L16.u16limbs_to_u64limbs(std)

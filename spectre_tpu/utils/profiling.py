@@ -3,9 +3,11 @@
 Reference parity (SURVEY.md §5): ark-std `start_timer!/end_timer!` under the
 `print-trace` feature + `RUST_LOG` env filtering. Here: `phase(...)` context
 managers emit wall-clock per prover phase when SPECTRE_TRACE=1 (or via
-logging at DEBUG), and a process-wide registry accumulates totals so services
-can expose them (the JSON-RPC server reports them under `ping`-style
-diagnostics).
+logging at DEBUG). Totals per phase live in the job's span tree and
+manifest and in the histogram below, nowhere else. Spans BELOW a phase
+(the device boundary, loops inside a phase) use `observability.tracing.span`
+directly: 3,300 a proof should not each pay a histogram observation, two
+environment reads and a debug log.
 
 Observability integration (ISSUE 7): every `phase` additionally
 
@@ -28,16 +30,12 @@ import json
 import logging
 import os
 import time
-from collections import defaultdict
 
 from ..observability import metrics as _obs_metrics
 from ..observability import tracing as _obs_tracing
 from . import faults
 
 log = logging.getLogger("spectre_tpu")
-
-_TOTALS: dict[str, float] = defaultdict(float)
-_COUNTS: dict[str, int] = defaultdict(int)
 
 
 def trace_enabled() -> bool:
@@ -59,8 +57,6 @@ def phase(name: str):
             yield
     finally:
         dt = time.perf_counter() - t0
-        _TOTALS[name] += dt
-        _COUNTS[name] += 1
         _obs_metrics.PHASE_SECONDS.labels(phase=name).observe(dt)
         if trace_enabled():
             print(f"[trace] {name}: {dt * 1000:.1f} ms", flush=True)
@@ -77,12 +73,3 @@ def phase(name: str):
                 HEALTH.incr("metrics_write_failures")
         log.debug("phase %s: %.1f ms", name, dt * 1000)
 
-
-def totals() -> dict:
-    return {k: {"seconds": round(v, 4), "count": _COUNTS[k]}
-            for k, v in sorted(_TOTALS.items())}
-
-
-def reset():
-    _TOTALS.clear()
-    _COUNTS.clear()

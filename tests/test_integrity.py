@@ -120,8 +120,9 @@ class _ToyState:
 
 
 def _self_verify_count():
-    from spectre_tpu.utils import profiling
-    return profiling.totals().get("prove/self_verify", {}).get("count", 0)
+    from spectre_tpu.observability import metrics
+    return metrics.PHASE_SECONDS.labels(
+        phase="prove/self_verify").snapshot()["count"]
 
 
 # ---------------------------------------------------------------------------

@@ -941,14 +941,17 @@ class TestCLI:
 
 class TestProfiling:
     def test_phase_timers(self):
+        from spectre_tpu.observability import metrics, tracing
         from spectre_tpu.utils import profiling as prof
-        prof.reset()
-        with prof.phase("unit/test"):
-            pass
-        t = prof.totals()
-        assert t["unit/test"]["count"] == 1
-        prof.reset()
-        assert prof.totals() == {}
+        hist = metrics.PHASE_SECONDS.labels(phase="unit/test")
+        n0 = hist.snapshot()["count"]
+        with tracing.trace("unit-phase-timers") as tr:
+            with prof.phase("unit/test"):
+                pass
+        assert [c.name for c in tr.root.children] == ["unit/test"]
+        assert tr.root.children[0].seconds() >= 0
+        assert hist.snapshot()["count"] == n0 + 1
+        assert not hasattr(prof, "totals")     # no registry beside the two
 
 
 class TestEmittedSpectreSol:
