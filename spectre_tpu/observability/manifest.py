@@ -175,10 +175,10 @@ def build(*, job_id: str, method: str, witness_digest: str | None = None,
           result_digest: str | None = None,
           error: str | None = None) -> dict:
     """Assemble the manifest dict. `trace` is an observability.tracing
-    Trace or None (phase seconds, span counts and transfer bytes are
-    derived from the same tree `getTrace` serves, so they agree by
-    construction); `compile_events` is the
-    compilelog.capture output; `events` the collect_events output."""
+    Trace or None (phase seconds, span counts, transfer bytes and MSM
+    columns are derived from the same tree `getTrace` serves, so they
+    agree by construction); `compile_events` is the compilelog.capture
+    output; `events` the collect_events output."""
     from . import compilelog, tracing
     prove_s = None
     if started is not None and finished is not None:

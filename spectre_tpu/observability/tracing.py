@@ -272,10 +272,15 @@ def summary(tr: Trace | None) -> dict:
     `phase_seconds`, total seconds per span name — the shared schema
     between production traces and bench.py's `phase_seconds` key;
     `span_counts`, spans per name; `transfer_bytes`, the `bytes` metadata
-    summed over the `encode` spans (`h2d`) and the `wait` spans (`d2h`)."""
+    summed over the `encode` spans (`h2d`) and the `wait` spans (`d2h`);
+    `msm_columns`, over the spans that carry a `batch` and a `width` (a
+    run of the one-device MSM path, plonk/backend.py `_msm_chunks`): the
+    columns committed (`real`) and the identity columns that filled the
+    runs up to their width (`padded`)."""
     seconds: dict[str, float] = {}
     counts: dict[str, int] = {}
     moved = {"h2d": 0, "d2h": 0}
+    columns = {"real": 0, "padded": 0}
     way = {ENCODE: "h2d", WAIT: "d2h"}
 
     def walk(s: Span):
@@ -287,6 +292,9 @@ def summary(tr: Trace | None) -> dict:
                 direction = way.get(c.name.rsplit("/", 1)[-1])
                 if direction:
                     moved[direction] += int(c.meta["bytes"])
+            if c.meta and "width" in c.meta:
+                columns["real"] += int(c.meta["batch"])
+                columns["padded"] += int(c.meta["width"] - c.meta["batch"])
             walk(c)
 
     if tr is not None:
@@ -294,7 +302,8 @@ def summary(tr: Trace | None) -> dict:
     return {"phase_seconds": {k: round(v, 6)
                               for k, v in sorted(seconds.items())},
             "span_counts": dict(sorted(counts.items())),
-            "transfer_bytes": moved}
+            "transfer_bytes": moved,
+            "msm_columns": columns}
 
 
 def phase_seconds(tr: Trace) -> dict[str, float]:

@@ -56,24 +56,40 @@ def _affine_mont(arr):
             F.mont_mul(ctx, arr[:, 1], zinv))
 
 
+def affine_points(arr):
+    """Enqueue the affine conversion of [m, 3, 16] projective Montgomery
+    points and return the device's (x, y, z) limbs, unread."""
+    arr = jnp.asarray(arr).reshape(-1, 3, F.NLIMBS)
+    xm, ym = _affine_mont(arr)
+    return xm, ym, arr[:, 2]
+
+
+def read_points(affine, call: str, keep: int | None = None) -> list:
+    """`affine_points`' three arrays -> list of affine (x:int, y:int) |
+    None for the first `keep` points (all, by default; a fixed-width
+    batch's padding is read with the rest, 192 bytes a point, and
+    dropped), under the stage spans `wait` (the three reads; the first is
+    the one that blocks) and `decode` (the host's Montgomery decoding) of
+    `call`."""
+    ctx = _fq()
+    xm, ym, z = affine
+    with span(call + "/wait", bytes=xm.nbytes + ym.nbytes + z.nbytes):
+        xm, ym, z = (np.asarray(a)[:keep] for a in (xm, ym, z))
+    with span(call + "/decode"):
+        xs, ys, z_int = ctx.decode(xm), ctx.decode(ym), ctx.decode(z)
+        return [None if z == 0 else (x, y)
+                for x, y, z in zip(xs, ys, z_int)]
+
+
 def decode_points(arr, call: str = "ec/decode_points") -> list:
     """Device projective -> list of affine (x:int, y:int) | None.
 
     Three stage spans named for `call` (the backend passes its own call's
     name; observability/tracing.py): `dispatch` enqueues the affine
-    conversion, `wait` holds the three reads (the first is the one that
-    blocks), `decode` the host's Montgomery decoding."""
-    ctx = _fq()
+    conversion, then `read_points`' `wait` and `decode`."""
     with span(call + "/dispatch"):
-        arr = jnp.asarray(arr).reshape(-1, 3, F.NLIMBS)
-        xm, ym = _affine_mont(arr)
-        z = arr[:, 2]
-    with span(call + "/wait", bytes=xm.nbytes + ym.nbytes + z.nbytes):
-        xm, ym, z = np.asarray(xm), np.asarray(ym), np.asarray(z)
-    with span(call + "/decode"):
-        xs, ys, z_int = ctx.decode(xm), ctx.decode(ym), ctx.decode(z)
-        return [None if z == 0 else (x, y)
-                for x, y, z in zip(xs, ys, z_int)]
+        affine = affine_points(arr)
+    return read_points(affine, call)
 
 
 def inf_point(shape=()) -> jax.Array:

@@ -210,10 +210,16 @@ def _aggregate_buckets(bucket_sums, c: int):
             if k % 2 else merged
     bit_sums = sel[:, :, 0]                      # [nwin, c, 3, 16]
     # acc = sum_j 2^j bit_sums[:, j] by high-to-low double-and-add
-    acc = ec.inf_point((nwin,))
-    for j in range(c - 1, -1, -1):
+    # (a scan, not c unrolled steps: the same chain of additions as one loop
+    # body where the unrolled form is 2c copies of every field operation's
+    # loops to lower, compile and load; PERF.md section 5 has what the
+    # TPU compiler makes of each)
+    def step(acc, bit_sum):
         acc = ec.padd(acc, acc)
-        acc = ec.padd(acc, bit_sums[:, j])
+        return ec.padd(acc, bit_sum), None
+
+    acc, _ = jax.lax.scan(step, ec.inf_point((nwin,)),
+                          jnp.moveaxis(bit_sums, 1, 0), reverse=True)
     return acc
 
 
@@ -234,7 +240,8 @@ def _msm_windows_impl(points, scalars, c: int, nbits: int):
 # jit — the discipline that keeps per-prove calls on a warm trace cache.
 TRACE_JIT_ROOTS = ("msm_windows", "msm_windows_bits", "msm_windows_signed",
                    "combine_windows", "_build_window_table", "msm_fixed_run",
-                   "msm_windows_batch")
+                   "msm_windows_batch", "combine_windows_batch",
+                   "pad_window_sums")
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
@@ -280,8 +287,7 @@ def combine_windows(window_sums, c: int):
     nwin = window_sums.shape[0]
 
     def body(i, acc):
-        for _ in range(c):
-            acc = ec.padd(acc, acc)
+        acc = jax.lax.fori_loop(0, c, lambda _, a: ec.padd(a, a), acc)
         return ec.padd(acc, window_sums[nwin - 1 - i])
 
     return jax.lax.fori_loop(0, nwin, body, ec.inf_point(()))
@@ -723,21 +729,53 @@ def msm_windows_batch(points, scalars_batch, c: int):
     """Batched MSM window phase: one point set, many scalar vectors.
 
     scalars_batch: [m, n, 16] -> [m, nwin, 3, 16]. The inter-proof /
-    multi-column batching axis (SURVEY.md §2c(b)). MEASURED NOTE: on a single
-    chip this is bandwidth-bound and vmap multiplies HBM traffic — batch=8 at
-    2^16 ran ~3x slower than sequential single MSMs, so the sequential path
-    stays the default; this entry point exists for multi-chip sharding where
-    the batch axis maps onto the mesh."""
+    multi-column batching axis (SURVEY.md §2c(b)); on a mesh it maps onto
+    the devices (parallel.batch_msm). On one chip the window phase is bound
+    by its additions, not by its chain of dependent steps, and a batch axis
+    buys it nothing: 16 columns at 2^14 took 5.08 s here against 16 x
+    0.335 s through `msm_windows` (PERF.md section 5, my chip run, PR 30),
+    so the one-chip commit path runs `msm_windows` a column and batches
+    only what follows it (`combine_windows_batch`)."""
     return jax.vmap(lambda sc: msm_windows.__wrapped__(points, sc, c))(scalars_batch)
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def combine_windows_batch(window_sums_batch, c: int):
+    """[m, nwin, 3, 16] -> [m, 3, 16]: `combine_windows`' chain of
+    doublings once, at width m. The chain is nwin x (c + 1) dependent
+    additions whatever m is, and on the chip 16 columns cost less than one
+    (PERF.md section 5)."""
+    return jax.vmap(lambda w: combine_windows.__wrapped__(w, c))(
+        window_sums_batch)
+
+
+# Columns a run of the one-chip commit path combines and converts together
+# (plonk/backend.py `TpuBackend._msm_chunks`): the prover's COMMIT_CHUNK.
+CHUNK_WIDTH = 16
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def pad_window_sums(window_sums: tuple, width: int):
+    """<= width arrays [nwin, 3, 16] -> [width, nwin, 3, 16], the missing
+    columns identity window sums (they combine to the identity). Jitted:
+    one small program for each count of columns a prove sends, where the
+    same few operations run eagerly are a dozen."""
+    stack = jnp.stack(window_sums)
+    short = width - stack.shape[0]
+    if short:
+        stack = jnp.concatenate(
+            [stack, ec.inf_point((short, stack.shape[1]))], axis=0)
+    return stack
 
 
 def msm_batch(points, scalars_batch, c: int | None = None,
               mode: str | None = None, base_key=None):
     """[m] results (projective [m, 3, 16]) for m scalar vectors.
 
-    Non-vanilla modes run the rows SEQUENTIALLY through the single-MSM
-    kernels (the measured-faster single-chip shape — see msm_windows_batch)
-    with the GLV expansion / fixed table shared across rows; the mesh-
+    Vanilla runs the batched window phase and the batched combine. The
+    other modes run the rows one after another through the single-MSM
+    kernels with the GLV expansion / fixed table shared across rows (none
+    of them has run on the chip: ROADMAP Queue 3 item 1); the mesh-
     parallel batch axis lives in parallel.batch_msm."""
     mode = mode if mode is not None else msm_mode()
     n = points.shape[0]
@@ -750,8 +788,8 @@ def msm_batch(points, scalars_batch, c: int | None = None,
     if mode == "vanilla":
         if c is None:
             c = default_window(n)
-        wins = msm_windows_batch(points, scalars_batch, c)
-        return jax.vmap(lambda w: combine_windows.__wrapped__(w, c))(wins)
+        return combine_windows_batch(
+            msm_windows_batch(points, scalars_batch, c), c)
 
     from . import glv
     nbits = glv.glv_bits()

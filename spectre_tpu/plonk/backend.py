@@ -203,9 +203,12 @@ class TpuBackend(CpuBackend):
     call (`backend/msm`, `backend/ntt`, ...) and inside it the stages
     `encode`, `dispatch`, `wait`, `decode` (observability/tracing.py). A
     `wait` closes on the read that blocked anyway; no stage adds a
-    synchronisation. The NTT kinds cross the boundary twice a call (the
-    transform's result comes to the host and goes straight back for
-    `from_mont`): their spans show both crossings."""
+    synchronisation. On one device a list of commits is one
+    `backend/msm_many` span a run of MSM.CHUNK_WIDTH columns
+    (`_msm_chunks`), with `batch` and `width` on it. The NTT kinds cross
+    the boundary twice a call (the transform's result comes to the host
+    and goes straight back for `from_mont`): their spans show both
+    crossings."""
 
     name = "tpu"
     # quotient phase as one device-resident XLA program (quotient_device.py)
@@ -284,6 +287,9 @@ class TpuBackend(CpuBackend):
         m = min(points.shape[0], scalars.shape[0])
         if self._use_mesh(m, self._shard_min_logn):
             return self._msm_sharded(points, scalars, m, base_key=base_key)
+        if self._one_chip_default():
+            # a chunk of one: the programs a list's runs have loaded already
+            return self._msm_chunks("backend/msm", points, [scalars])[0]
         with span("backend/msm", n=m):
             with span("backend/msm/encode"):
                 pts = self._base_points(points, m)
@@ -432,8 +438,12 @@ class TpuBackend(CpuBackend):
         """Commit several scalar vectors against one cached device base.
 
         With >1 local device the batch axis is sharded over a 1-D mesh
-        (SURVEY §2c(b): inter-proof/column DP); single-chip it loops the
-        sequential kernel (measured faster than vmap there). GLV modes
+        (SURVEY §2c(b): inter-proof/column DP). On one device, in the
+        default mode, the columns go through `_msm_chunks`, 16 to a device
+        run and a read (PERF.md section 5 has the chip's readings of it
+        against a loop of `msm`); the other modes and
+        SPECTRE_MSM_IMPL=pallas, which have not run on the chip, loop
+        `msm`. GLV modes
         thread the scalar-prep stage through the DP path: half-scalars and
         sign masks are stacked per batch row against ONE replicated
         endomorphism-expanded base (`fixed` uses the glv+signed kernels
@@ -493,8 +503,62 @@ class TpuBackend(CpuBackend):
                 with span(call + "/wait", bytes=res.nbytes):
                     proj = np.asarray(res)
                 return list(ec.decode_points(proj, call=call))
+        if self._one_chip_default():
+            return self._msm_chunks("backend/msm_many", points, scalars_list)
         return [self.msm(points, s, base_key=base_key)
                 for s in scalars_list]
+
+    @staticmethod
+    def _one_chip_default() -> bool:
+        """One device and the MSM as the chip has run it (SPECTRE_MSM_MODE
+        vanilla on the XLA kernels): what `_msm_chunks` serves."""
+        from ..ops import msm as MSM
+        from ..parallel.plan import current_plan
+        return (current_plan().n_devices == 1
+                and MSM.msm_mode() == "vanilla" and MSM.msm_impl() == "xla")
+
+    def _msm_chunks(self, call: str, points, scalars_list) -> list:
+        """Commit `scalars_list` against one resident base, MSM.CHUNK_WIDTH
+        columns a device run: each column's window phase (`msm_windows`,
+        the one window-phase program of its n), then for the whole run the
+        combine chain at width W, the affine conversion of W points and
+        one blocking read, with no host synchronisation between them. On
+        the chip the window phase is bound by its additions and gains
+        nothing from a batch axis; combine and affine conversion are
+        chains of dependent steps that cost less at width 16 than at width
+        1 (PERF.md section 5 has the readings).
+
+        ONE width: a run of fewer columns is filled with identity window
+        sums, whose points are read with the rest and dropped; a longer
+        list is split; a shorter scalar vector is zero-extended. One span
+        named `call` a run, with `batch` (its columns) and `width` on it."""
+        import jax.numpy as jnp
+
+        from ..ops import ec, limbs as L16, msm as MSM
+
+        n = min(points.shape[0], max(s.shape[0] for s in scalars_list))
+        c = MSM.default_window(n)
+        width = MSM.CHUNK_WIDTH
+        out = []
+        for at in range(0, len(scalars_list), width):
+            chunk = scalars_list[at:at + width]
+            with span(call, n=n, batch=len(chunk), width=width):
+                with span(call + "/encode"):
+                    pts = self._base_points(points, n)
+                    cols, sent = [], 0
+                    for col in chunk:
+                        sc = L16.u64limbs_to_u16limbs(col[:n])
+                        if sc.shape[0] < n:
+                            sc = np.pad(sc, ((0, n - sc.shape[0]), (0, 0)))
+                        cols.append(jnp.asarray(sc))
+                        sent += sc.nbytes
+                    annotate(bytes=sent)
+                with span(call + "/dispatch"):
+                    wins = tuple(MSM.msm_windows(pts, sc, c) for sc in cols)
+                    affine = ec.affine_points(MSM.combine_windows_batch(
+                        MSM.pad_window_sums(wins, width), c))
+                out += ec.read_points(affine, call, keep=len(chunk))
+        return out
 
     # NTTs at least this large ride the four-step mesh-sharded kernel
     # (all-to-all transpose over ICI, parallel/sharded_ntt.py) when >1

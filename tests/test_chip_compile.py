@@ -4,8 +4,9 @@ itself, for a DESCRIBED v5e:2x2 — no chip attached, nothing runs.
 The shapes are the smoke's (committee-update Minimal-32, k=14: columns of
 2^14 rows, the 2^16 extended domain) and production's (2^18). A compile that
 passes is not a chip run: it says the program lowers, fits, and which
-collectives the partitioner put in. The slow ones (msm_windows 2^14/2^18/2^21:
-minutes each) are run by hand and recorded in CHANGES.md / PERF.md instead.
+collectives the partitioner put in. The slow ones (msm_windows 2^14/2^18/2^21,
+msm_windows_batch: minutes each) are run by hand and recorded in CHANGES.md /
+PERF.md instead.
 
 The topology is described inside a module-scoped fixture: only one process at
 a time may load libtpu, and xdist workers each import every test file.
@@ -85,6 +86,17 @@ class TestOneChip:
         c = MSM.default_window(1 << 14)
         nwin = (254 + c - 1) // c
         _compile(MSM.combine_windows, _shape((nwin, 3, 16), one_chip), c)
+
+    @pytest.mark.parametrize("logn", [14, 18])
+    def test_chunk_combine_and_affine(self, one_chip, logn):
+        """What a run of the one-chip commit path adds to a column's window
+        phase: the combine chain and the affine conversion at CHUNK_WIDTH."""
+        from spectre_tpu.ops import ec, msm as MSM
+        c = MSM.default_window(1 << logn)
+        nwin = (254 + c - 1) // c
+        _compile(MSM.combine_windows_batch,
+                 _shape((MSM.CHUNK_WIDTH, nwin, 3, 16), one_chip), c)
+        _compile(ec._affine_mont, _shape((MSM.CHUNK_WIDTH, 3, 16), one_chip))
 
     @pytest.mark.parametrize("logn", [14, 18])
     def test_ntt_forward(self, one_chip, logn):

@@ -655,6 +655,10 @@ PROVE_PHASES = {
 MSM_STAGES = ["encode", "dispatch", "dispatch", "wait", "decode"]
 # the batched mesh branch reads the mesh result whole and sends it back up
 MESH_MSM_STAGES = ["encode", "dispatch", "wait", "dispatch", "wait", "decode"]
+# one device, default mode: a run's window phases, its combine and its affine
+# conversion are enqueued together and read once (`TpuBackend._msm_chunks`)
+CHUNK_MSM_STAGES = ["encode", "dispatch", "wait", "decode"]
+MSM_WIDTH = 16       # `ops/msm.py:CHUNK_WIDTH`
 TWO_CROSSINGS = ["encode", "dispatch", "wait",
                  "encode", "dispatch", "wait", "decode"]
 
@@ -717,11 +721,13 @@ class TestDeviceBoundarySpans:
     def test_proof_bytes_equal_cpu_backend(self, tiny_tpu_prove):
         assert tiny_tpu_prove.proof == tiny_tpu_prove.cpu_proof
 
-    @pytest.mark.parametrize("op,want", [
-        ("msm", MSM_STAGES), ("ntt", TWO_CROSSINGS),
-        ("intt_many", TWO_CROSSINGS), ("msm_many", MESH_MSM_STAGES)])
+    @pytest.mark.parametrize("op,mesh,want", [
+        ("msm", None, MSM_STAGES), ("msm", "1x1", CHUNK_MSM_STAGES),
+        ("ntt", None, TWO_CROSSINGS), ("intt_many", None, TWO_CROSSINGS),
+        ("msm_many", None, MESH_MSM_STAGES),
+        ("msm_many", "1x1", CHUNK_MSM_STAGES)])
     def test_backend_call_has_its_stages_in_order(self, tiny_tpu_prove, op,
-                                                  want):
+                                                  mesh, want, monkeypatch):
         import numpy as np
 
         from spectre_tpu.parallel.plan import current_plan
@@ -732,8 +738,11 @@ class TestDeviceBoundarySpans:
         arr = B.to_arr(range(1, n + 1))
         omega = t.pk.vk.domain.omega
         cpu_point = B.get_backend("cpu").msm(t.srs.g1_powers, arr)
-        if op == "msm_many" and current_plan().n_devices == 1:
-            pytest.skip("the batched branch of msm_many needs a mesh")
+        if mesh:
+            monkeypatch.setenv("SPECTRE_MESH_SHAPE", mesh)
+        one_chip = current_plan().n_devices == 1
+        if op == "msm_many" and one_chip and not mesh:
+            pytest.skip("the mesh branch of msm_many needs a mesh")
         with tracing.trace(f"one-{op}") as tr:
             if op == "msm":
                 assert t.bk.msm(t.srs.g1_powers, arr) == cpu_point
@@ -754,11 +763,23 @@ class TestDeviceBoundarySpans:
         assert [s.name for s in stages] == [f"backend/{op}/{w}" for w in want]
         _check_in_flight(call, stages)
         # what is shipped and what comes back, from the shapes: 64 bytes a
-        # field element as 16 u32 limbs; a batch of 3 is padded to 4
+        # field element as 16 u32 limbs; a batch of 3 is padded to 4; a
+        # run of the one-chip MSM path ships its columns and reads the
+        # affine points (x, y, z) of its whole width
         rows = n * (4 if op == "intt_many" else 1)
-        moved = tracing.summary(tr)["transfer_bytes"]
-        if op == "msm":
+        got = tracing.summary(tr)
+        moved = got["transfer_bytes"]
+        if one_chip and op in ("msm", "msm_many"):
+            batch = 1 if op == "msm" else 2
+            assert (call.meta["batch"], call.meta["width"]) \
+                == (batch, MSM_WIDTH)
+            assert moved == {"h2d": 64 * n * batch,
+                             "d2h": MSM_WIDTH * 3 * 64}
+            assert got["msm_columns"] == {"real": batch,
+                                          "padded": MSM_WIDTH - batch}
+        elif op == "msm":
             assert moved == {"h2d": 64 * n, "d2h": 3 * 64}
+            assert got["msm_columns"] == {"real": 0, "padded": 0}
         elif op == "msm_many":
             assert call.meta["batch"] == 2 and moved["d2h"] == 2 * 6 * 64
         else:
@@ -768,11 +789,10 @@ class TestDeviceBoundarySpans:
         calls = [s for s in _walk(tiny_tpu_prove.trace.root)
                  if s.name.startswith("backend/") and s.name.count("/") == 1]
         assert {c.name for c in calls} == {
-            "backend/msm", "backend/ntt", "backend/intt",
+            "backend/msm", "backend/msm_many", "backend/ntt", "backend/intt",
             "backend/intt_many"}
         for call in calls:
-            want = MSM_STAGES if call.name == "backend/msm" \
-                else TWO_CROSSINGS
+            want = CHUNK_MSM_STAGES if "msm" in call.name else TWO_CROSSINGS
             stages = _stages(call)
             assert [s.name.rsplit("/", 1)[-1] for s in stages] == want
             _check_in_flight(call, stages)
@@ -835,16 +855,27 @@ class TestDeviceBoundarySpans:
         assert set(man["phase_seconds"]) == set(counts)
         # one MSM a committed column: advice and lookup advice, two permuted
         # columns a lookup, a grand product a permutation chunk and a
-        # lookup, the quotient's chunks, W1 and W2
+        # lookup, the quotient's chunks, W1 and W2; all but W1 and W2 reach
+        # the backend in lists, each list one run of MSM_WIDTH columns here
         commits = (cfg.num_advice + cfg.num_lookup_advice
                    + 2 * cfg.num_lookup_advice
                    + cfg.num_perm_chunks + cfg.num_lookup_advice
                    + NUM_H_CHUNKS + 2)
-        assert counts["backend/msm"] == commits
-        assert counts["backend/msm/encode"] == commits
-        assert counts["backend/msm/dispatch"] == 2 * commits
-        assert counts["backend/msm/wait"] == commits
-        assert counts["backend/msm/decode"] == commits
+        assert counts["backend/msm"] == 2
+        runs = counts["backend/msm"] + counts["backend/msm_many"]
+        assert got["msm_columns"] == man["msm_columns"] == {
+            "real": commits, "padded": MSM_WIDTH * runs - commits}
+        real = 0
+        for s in _walk(t.trace.root):
+            if s.name in ("backend/msm", "backend/msm_many"):
+                assert s.meta["width"] == MSM_WIDTH
+                assert 1 <= s.meta["batch"] <= MSM_WIDTH
+                real += s.meta["batch"]
+        assert real == commits
+        for op in ("msm", "msm_many"):
+            c = counts[f"backend/{op}"]
+            assert [counts[f"backend/{op}/{w}"] for w in CHUNK_MSM_STAGES] \
+                == [c] * 4
         for op in ("ntt", "intt", "intt_many"):
             c = counts[f"backend/{op}"]
             assert counts[f"backend/{op}/encode"] == 2 * c
@@ -865,7 +896,7 @@ class TestDeviceBoundarySpans:
         lde_rows = 3 + _ext_chunk(m) * (counts["quotient/extend/encode"] - 1)
         assert moved == {
             "h2d": 64 * (n * commits + 2 * rows + m * lde_rows),
-            "d2h": 64 * (3 * commits + 2 * rows + m)}
+            "d2h": 64 * (3 * MSM_WIDTH * runs + 2 * rows + m)}
 
     def test_span_meta_is_allocated_on_first_use(self):
         with tracing.trace("t-meta") as tr:

@@ -231,6 +231,14 @@ class TestMSMBatch:
         for sc, g_pt in zip(scs, got):
             want = bn.g1_curve.msm(pts, sc)
             assert g_pt == (int(want[0]), int(want[1]))
+        # the one-chip commit path's shape: a window phase a column, the
+        # combine at a fixed width with identity window sums for the rest
+        wins = tuple(MSM.msm_windows(pp, sc, 4) for sc in batch)
+        padded = MSM.pad_window_sums(wins, 8)
+        assert padded.shape == (8,) + wins[0].shape
+        assert ec.decode_points(MSM.combine_windows_batch(padded, 4)) \
+            == got + [None] * 5
+        assert MSM.pad_window_sums(wins, m).shape[0] == m
 
 
 class TestMxuField:

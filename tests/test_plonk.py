@@ -186,6 +186,95 @@ class TestMsmModeCommitments:
         assert not bad, f"pallas path degraded to XLA: {bad}"
 
 
+class TestOneChipBatchedCommit:
+    """ISSUE 30: on ONE device, in the default MSM mode, `TpuBackend`
+    commits a list MSM.CHUNK_WIDTH columns a device run (`_msm_chunks`: a
+    window phase a column, one combine, one affine conversion and one read
+    a run) and a single column as a chunk of one. Same group elements as
+    the one-column kernels and as the native Pippenger; bytes and counts
+    only."""
+
+    N = 32
+
+    @pytest.fixture(scope="class")
+    def base(self):
+        pts = [bn.g1_curve.mul(bn.G1_GEN, 3 * k + 2) for k in range(self.N)]
+        pts[5] = None                      # an infinity in the base
+        return host.points_to_limbs(pts)
+
+    @pytest.fixture(scope="class")
+    def columns(self):
+        import random
+        rng = random.Random(30)
+        cols = [B.to_arr([rng.randrange(bn.R) for _ in range(self.N)])
+                for _ in range(17)]
+        cols[1] = B.zeros(self.N)          # an all-zero column
+        cols[2] = cols[2][:self.N - 5]     # shorter than the base
+        return cols
+
+    @pytest.fixture()
+    def one_chip(self, monkeypatch):
+        monkeypatch.setenv("SPECTRE_MESH_SHAPE", "1x1")
+        monkeypatch.delenv("SPECTRE_MSM_MODE", raising=False)
+        monkeypatch.delenv("SPECTRE_MSM_IMPL", raising=False)
+        return B.TpuBackend()
+
+    @pytest.mark.parametrize("length", [1, 2, 3, 10, 16, 17])
+    def test_chunks_equal_the_loop_and_the_cpu(self, base, columns,
+                                               one_chip, length):
+        import jax.numpy as jnp
+
+        from spectre_tpu.observability import tracing
+        from spectre_tpu.ops import ec, limbs as L16, msm as MSM
+
+        cols = columns[:length]
+        with tracing.trace(f"chunks-{length}") as tr:
+            got = one_chip.msm_many(base, cols)
+        assert got == B.get_backend("cpu").msm_many(base, cols)
+        # the loop the batched path replaced: one column a program
+        pts = one_chip._base_points(base, self.N)
+        for col, pt in list(zip(cols, got))[:3]:
+            m = col.shape[0]
+            sc16 = jnp.asarray(L16.u64limbs_to_u16limbs(col))
+            assert ec.decode_points(MSM.msm(pts[:m], sc16)[None])[0] == pt
+        if length > 1:
+            assert got[1] is None                   # the all-zero column
+        # a list longer than the width is split, a shorter one padded
+        runs = tr.root.children
+        assert [(r.name, r.meta["batch"], r.meta["width"]) for r in runs] \
+            == [("backend/msm_many", min(16, length - at), 16)
+                for at in range(0, length, 16)]
+        assert tracing.summary(tr)["msm_columns"] == {
+            "real": length, "padded": 16 * len(runs) - length}
+
+    def test_msm_is_a_chunk_of_one(self, base, columns, one_chip):
+        from spectre_tpu.observability import tracing
+        cpu = B.get_backend("cpu")
+        with tracing.trace("chunk-of-one") as tr:
+            for col in columns[:3]:
+                assert one_chip.msm(base, col) == cpu.msm(base, col)
+        assert [(r.name, r.meta["batch"], r.meta["width"])
+                for r in tr.root.children] == [("backend/msm", 1, 16)] * 3
+
+    @pytest.mark.parametrize("var,value", [
+        ("SPECTRE_MSM_MODE", "glv"), ("SPECTRE_MESH_SHAPE", None)])
+    def test_other_modes_and_meshes_keep_their_paths(self, base, columns,
+                                                     one_chip, monkeypatch,
+                                                     var, value):
+        """Default mode on one device only: another MSM mode loops `msm`
+        on its own kernels, a mesh (all 8 virtual devices once the 1x1
+        shape is unset) keeps the data-parallel branch."""
+        from spectre_tpu.observability import tracing
+        if value is None:
+            monkeypatch.delenv(var)
+        else:
+            monkeypatch.setenv(var, value)
+        with tracing.trace("not-batched") as tr:
+            got = one_chip.msm_many(base, columns[:2])
+        assert got == B.get_backend("cpu").msm_many(base, columns[:2])
+        assert tracing.summary(tr)["msm_columns"] == {"real": 0, "padded": 0}
+
+
 def _tiny_circuit(cfg):
     """x + x*y = out, x range-checked, one constant pin."""
     n = cfg.n
