@@ -7,8 +7,10 @@ all: native
 native:
 	$(MAKE) -C spectre_tpu/native
 
+# the driver's tier-1 form (ROADMAP "Tier-1 verify"): what a builder runs
+# here is what is counted there. `test-slow` below is the whole ladder.
 test: native lint lint-deep test-faults test-farm test-farm-proc test-gateway bench-fast
-	python -m pytest tests/ -q
+	JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' -p xdist -n 6 --dist loadfile
 
 # fault-injection tier (PR 3, grown in PR 6): deterministic resilience
 # suite — beacon retry/backoff + circuit breaker, device-prove -> CPU
@@ -30,9 +32,13 @@ test: native lint lint-deep test-faults test-farm test-farm-proc test-gateway be
 # PR 14 adds the gateway tier (test_gateway.py): pack corruption
 # quarantine -> rebuild, gateway.pack_write ioerror, torn pack-journal
 # tail, and the fault-scheduled 10^4-client acceptance drill.
+# The device-boundary span tests are tests/test_device_prove.py
+# (one file for everything that proves the tiny circuit on TpuBackend), and
+# the MSM table-budget degrade is tests/test_msm_modes.py's, beside the
+# kernels it falls back to.
 # Also part of the full pytest ladder above.
 test-faults: native
-	JAX_PLATFORMS=cpu python -m pytest tests/test_faults.py tests/test_service.py tests/test_observability.py tests/test_manifest.py tests/test_integrity.py tests/test_follower.py tests/test_farm.py tests/test_gateway.py -q
+	JAX_PLATFORMS=cpu python -m pytest tests/test_faults.py tests/test_service.py tests/test_observability.py tests/test_device_prove.py tests/test_manifest.py tests/test_integrity.py tests/test_follower.py tests/test_farm.py tests/test_gateway.py -q
 
 # proof-farm failover matrix (PR 11, tests/test_farm.py): replica crash
 # mid-prove -> lease takeover with a byte-identical proof, breaker-open

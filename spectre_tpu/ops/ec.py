@@ -206,16 +206,3 @@ def select_point(mask, a, b):
 
 def is_inf(p):
     return F.is_zero(p[..., 2, :])
-
-
-def scalar_mul(p, k: int):
-    """Single-point scalar mul by host int (double-and-add, unrolled bits)."""
-    acc = inf_point(p.shape[:-2])
-    base = p
-    while k:
-        if k & 1:
-            acc = padd(acc, base)
-        k >>= 1
-        if k:
-            base = padd(base, base)
-    return acc

@@ -240,8 +240,7 @@ def _msm_windows_impl(points, scalars, c: int, nbits: int):
 # jit — the discipline that keeps per-prove calls on a warm trace cache.
 TRACE_JIT_ROOTS = ("msm_windows", "msm_windows_bits", "msm_windows_signed",
                    "combine_windows", "_build_window_table", "msm_fixed_run",
-                   "msm_windows_batch", "combine_windows_batch",
-                   "pad_window_sums")
+                   "combine_windows_batch", "pad_window_sums")
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
@@ -724,21 +723,6 @@ def msm(points, scalars, c: int | None = None, mode: str | None = None,
     return combine_windows(wins, c)
 
 
-@functools.partial(jax.jit, static_argnums=(2,))
-def msm_windows_batch(points, scalars_batch, c: int):
-    """Batched MSM window phase: one point set, many scalar vectors.
-
-    scalars_batch: [m, n, 16] -> [m, nwin, 3, 16]. The inter-proof /
-    multi-column batching axis (SURVEY.md §2c(b)); on a mesh it maps onto
-    the devices (parallel.batch_msm). On one chip the window phase is bound
-    by its additions, not by its chain of dependent steps, and a batch axis
-    buys it nothing: 16 columns at 2^14 took 5.08 s here against 16 x
-    0.335 s through `msm_windows` (PERF.md section 5, my chip run, PR 30),
-    so the one-chip commit path runs `msm_windows` a column and batches
-    only what follows it (`combine_windows_batch`)."""
-    return jax.vmap(lambda sc: msm_windows.__wrapped__(points, sc, c))(scalars_batch)
-
-
 @functools.partial(jax.jit, static_argnums=(1,))
 def combine_windows_batch(window_sums_batch, c: int):
     """[m, nwin, 3, 16] -> [m, 3, 16]: `combine_windows`' chain of
@@ -766,54 +750,3 @@ def pad_window_sums(window_sums: tuple, width: int):
         stack = jnp.concatenate(
             [stack, ec.inf_point((short, stack.shape[1]))], axis=0)
     return stack
-
-
-def msm_batch(points, scalars_batch, c: int | None = None,
-              mode: str | None = None, base_key=None):
-    """[m] results (projective [m, 3, 16]) for m scalar vectors.
-
-    Vanilla runs the batched window phase and the batched combine. The
-    other modes run the rows one after another through the single-MSM
-    kernels with the GLV expansion / fixed table shared across rows (none
-    of them has run on the chip: ROADMAP Queue 3 item 1); the mesh-
-    parallel batch axis lives in parallel.batch_msm."""
-    mode = mode if mode is not None else msm_mode()
-    n = points.shape[0]
-    if msm_impl() == "pallas":
-        # per-row dispatch through the bucket pipeline: the fixed table is
-        # LRU-shared across rows and every trace below is a cached jit
-        return jnp.stack([
-            _msm_pallas(points, sc, c, mode, base_key)
-            for sc in scalars_batch])
-    if mode == "vanilla":
-        if c is None:
-            c = default_window(n)
-        return combine_windows_batch(
-            msm_windows_batch(points, scalars_batch, c), c)
-
-    from . import glv
-    nbits = glv.glv_bits()
-    outs = []
-    if mode == "fixed":
-        cf = c if c is not None else default_window_fixed(2 * n)
-        if _degrade_fixed(n, cf, nbits):
-            mode = "glv+signed"
-        else:
-            nwin = (nbits + cf) // cf
-            table = fixed_base_table(points, cf, nwin, base_key=base_key)
-            for sc in scalars_batch:
-                sc2, neg = _glv_scalars_device(sc)
-                outs.append(msm_fixed_run(table, sc2, neg, cf, nbits))
-            return jnp.stack(outs)
-
-    pts2 = _expand_endo(points)
-    if c is None:
-        c = default_window(2 * n, signed=(mode == "glv+signed"))
-    for sc in scalars_batch:
-        sc2, neg = _glv_scalars_device(sc)
-        if mode == "glv":
-            wins = msm_windows_bits(_apply_sign(pts2, neg), sc2, c, nbits)
-        else:
-            wins = msm_windows_signed(pts2, sc2, neg, c, nbits)
-        outs.append(combine_windows(wins, c))
-    return jnp.stack(outs)

@@ -52,6 +52,23 @@ def inner():
     return pk, srs, asg.instances, proof
 
 
+def _host_msm(vc):
+    """`MsmChip.msm`'s answer from the host curve: the sum of the same
+    (point, scalar) terms, loaded as the cells the chip would return."""
+    g1 = bn254.g1_curve
+
+    def msm(ctx, witness_pairs, constant_pairs):
+        acc = None
+        for (x, y), s in witness_pairs:
+            pt = (bn254.Fq(x.value % P), bn254.Fq(y.value % P))
+            acc = g1.add(acc, g1.mul(pt, s.value % R))
+        for pt, s in constant_pairs:
+            acc = g1.add(acc, g1.mul(pt, s.value % R))
+        return (vc.fq.load(ctx, int(acc[0])), vc.fq.load(ctx, int(acc[1])))
+
+    return msm
+
+
 class TestAccumulator:
     def test_limbs_roundtrip(self):
         g1 = bn254.g1_curve
@@ -125,10 +142,21 @@ class TestInCircuitVerifier:
             rhs=(bn254.Fq(rhs[0].value % P), bn254.Fq(rhs[1].value % P)),
         ).check(srs)
 
-    def test_sha_region_inner_proof_aggregates(self):
+    @pytest.mark.parametrize("msm", [
+        "host_msm", pytest.param("msm_chip", marks=pytest.mark.slow)])
+    def test_sha_region_inner_proof_aggregates(self, msm, monkeypatch):
         """An inner proof whose circuit uses the wide-SHA region (extra
         commitment/query-plan keys: shb/shw/shq/shk) must flow through the
-        in-circuit verifier and close the deferred pairing."""
+        in-circuit verifier and close the deferred pairing.
+
+        The wide region is some 190 commitments whatever the message, and
+        the in-circuit MSM witnesses each as 64 windows of non-native
+        additions in Python: minutes, all of it the loop that
+        `test_accumulator_matches_native` runs over the small proof's
+        terms. `host_msm` hands the verifier's terms (every commitment key
+        with its in-circuit scalar) to the host curve and loads the sum, so
+        the keys, the transcript, the query plan and the scalars are what
+        is checked; `msm_chip` is the whole of it, in the slow tier."""
         from spectre_tpu.builder import GateChip
         from spectre_tpu.builder.sha256_wide_chip import Sha256WideChip
         from spectre_tpu.gadgets import ssz_merkle as M
@@ -143,12 +171,17 @@ class TestInCircuitVerifier:
         srs = SRS.unsafe_setup(11)
         pk = keygen(srs, cfg, asg.fixed, asg.selectors, asg.copies)
         proof = prove(pk, srs, asg, transcript=PoseidonTranscript())
+        assert {k[0] for k in pk.vk.commitment_plan()[0]} \
+            >= {"shb", "shw"} and {k[0] for k, _ in pk.vk.query_plan()} \
+            >= {"shb", "shw", "shq", "shk"}
 
         acc = VerifierChip.native_accumulator(pk.vk, srs, asg.instances,
                                               proof)
         assert acc is not None and acc.check(srs)
         vctx = Context()
         vc = VerifierChip(RangeChip(lookup_bits=14))
+        if msm == "host_msm":
+            monkeypatch.setattr(vc.msm, "msm", _host_msm(vc))
         icells = [[vctx.load_witness(int(v)) for v in col]
                   for col in asg.instances]
         lhs, rhs = vc.verify_proof(vctx, pk.vk, srs, icells, proof)
@@ -199,12 +232,21 @@ def inner2():
 
 
 class TestMultiSnarkFold:
-    def test_fold_matches_native_accumulate(self, inner, inner2):
+    @pytest.mark.parametrize("msm", [
+        "host_msm", pytest.param("msm_chip", marks=pytest.mark.slow)])
+    def test_fold_matches_native_accumulate(self, inner, inner2, msm,
+                                            monkeypatch):
         """Two inner snarks (distinct vks) verified in-circuit; the
         transcript-bound RLC fold equals the native `accumulate` and the
         folded deferred pairing closes (reference: snark-verifier
-        aggregating Vec<Snark> with N > 1)."""
-        from spectre_tpu.models.aggregation import SnarkWitness
+        aggregating Vec<Snark> with N > 1).
+
+        The fold is what is tested here, and it runs as it is. The two
+        verifications before it repeat `test_accumulator_matches_native`'s
+        in-circuit MSM over each proof (some 20 s of Python a proof, alone
+        on a host): `host_msm` takes their sums from the host curve, as in
+        `test_sha_region_inner_proof_aggregates`; `msm_chip`, in the slow
+        tier, runs them whole."""
 
         pk1, srs, inst1, proof1 = inner
         pk2, _srs2, inst2, proof2 = inner2
@@ -216,9 +258,14 @@ class TestMultiSnarkFold:
         ctx = Context()
         vc = VerifierChip(RangeChip(lookup_bits=14))
         accs = []
-        for pk, inst, proof in ((pk1, inst1, proof1), (pk2, inst2, proof2)):
-            cells = [[ctx.load_witness(int(v)) for v in col] for col in inst]
-            accs.append(vc.verify_proof(ctx, pk.vk, srs, cells, proof))
+        with monkeypatch.context() as mp:
+            if msm == "host_msm":
+                mp.setattr(vc.msm, "msm", _host_msm(vc))
+            for pk, inst, proof in ((pk1, inst1, proof1),
+                                    (pk2, inst2, proof2)):
+                cells = [[ctx.load_witness(int(v)) for v in col]
+                         for col in inst]
+                accs.append(vc.verify_proof(ctx, pk.vk, srs, cells, proof))
         lhs, rhs = vc.fold_accumulators(ctx, accs)
         assert (lhs[0].value % P, lhs[1].value % P) == \
             (int(want.lhs[0]), int(want.lhs[1]))

@@ -468,8 +468,8 @@ class TestTraceLintDynamic:
         tree AND fits the 120s lint-deep budget on a 1-core CPU host.
 
         slow-marked: ~90s of probe compiles on the 1-core box — runs in
-        `make test` (no marker filter; lint-deep also drives the same
-        probes there), stays out of the 870s tier-1 window."""
+        `make test-slow` (no marker filter; `make test`'s lint-deep drives
+        the same probes), stays out of the 870s tier-1 window."""
         from spectre_tpu.analysis.trace_lint import PROBES, run_probes
         assert len(PROBES) == 7
         t0 = time.monotonic()

@@ -4,9 +4,8 @@ itself, for a DESCRIBED v5e:2x2 — no chip attached, nothing runs.
 The shapes are the smoke's (committee-update Minimal-32, k=14: columns of
 2^14 rows, the 2^16 extended domain) and production's (2^18). A compile that
 passes is not a chip run: it says the program lowers, fits, and which
-collectives the partitioner put in. The slow ones (msm_windows 2^14/2^18/2^21,
-msm_windows_batch: minutes each) are run by hand and recorded in CHANGES.md /
-PERF.md instead.
+collectives the partitioner put in. The slow ones (msm_windows 2^14/2^18/2^21:
+minutes each) are run by hand and recorded in CHANGES.md / PERF.md instead.
 
 The topology is described inside a module-scoped fixture: only one process at
 a time may load libtpu, and xdist workers each import every test file.

@@ -12,23 +12,16 @@ from spectre_tpu.evm.simulator import run_verifier
 from spectre_tpu.plonk.constraint_system import Assignment, CircuitConfig
 from spectre_tpu.plonk.keygen import keygen
 from spectre_tpu.plonk.prover import prove
-from spectre_tpu.plonk.srs import SRS
 from spectre_tpu.plonk.transcript import KeccakTranscript, keccak256
 from spectre_tpu.plonk.verifier import verify
 
-K = 7
+from _shapes import TINY_K as K  # noqa: E402  (the shared tiny SRS's k)
 
 
 @pytest.fixture(scope="module")
-def setup():
-    from test_plonk import _tiny_circuit
-    srs = SRS.unsafe_setup(K)
-    cfg = CircuitConfig(k=K, num_advice=1, num_lookup_advice=1, num_fixed=1,
-                        lookup_bits=4)
-    advice, lookup, fixed, selectors, copies, out = _tiny_circuit(cfg)
-    pk = keygen(srs, cfg, fixed, selectors, copies)
-    asg = Assignment(cfg, advice, lookup, fixed, selectors, [[out]], copies)
-    proof = prove(pk, srs, asg, transcript=KeccakTranscript())
+def setup(tiny):
+    srs, pk, out = tiny.srs, tiny.pk, tiny.out
+    proof = prove(pk, srs, tiny.asg, transcript=KeccakTranscript())
     assert verify(pk.vk, srs, [[out]], proof, transcript_cls=KeccakTranscript)
     src = gen_evm_verifier(pk.vk, srs, num_instances=1)
     return srs, pk, out, proof, src

@@ -201,25 +201,11 @@ class TestVm:
 
 
 @pytest.fixture(scope="module")
-def setup():
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from test_plonk import _tiny_circuit
-
-    from spectre_tpu.plonk.constraint_system import (Assignment,
-                                                     CircuitConfig)
-    from spectre_tpu.plonk.keygen import keygen
+def setup(tiny):
     from spectre_tpu.plonk.prover import prove
-    from spectre_tpu.plonk.srs import SRS
     from spectre_tpu.plonk.transcript import KeccakTranscript
-    K = 7
-    srs = SRS.unsafe_setup(K)
-    cfg = CircuitConfig(k=K, num_advice=1, num_lookup_advice=1, num_fixed=1,
-                        lookup_bits=4)
-    advice, lookup, fixed, selectors, copies, out = _tiny_circuit(cfg)
-    pk = keygen(srs, cfg, fixed, selectors, copies)
-    asg = Assignment(cfg, advice, lookup, fixed, selectors, [[out]], copies)
-    proof = prove(pk, srs, asg, transcript=KeccakTranscript())
+    srs, pk, out = tiny.srs, tiny.pk, tiny.out
+    proof = prove(pk, srs, tiny.asg, transcript=KeccakTranscript())
     src = gen_evm_verifier(pk.vk, srs, num_instances=1)
     return srs, pk, out, proof, src
 

@@ -178,10 +178,15 @@ class TestBeaconResilience:
         with pytest.raises(TimeoutError, match="total deadline"):
             c.head_block_root()
 
-    def test_breaker_trips_fails_fast_half_opens(self, beacon_server):
+    def test_breaker_trips_fails_fast_half_opens(self, beacon_server,
+                                                 monkeypatch):
         from spectre_tpu.preprocessor.beacon import CircuitBreakerOpen
         url, root = beacon_server
         c = _client(url, breaker_threshold=3, breaker_cooldown=0.05)
+        # the breaker's clock is the test's: "open" holds until the test
+        # moves it, however long the host took between two statements
+        now = [1000.0]
+        monkeypatch.setattr(c._breaker, "_clock", lambda: now[0])
         trips0 = HEALTH.get("beacon_breaker_trips")
         faults.install_plan("beacon.fetch:connreset:10")
         # 3 consecutive failures trip the breaker mid-call
@@ -195,7 +200,7 @@ class TestBeaconResilience:
         assert faults.fired_count("beacon.fetch") == 3
         # cooldown elapses -> half-open admits a trial; it fails (faults
         # still armed) and the breaker re-opens (counted as a trip)
-        time.sleep(0.06)
+        now[0] += 0.06
         assert c.breaker_state == "half-open"
         with pytest.raises(CircuitBreakerOpen):
             c.head_block_root()
@@ -205,7 +210,7 @@ class TestBeaconResilience:
         # cooldown again; disarm faults -> the half-open trial succeeds
         # and the breaker closes
         faults.clear()
-        time.sleep(0.06)
+        now[0] += 0.06
         assert c.head_block_root() == root
         assert c.breaker_state == "closed"
 
@@ -590,44 +595,6 @@ class TestJournalCompaction:
 
 
 # ---------------------------------------------------------------------------
-# fixed-base MSM table-budget degradation
-# ---------------------------------------------------------------------------
-
-class TestMsmTableBudgetDegrade:
-    def test_degrades_to_glv_signed_same_point(self, monkeypatch):
-        import jax.numpy as jnp
-        from spectre_tpu.fields import bn254 as bn
-        from spectre_tpu.ops import ec, limbs as L, msm as MSM
-
-        n = 8
-        pts = [bn.g1_curve.mul(bn.G1_GEN, k + 1) for k in range(n)]
-        pp = ec.encode_points(pts)
-        sc = [(k * 977 + 5) % bn.R for k in range(n)]
-        ss = jnp.asarray(L.ints_to_limbs16(sc))
-        want = bn.g1_curve.msm(pts, sc)
-
-        monkeypatch.setattr(MSM._TABLES, "budget", 64)   # nothing fits
-        d0 = HEALTH.get("msm_fixed_degraded")
-        builds0 = MSM._TABLES.builds
-        got = ec.decode_points(
-            MSM.msm(pp, ss, mode="fixed", base_key="degrade-test")[None])[0]
-        assert got == (int(want[0]), int(want[1]))
-        assert HEALTH.get("msm_fixed_degraded") == d0 + 1
-        assert MSM._TABLES.builds == builds0     # no table was built
-
-    # NOTE: the within-budget build path (table built + cached) is already
-    # pinned by test_msm_modes.py::TestFixedTableCache — not duplicated
-    # here to keep the fault tier inside the tier-1 time budget.
-
-    def test_table_bytes_estimate_exact(self):
-        from spectre_tpu.ops import msm as MSM
-        n, c, nbits = 8, 8, 126
-        nwin = (nbits + c) // c
-        assert MSM._fixed_table_bytes(n, c, nbits) == \
-            nwin * 2 * n * 3 * 16 * 4
-
-
-# ---------------------------------------------------------------------------
 # SRS load fault site
 # ---------------------------------------------------------------------------
 
@@ -773,36 +740,71 @@ class TestDeadlinePropagation:
         q.stop()
 
 
+class _SupervisorClock:
+    """The supervisor's injectable clock, moved by the test and never by
+    the wall: `advance` moves it, `scanned` waits until the supervisor has
+    read the new time once and looked at every slot with it."""
+
+    def __init__(self):
+        self.now = 0.0
+        self._reads = 0
+        self._cv = threading.Condition()
+
+    def __call__(self):
+        with self._cv:
+            if threading.current_thread().name == "prover-job-supervisor":
+                self._reads += 1
+                self._cv.notify_all()
+            return self.now
+
+    def advance(self, dt: float):
+        with self._cv:
+            self.now += dt
+
+    def scanned(self, timeout=30.0):
+        """Two reads from now: the second one's scan began after this call,
+        so it saw the clock as it stands."""
+        with self._cv:
+            want = self._reads + 2
+            assert self._cv.wait_for(lambda: self._reads >= want, timeout), \
+                "the supervisor stopped scanning"
+
+
 class TestWorkerSupervision:
     """A hung worker (wedged device call: heartbeat stops) is detected by
     the supervisor, its job failed(stalled), and a replacement thread takes
-    the slot — other jobs keep completing. Deterministic + fast via the
-    injectable stall_timeout / sleep_interval knobs."""
+    the slot — other jobs keep completing. Time is the injected clock's:
+    nothing here is decided by how long a sleep took on a loaded host."""
 
     def test_stalled_worker_replaced(self, tmp_path):
         from spectre_tpu.prover_service.jobs import JobQueue
+        clock = _SupervisorClock()
         release = threading.Event()
+        hung_thread = []
 
         def runner(method, params):
             if params.get("hang"):
-                release.wait(timeout=30)     # no heartbeat: presumed hung
+                hung_thread.append(threading.current_thread())
+                clock.advance(1.0)           # no heartbeat: presumed hung
+                release.wait(timeout=30)
                 return {"proof": "late"}
             return _digest_runner(method, params)
 
         q = JobQueue(runner, concurrency=1, journal_dir=str(tmp_path),
-                     stall_timeout=0.3, sleep_interval=0.05)
+                     stall_timeout=0.3, sleep_interval=0.01, clock=clock)
         r0 = HEALTH.get("workers_replaced")
         hung = q.submit("m", {"hang": True})
-        job = q.wait(hung, timeout=10)
+        job = q.wait(hung, timeout=30)
         assert job.status == "failed"
         assert job.error["kind"] == "StalledWorker"
         assert HEALTH.get("workers_replaced") == r0 + 1
         # the REPLACEMENT worker serves new jobs
         ok = q.submit("m", {"w": "after-stall"})
-        assert q.wait(ok, timeout=10).status == "done"
+        assert q.wait(ok, timeout=30).status == "done"
         # the disowned thread waking up must NOT resurrect the failed job
         release.set()
-        time.sleep(0.2)
+        hung_thread[0].join(timeout=30)
+        assert not hung_thread[0].is_alive()
         assert q.result(hung).status == "failed"
         assert q.result(ok).result == _digest_runner("m",
                                                      {"w": "after-stall"})
@@ -810,21 +812,25 @@ class TestWorkerSupervision:
 
     def test_heartbeat_keeps_slow_prove_alive(self, tmp_path):
         from spectre_tpu.prover_service.jobs import JobQueue
+        clock = _SupervisorClock()
 
         def runner(method, params, heartbeat=None):
-            # a LEGITIMATE slow prove: total 0.6s >> stall_timeout, but
-            # the phase-boundary heartbeats keep the supervisor off it
+            # a LEGITIMATE slow prove: 1.2 s of the clock in all, four
+            # times stall_timeout, but never 0.3 s without a heartbeat,
+            # and the supervisor looks at every step
             for _ in range(6):
-                time.sleep(0.1)
+                clock.advance(0.2)
+                clock.scanned()
                 heartbeat()
             return _digest_runner(method, params)
 
         q = JobQueue(runner, concurrency=1, journal_dir=str(tmp_path),
-                     stall_timeout=0.3, sleep_interval=0.05)
+                     stall_timeout=0.3, sleep_interval=0.01, clock=clock)
         r0 = HEALTH.get("workers_replaced")
         jid = q.submit("m", {"w": "slow-but-alive"})
-        assert q.wait(jid, timeout=10).status == "done"
+        assert q.wait(jid, timeout=30).status == "done"
         assert HEALTH.get("workers_replaced") == r0
+        q.stop()
 
 
 # ---------------------------------------------------------------------------

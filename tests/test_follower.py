@@ -701,27 +701,16 @@ class TestTracker:
 # -- aggregation cadence (ISSUE 18) ------------------------------------------
 
 @pytest.fixture(scope="module")
-def evm_agg_setup():
+def evm_agg_setup(tiny):
     """A real tiny-circuit proof + its generated Solidity verifier (the
     test_evm.py recipe): the canned committee prover serves THIS proof,
     so the published aggregate's bytes genuinely verify on-EVM."""
-    from test_plonk import _tiny_circuit
-
     from spectre_tpu.evm import gen_evm_verifier
-    from spectre_tpu.plonk.constraint_system import (Assignment,
-                                                     CircuitConfig)
-    from spectre_tpu.plonk.keygen import keygen
     from spectre_tpu.plonk.prover import prove
-    from spectre_tpu.plonk.srs import SRS
     from spectre_tpu.plonk.transcript import KeccakTranscript
 
-    srs = SRS.unsafe_setup(7)
-    cfg = CircuitConfig(k=7, num_advice=1, num_lookup_advice=1,
-                        num_fixed=1, lookup_bits=4)
-    advice, lookup, fixed, selectors, copies, out = _tiny_circuit(cfg)
-    pk = keygen(srs, cfg, fixed, selectors, copies)
-    asg = Assignment(cfg, advice, lookup, fixed, selectors, [[out]], copies)
-    proof = prove(pk, srs, asg, transcript=KeccakTranscript())
+    srs, pk, out = tiny.srs, tiny.pk, tiny.out
+    proof = prove(pk, srs, tiny.asg, transcript=KeccakTranscript())
     src = gen_evm_verifier(pk.vk, srs, num_instances=1)
     return out, proof, src
 

@@ -70,10 +70,14 @@ class TestInstanceParity:
         assert [c.value for c in ctx.instance_cells] == \
             CommitteeUpdateCircuit.get_instances(args, TINY)
 
+    @pytest.mark.slow
     def test_step(self):
-        # full BLS block witness gen: ~40s after the bulk/vectorization work
-        # — kept in the default tier so plain pytest exercises the flagship
-        # circuit end to end (round-1 verdict weak #3)
+        # full BLS block witness gen in Python (the pairing, hash-to-curve
+        # and 66 SHA compressions as cells): some 20 s alone on a host, 50
+        # to 100 s beside five other workers, and nothing of it can be left
+        # out with the instances still the circuit's own. Slow tier (`make
+        # test-slow`); the default tier keeps the step circuit's guards,
+        # its native instances and the committee circuit's parity.
         args = default_sync_step_args(TINY)
         ctx = StepCircuit.build_context(args, TINY)
         assert [c.value for c in ctx.instance_cells] == \

@@ -14,6 +14,11 @@ import jax.numpy as jnp
 
 from spectre_tpu.fields import bn254 as bn
 from spectre_tpu.ops import ec, glv, limbs as L, msm as MSM
+from spectre_tpu.plonk import backend as B, kzg
+from spectre_tpu.utils.health import HEALTH
+
+from _shapes import (MSM_CASES, MSM_N, MSM_N_OTHER_MODES, MSM_WINDOWS,
+                     check_msm_case, encode_msm, kernel_programs, msm_case)
 
 
 def _edge_scalars():
@@ -173,31 +178,24 @@ class TestSignedDigits:
             sum(int(d) << (c * w) for w, d in enumerate(signed)) == k
 
 
+# The modes whose kernels this file compiles. The default mode's (vanilla)
+# cases are tests/test_device_prove.py::TestDefaultMsm's, beside the tiny
+# device prove that commits with the same program: under `--dist loadfile`
+# a file is one worker's, and the kernel programs are shared out so that no
+# file waits for all of them.
+OTHER_MODES = ("glv", "glv+signed", "fixed")
+
+
 class TestMSMModes:
-    def _inputs(self, n=48):
-        pts = [bn.g1_curve.mul(bn.G1_GEN, secrets.randbelow(bn.R))
-               for _ in range(n)]
-        pts[3] = None
-        scalars = [secrets.randbelow(bn.R) for _ in range(n)]
-        scalars[0] = 0
-        scalars[1] = 1
-        scalars[2] = bn.R - 1
-        want = bn.g1_curve.msm(pts, scalars)
-        return (ec.encode_points(pts), jnp.asarray(L.ints_to_limbs16(scalars)),
-                (int(want[0]), int(want[1])))
+    """Every mode but the default against the host curve's MSM at ONE shape
+    a mode (MSM_N_OTHER_MODES points, the mode's own window there): the
+    edge inputs are rows of that shape, so a mode compiles its kernel once
+    for all of them."""
 
-    @pytest.mark.parametrize("mode", MSM.MSM_MODES)
-    def test_matches_oracle(self, mode):
-        pp, ss, want = self._inputs()
-        got = ec.decode_points(MSM.msm(pp, ss, mode=mode)[None])[0]
-        assert got == want, mode
-
-    @pytest.mark.parametrize("mode", MSM.MSM_MODES)
-    def test_all_zero_is_identity(self, mode):
-        pts = [bn.g1_curve.mul(bn.G1_GEN, k + 1) for k in range(8)]
-        pp = ec.encode_points(pts)
-        ss = jnp.asarray(L.ints_to_limbs16([0] * 8))
-        assert ec.decode_points(MSM.msm(pp, ss, mode=mode)[None])[0] is None
+    @pytest.mark.parametrize("case", MSM_CASES)
+    @pytest.mark.parametrize("mode", OTHER_MODES)
+    def test_matches_oracle(self, mode, case):
+        check_msm_case(mode, case)
 
     def test_env_mode_dispatch(self, monkeypatch):
         monkeypatch.setenv("SPECTRE_MSM_MODE", "glv+signed")
@@ -206,25 +204,17 @@ class TestMSMModes:
         with pytest.raises(ValueError):
             MSM.msm_mode()
 
-    def test_batch_modes_match_single(self):
-        n, m = 24, 3
-        pts = [bn.g1_curve.mul(bn.G1_GEN, k + 1) for k in range(n)]
-        pp = ec.encode_points(pts)
-        scs = [[(i * 131 + k * 7 + 1) % bn.R for k in range(n)]
-               for i in range(m)]
-        batch = jnp.stack([jnp.asarray(L.ints_to_limbs16(sc)) for sc in scs])
-        for mode in ("glv", "glv+signed", "fixed"):
-            got = ec.decode_points(MSM.msm_batch(pp, batch, mode=mode))
-            for sc, g_pt in zip(scs, got):
-                want = bn.g1_curve.msm(pts, sc)
-                assert g_pt == (int(want[0]), int(want[1])), mode
+
+@pytest.fixture(scope="module", autouse=True)
+def programs_before():
+    """Programs each mode's kernel held when this file's first test began
+    (another file's, where one process or one worker ran it first)."""
+    return kernel_programs()
 
 
 class TestFixedTableCache:
     def test_hit_and_key_separation(self):
-        pts = ec.encode_points(
-            [bn.g1_curve.mul(bn.G1_GEN, k + 1) for k in range(8)])
-        ss = jnp.asarray(L.ints_to_limbs16([k * 3 + 1 for k in range(8)]))
+        pts, ss = encode_msm(*msm_case("random", "fixed"))
         MSM.msm(pts, ss, mode="fixed", base_key="t-cache-a")
         builds0, hits0 = MSM._TABLES.builds, MSM._TABLES.hits
         MSM.msm(pts, ss, mode="fixed", base_key="t-cache-a")
@@ -307,17 +297,6 @@ class TestWindowOverride:
         with pytest.raises(ValueError):
             MSM.window_override()
 
-    def test_override_result_unchanged(self, monkeypatch):
-        """An overridden window changes the work shape, never the point."""
-        pts = ec.encode_points(
-            [bn.g1_curve.mul(bn.G1_GEN, 3 * k + 1) for k in range(8)])
-        ss = jnp.asarray(L.ints_to_limbs16([k * 5 + 2 for k in range(8)]))
-        want = np.asarray(MSM.msm(pts, ss, mode="vanilla"))
-        monkeypatch.setenv("SPECTRE_MSM_WINDOW", "3")
-        got = np.asarray(MSM.msm(pts, ss, mode="vanilla"))
-        assert ec.decode_points(jnp.asarray(got)[None]) == \
-            ec.decode_points(jnp.asarray(want)[None])
-
 
 class TestImplDispatch:
     """SPECTRE_MSM_IMPL: xla (default) vs the pallas SoA kernel path."""
@@ -378,7 +357,7 @@ class TestImplDispatch:
         ZERO msm_pallas_unsupported_mode events, and never round-trips
         scalars through the host GLV decomposition (decompose_limbs16 is
         poisoned for the duration). slow marker = the four interpret-mode
-        compile chains (~40s, 1-core box); `make test` runs it (plain
+        compile chains (~40s, 1-core box); `make test-slow` runs it (plain
         pytest, no marker filter) — the 870s driver tier keeps only the
         structural pins above."""
         events = []
@@ -409,20 +388,6 @@ class TestImplDispatch:
             assert got == want, mode
         assert not [e for e in events
                     if e[0] == "msm_pallas_unsupported_mode"], events
-
-    @pytest.mark.slow
-    def test_pallas_batch_matches_oracle(self, monkeypatch):
-        monkeypatch.setenv("SPECTRE_MSM_IMPL", "pallas")
-        n, m = 6, 2
-        pts = [bn.g1_curve.mul(bn.G1_GEN, k + 1) for k in range(n)]
-        pp = ec.encode_points(pts)
-        scs = [[(i * 131 + k * 7 + 1) % bn.R for k in range(n)]
-               for i in range(m)]
-        batch = jnp.stack([jnp.asarray(L.ints_to_limbs16(sc)) for sc in scs])
-        got = ec.decode_points(MSM.msm_batch(pp, batch, c=3, mode="glv"))
-        for sc, g_pt in zip(scs, got):
-            want = bn.g1_curve.msm(pts, sc)
-            assert g_pt == (int(want[0]), int(want[1]))
 
     def test_dp_runner_records_degrade_event(self, monkeypatch):
         """The DP shard_map runner stays XLA: under impl=pallas it must
@@ -455,3 +420,113 @@ class TestImplDispatch:
         detail = kinds[0][1]
         assert detail["n"] == 8 and detail["c"] == 2
         assert detail["site"] == "parallel.batch_msm_dp"
+
+
+def _seeded_poly(n: int):
+    """n coefficients in the backends' u64-limb form, the same every run."""
+    import random
+    rng = random.Random(0xD16E57)
+    return B.to_arr([rng.randrange(bn.R) for _ in range(n)])
+
+
+class TestMsmModeCommitments:
+    """The ISSUE-2 correctness gate: KZG commitments through the device
+    backend are byte-identical across every MSM mode (GLV, signed digits,
+    fixed-base tables) AND match the native CPU oracle — the modes change
+    work shape, never the committed group element. Commitment-level (not
+    full-prove) in the default tier on purpose: this box's XLA CPU client
+    segfaults in LLVM under repeated full-prove compile churn; the
+    full-prove cross-mode equality is the SPECTRE_BYTEEQ_FULL tier in
+    tests/test_plonk.py::TestBackendByteEquality. A polynomial of
+    MSM_N_OTHER_MODES coefficients under the tiny SRS: the mode cases'
+    programs. The default mode through this backend is
+    tests/test_device_prove.py's, call by call (`TestOneChipBatchedCommit`,
+    the `msm` cases of its spans)."""
+
+    def test_msm_mode_commitments_byte_identical(self, tiny, monkeypatch):
+        srs, coeffs = tiny.srs, _seeded_poly(MSM_N_OTHER_MODES)
+        oracle = kzg.commit(srs, coeffs, B.get_backend("cpu"))
+        bk = B.get_backend("tpu")
+        for mode in OTHER_MODES:
+            monkeypatch.setenv("SPECTRE_MSM_MODE", mode)
+            got = kzg.commit(srs, coeffs, bk)
+            assert got == oracle, \
+                f"SPECTRE_MSM_MODE={mode} commitment diverged from oracle"
+
+    @pytest.mark.slow
+    def test_pallas_impl_commitments_byte_identical(self, tiny, monkeypatch):
+        """ISSUE 17 tier of the same gate, impl axis: every mode under
+        SPECTRE_MSM_IMPL=pallas (interpret mode off-TPU) commits to the
+        SAME bytes as the CPU oracle through the device backend, and none
+        of the four modes falls back to XLA (zero unsupported-mode
+        events). Slow tier: four interpret-mode pallas compile chains at
+        K=7 cost ~100s on the 1-core box; the fast tier covers the same
+        matrix at MSM level in test_msm_modes."""
+        srs, coeffs = tiny.srs, _seeded_poly(tiny.srs.n)
+        oracle = kzg.commit(srs, coeffs, B.get_backend("cpu"))
+        events = []
+        orig = MSM._record_event
+        monkeypatch.setattr(
+            MSM, "_record_event",
+            lambda name, **kw: (events.append((name, kw)),
+                                orig(name, **kw)))
+        monkeypatch.setenv("SPECTRE_MSM_IMPL", "pallas")
+        bk = B.get_backend("tpu")
+        for mode in ("glv+signed", "glv", "fixed", "vanilla"):
+            monkeypatch.setenv("SPECTRE_MSM_MODE", mode)
+            got = kzg.commit(srs, coeffs, bk)
+            assert got == oracle, \
+                f"impl=pallas mode={mode} commitment diverged from oracle"
+        bad = [e for e in events if e[0] == "msm_pallas_unsupported_mode"]
+        assert not bad, f"pallas path degraded to XLA: {bad}"
+
+
+class TestMsmTableBudgetDegrade:
+    """A fixed-base table over the budget degrades the call to glv+signed
+    (fault tier, ISSUE 3; beside the kernels it falls back to, so that no
+    other file compiles them)."""
+
+    def test_degrades_to_glv_signed_same_point(self, monkeypatch):
+        # (the glv+signed program it degrades to is the mode cases')
+        pts, sc = msm_case("random", "glv+signed")
+        pp, ss = encode_msm(pts, sc)
+        want = bn.g1_curve.msm(pts, sc)
+
+        monkeypatch.setattr(MSM._TABLES, "budget", 64)   # nothing fits
+        d0 = HEALTH.get("msm_fixed_degraded")
+        builds0 = MSM._TABLES.builds
+        got = ec.decode_points(
+            MSM.msm(pp, ss, mode="fixed", base_key="degrade-test")[None])[0]
+        assert got == (int(want[0]), int(want[1]))
+        assert HEALTH.get("msm_fixed_degraded") == d0 + 1
+        assert MSM._TABLES.builds == builds0     # no table was built
+
+    def test_table_bytes_estimate_exact(self):
+        n, c, nbits = 8, 8, 126
+        nwin = (nbits + c) // c
+        assert MSM._fixed_table_bytes(n, c, nbits) == \
+            nwin * 2 * n * 3 * 16 * 4
+
+
+class TestKernelShapesPinned:
+    """The sharing itself: this file leaves each mode's kernel compiled for
+    ONE (n, c), the shared one. A case that brings a shape of its own adds
+    tens of seconds to a run with a cold compile cache; it shows up here as
+    a failure and not there as a slower suite. The last class of the file:
+    it runs after every class that calls a kernel."""
+
+    @pytest.mark.parametrize("mode", OTHER_MODES)
+    def test_one_program_a_mode(self, programs_before, mode):
+        # the shared shape once more: a hit after the mode cases, the one
+        # compile where this class runs alone
+        MSM.msm(*encode_msm(*msm_case("skewed", mode)), mode=mode)
+        assert kernel_programs()[mode] - programs_before[mode] <= 1, \
+            f"{mode}: a test of this file compiled another (n, c)"
+
+    def test_the_default_windows_are_the_shared_ones(self):
+        n = MSM_N_OTHER_MODES
+        assert MSM_WINDOWS == {
+            "vanilla": MSM.default_window(MSM_N),
+            "glv": MSM.default_window(2 * n),
+            "glv+signed": MSM.default_window(2 * n, signed=True),
+            "fixed": MSM.default_window_fixed(2 * n)}

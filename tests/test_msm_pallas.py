@@ -159,7 +159,7 @@ class TestBucketKernel:
         """One window, signed digits + GLV signs: the kernel body's bucket
         array must equal per-bucket ec sums of the (conditionally negated)
         points. slow marker: the nested fori_loop body costs a ~40s
-        XLA-CPU compile; `make test` (no marker filter) runs it."""
+        XLA-CPU compile; `make test-slow` (no marker filter) runs it."""
         a, _ = batch
         n = a.shape[0]
         nb = 4

@@ -125,30 +125,13 @@ class TestKernelDispatch:
 
 
 def _tiny_circuit():
-    """The k=7 gate+lookup shape shared with test_ntt_modes/test_plonk."""
-    from spectre_tpu.plonk.constraint_system import Assignment, CircuitConfig
+    """The tiny gate+lookup circuit at the shape every prove test shares
+    (tests/_shapes.py)."""
+    from _shapes import tiny_circuit, tiny_config
+    from spectre_tpu.plonk.constraint_system import Assignment
 
-    k = 7
-    cfg = CircuitConfig(k=k, num_advice=1, num_lookup_advice=1,
-                        num_fixed=1, lookup_bits=4)
-    n = cfg.n
-    x_w, y_w = 7, 3
-    out = x_w + x_w * y_w
-    advice = [[0] * n for _ in range(cfg.num_advice)]
-    advice[0][0], advice[0][1], advice[0][2], advice[0][3] = \
-        x_w, x_w, y_w, out
-    advice[0][4] = 5
-    selectors = [[0] * n for _ in range(cfg.num_advice)]
-    selectors[0][0] = 1
-    lookup = [[0] * n for _ in range(cfg.num_lookup_advice)]
-    lookup[0][0] = x_w
-    fixed = [[0] * n for _ in range(cfg.num_fixed)]
-    fixed[0][0] = 5
-    copies = [
-        ((cfg.col_instance(0), 0), (cfg.col_gate_advice(0), 3)),
-        ((cfg.col_fixed(0), 0), (cfg.col_gate_advice(0), 4)),
-        ((cfg.col_gate_advice(0), 0), (cfg.col_lookup_advice(0), 0)),
-    ]
+    cfg = tiny_config()
+    advice, lookup, fixed, selectors, copies, out = tiny_circuit(cfg)
     asg = Assignment(cfg, advice, lookup, fixed, selectors, [[out]], copies)
     return cfg, asg, fixed, selectors, copies, [[out]]
 
@@ -168,7 +151,7 @@ class TestKernelProofBytes:
     byte-identity matrix above, so the expensive keygen runs once.
 
     slow-marked: ~4 min of prove wall-clock on the 1-core box — runs in
-    `make test` (no marker filter), stays out of the 870s tier-1 window
+    `make test-slow` (no marker filter), stays out of the 870s tier-1 window
     like test_integrity's heavy drills."""
 
     @pytest.mark.slow

@@ -189,60 +189,3 @@ class TestTwiddleTableLRU:
     def test_budget_env_override(self, monkeypatch):
         monkeypatch.setenv("SPECTRE_NTT_TABLE_MB", "3")
         assert NTT._table_budget_bytes() == 3 << 20
-
-
-class TestNttModeProofBytes:
-    """The ISSUE-4 correctness gate, mirroring TestMsmModeCommitments:
-    radix2 and fourstep must yield BYTE-IDENTICAL proofs through the device
-    backend under seeded blinding — the modes change kernel work shape,
-    never a single transformed value. Runs the tiny k=7 circuit shape
-    shared with test_plonk's prove suites (warm compile cache)."""
-
-    def test_proof_bytes_identical_across_ntt_modes(self, monkeypatch):
-        import random
-
-        from spectre_tpu.plonk import backend as B
-        from spectre_tpu.plonk.constraint_system import (Assignment,
-                                                         CircuitConfig)
-        from spectre_tpu.plonk.keygen import keygen
-        from spectre_tpu.plonk.prover import prove
-        from spectre_tpu.plonk.srs import SRS
-        from spectre_tpu.plonk.verifier import verify
-
-        def seeded():
-            r = random.Random(0x177E57)
-            return lambda: r.randrange(R)
-
-        k = 7
-        srs = SRS.unsafe_setup(k)
-        cfg = CircuitConfig(k=k, num_advice=1, num_lookup_advice=1,
-                            num_fixed=1, lookup_bits=4)
-        n = cfg.n
-        x_w, y_w = 7, 3
-        out = x_w + x_w * y_w
-        advice = [[0] * n for _ in range(cfg.num_advice)]
-        advice[0][0], advice[0][1], advice[0][2], advice[0][3] = \
-            x_w, x_w, y_w, out
-        advice[0][4] = 5
-        selectors = [[0] * n for _ in range(cfg.num_advice)]
-        selectors[0][0] = 1
-        lookup = [[0] * n for _ in range(cfg.num_lookup_advice)]
-        lookup[0][0] = x_w
-        fixed = [[0] * n for _ in range(cfg.num_fixed)]
-        fixed[0][0] = 5
-        copies = [
-            ((cfg.col_instance(0), 0), (cfg.col_gate_advice(0), 3)),
-            ((cfg.col_fixed(0), 0), (cfg.col_gate_advice(0), 4)),
-            ((cfg.col_gate_advice(0), 0), (cfg.col_lookup_advice(0), 0)),
-        ]
-        asg = Assignment(cfg, advice, lookup, fixed, selectors, [[out]],
-                         copies)
-        bk = B.get_backend("tpu")
-        proofs = {}
-        for mode in NTT.NTT_MODES:
-            monkeypatch.setenv("SPECTRE_NTT_MODE", mode)
-            pk = keygen(srs, cfg, fixed, selectors, copies, bk)
-            proofs[mode] = prove(pk, srs, asg, bk, blinding_rng=seeded())
-            assert verify(pk.vk, srs, [[out]], proofs[mode]), mode
-        assert proofs["radix2"] == proofs["fourstep"], \
-            "SPECTRE_NTT_MODE changed proof bytes (modes must be identical)"

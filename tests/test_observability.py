@@ -640,318 +640,3 @@ class TestIntegrityCounters:
         PHASE_SECONDS.labels(phase="prove/self_verify").observe(0.001)
         kids = PHASE_SECONDS.children()
         assert any(k.labels == {"phase": "prove/self_verify"} for k in kids)
-
-
-# ---------------------------------------------------------------------------
-# spans at the device boundary (ISSUE 29). No test here asserts a time:
-# names, order, containment and counts only.
-
-PROVE_PHASES = {
-    "prove/aggregation", "prove/app_snark", "prove/commit_advice",
-    "prove/commit_h", "prove/cross_verify", "prove/evals",
-    "prove/grand_products", "prove/instance_polys", "prove/lookup_permute",
-    "prove/multiopen", "prove/quotient", "prove/self_verify"}
-# the stages of one call, in order, by the last segment of their names
-MSM_STAGES = ["encode", "dispatch", "dispatch", "wait", "decode"]
-# the batched mesh branch reads the mesh result whole and sends it back up
-MESH_MSM_STAGES = ["encode", "dispatch", "wait", "dispatch", "wait", "decode"]
-# one device, default mode: a run's window phases, its combine and its affine
-# conversion are enqueued together and read once (`TpuBackend._msm_chunks`)
-CHUNK_MSM_STAGES = ["encode", "dispatch", "wait", "decode"]
-MSM_WIDTH = 16       # `ops/msm.py:CHUNK_WIDTH`
-TWO_CROSSINGS = ["encode", "dispatch", "wait",
-                 "encode", "dispatch", "wait", "decode"]
-
-
-def _walk(s):
-    yield s
-    for c in s.children:
-        yield from _walk(c)
-
-
-def _stages(call):
-    """The call's stage children (a first prove's `compile/*` children of a
-    dispatch are not stages)."""
-    return [c for c in call.children if c.name.startswith(call.name + "/")]
-
-
-def _check_in_flight(call, stages):
-    """Every wait is preceded by a dispatch since the last wait, and each
-    stretch from that dispatch to the wait's end lies inside the call."""
-    opened = None
-    for s in stages:
-        last = s.name.rsplit("/", 1)[-1]
-        if last == tracing.DISPATCH and opened is None:
-            opened = s
-        elif last == tracing.WAIT:
-            assert opened is not None, f"{s.name}: a wait with no dispatch"
-            assert call.t0 <= opened.t0 <= s.t1 <= call.t1
-            opened = None
-
-
-@pytest.fixture(scope="module")
-def tiny_tpu_prove():
-    """One k=6 prove on TpuBackend (XLA:CPU here) under a trace, on a 1x1
-    mesh as the one-chip cell runs it, with the same seeded blinding on
-    CpuBackend beside it."""
-    import random
-
-    from spectre_tpu.fields import bn254
-    from spectre_tpu.plonk import backend as B
-    from spectre_tpu.plonk.prover import prove
-    from spectre_tpu.prover_service.selfverify import _tiny_setup
-
-    pk, srs, asg, _ = _tiny_setup()
-
-    def rng():
-        r = random.Random(29)
-        return lambda: r.randrange(bn254.R)
-
-    bk = B.get_backend("tpu")
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setenv("SPECTRE_MESH_SHAPE", "1x1")
-        with tracing.trace("tiny-tpu-prove") as tr:
-            proof = prove(pk, srs, asg, bk, blinding_rng=rng())
-    want = prove(pk, srs, asg, B.get_backend("cpu"), blinding_rng=rng())
-    return types.SimpleNamespace(trace=tr, proof=proof, cpu_proof=want,
-                                 pk=pk, srs=srs, bk=bk)
-
-
-class TestDeviceBoundarySpans:
-    def test_proof_bytes_equal_cpu_backend(self, tiny_tpu_prove):
-        assert tiny_tpu_prove.proof == tiny_tpu_prove.cpu_proof
-
-    @pytest.mark.parametrize("op,mesh,want", [
-        ("msm", None, MSM_STAGES), ("msm", "1x1", CHUNK_MSM_STAGES),
-        ("ntt", None, TWO_CROSSINGS), ("intt_many", None, TWO_CROSSINGS),
-        ("msm_many", None, MESH_MSM_STAGES),
-        ("msm_many", "1x1", CHUNK_MSM_STAGES)])
-    def test_backend_call_has_its_stages_in_order(self, tiny_tpu_prove, op,
-                                                  mesh, want, monkeypatch):
-        import numpy as np
-
-        from spectre_tpu.parallel.plan import current_plan
-        from spectre_tpu.plonk import backend as B
-
-        t = tiny_tpu_prove
-        n = t.pk.vk.config.n
-        arr = B.to_arr(range(1, n + 1))
-        omega = t.pk.vk.domain.omega
-        cpu_point = B.get_backend("cpu").msm(t.srs.g1_powers, arr)
-        if mesh:
-            monkeypatch.setenv("SPECTRE_MESH_SHAPE", mesh)
-        one_chip = current_plan().n_devices == 1
-        if op == "msm_many" and one_chip and not mesh:
-            pytest.skip("the mesh branch of msm_many needs a mesh")
-        with tracing.trace(f"one-{op}") as tr:
-            if op == "msm":
-                assert t.bk.msm(t.srs.g1_powers, arr) == cpu_point
-            elif op == "msm_many":
-                assert t.bk.msm_many(t.srs.g1_powers, [arr, arr]) \
-                    == [cpu_point, cpu_point]
-            elif op == "ntt":
-                got = t.bk.ntt(arr, omega)
-                assert np.array_equal(got, B.get_backend("cpu").ntt(arr, omega))
-            else:
-                got = t.bk.intt_many([arr, arr, arr], omega)
-                assert len(got) == 3 and np.array_equal(
-                    got[2], B.get_backend("cpu").intt(arr, omega))
-        (call,) = tr.root.children
-        assert call.name == f"backend/{op}"
-        assert call.meta["n"] == n
-        stages = _stages(call)
-        assert [s.name for s in stages] == [f"backend/{op}/{w}" for w in want]
-        _check_in_flight(call, stages)
-        # what is shipped and what comes back, from the shapes: 64 bytes a
-        # field element as 16 u32 limbs; a batch of 3 is padded to 4; a
-        # run of the one-chip MSM path ships its columns and reads the
-        # affine points (x, y, z) of its whole width
-        rows = n * (4 if op == "intt_many" else 1)
-        got = tracing.summary(tr)
-        moved = got["transfer_bytes"]
-        if one_chip and op in ("msm", "msm_many"):
-            batch = 1 if op == "msm" else 2
-            assert (call.meta["batch"], call.meta["width"]) \
-                == (batch, MSM_WIDTH)
-            assert moved == {"h2d": 64 * n * batch,
-                             "d2h": MSM_WIDTH * 3 * 64}
-            assert got["msm_columns"] == {"real": batch,
-                                          "padded": MSM_WIDTH - batch}
-        elif op == "msm":
-            assert moved == {"h2d": 64 * n, "d2h": 3 * 64}
-            assert got["msm_columns"] == {"real": 0, "padded": 0}
-        elif op == "msm_many":
-            assert call.meta["batch"] == 2 and moved["d2h"] == 2 * 6 * 64
-        else:
-            assert moved == {"h2d": 2 * 64 * rows, "d2h": 2 * 64 * rows}
-
-    def test_every_call_of_a_prove_has_its_stages(self, tiny_tpu_prove):
-        calls = [s for s in _walk(tiny_tpu_prove.trace.root)
-                 if s.name.startswith("backend/") and s.name.count("/") == 1]
-        assert {c.name for c in calls} == {
-            "backend/msm", "backend/msm_many", "backend/ntt", "backend/intt",
-            "backend/intt_many"}
-        for call in calls:
-            want = CHUNK_MSM_STAGES if "msm" in call.name else TWO_CROSSINGS
-            stages = _stages(call)
-            assert [s.name.rsplit("/", 1)[-1] for s in stages] == want
-            _check_in_flight(call, stages)
-
-    def test_quotient_is_one_queue_with_one_read(self, tiny_tpu_prove):
-        (q,) = [s for s in _walk(tiny_tpu_prove.trace.root)
-                if s.name == "prove/quotient"]
-        # (a listener another test installed may add `compile/*` children)
-        parts = [c for c in q.children if c.name.startswith("quotient/")]
-        assert [c.name for c in parts] == [
-            "quotient/extend", "quotient/expressions", "quotient/wait",
-            "quotient/decode"]
-        extend = _stages(parts[0])
-        assert [s.name for s in extend] == [
-            "quotient/extend/encode", "quotient/extend/dispatch"] \
-            * (len(extend) // 2)
-        # in flight from the first LDE dispatch to the end of the one read
-        waits = [s for s in _walk(q) if s.name.endswith("/wait")]
-        assert [s.name for s in waits] == ["quotient/wait"]
-        assert q.t0 <= extend[1].t0 <= waits[0].t1 <= q.t1
-        assert not [s for s in _walk(parts[1])
-                    if s.name.endswith(("/wait", "/decode"))]
-
-    def test_only_the_phases_are_named_prove(self, tiny_tpu_prove):
-        import glob
-        import os
-
-        import spectre_tpu
-
-        names = {s.name for s in _walk(tiny_tpu_prove.trace.root)}
-        assert {n for n in names if n.startswith("prove/")} \
-            <= PROVE_PHASES
-        assert {"job/blind", "commit/marshal", "grand_products/perm_chunk",
-                "grand_products/lookup", "evals/horner", "multiopen/h_poly",
-                "multiopen/h_poly/remainder", "multiopen/linearisation",
-                "multiopen/w2_division"} <= names
-        # and in the source: `prove/...` is opened by phase(), never span()
-        root = os.path.dirname(spectre_tpu.__file__)
-        for path in glob.glob(os.path.join(root, "**", "*.py"),
-                              recursive=True):
-            with open(path) as f:
-                src = f.read()
-            assert not re.search(r'\bspan\(\s*f?"prove/', src), path
-            for name in re.findall(r'phase\(\s*f?"(prove/[^"]*)"', src):
-                assert name in PROVE_PHASES, (path, name)
-
-    def test_span_counts_and_transfer_bytes_from_the_shapes(
-            self, tiny_tpu_prove):
-        from spectre_tpu.observability import manifest
-        from spectre_tpu.plonk.constraint_system import NUM_H_CHUNKS
-        from spectre_tpu.plonk.quotient_device import _ext_chunk
-
-        t = tiny_tpu_prove
-        cfg = t.pk.vk.config
-        got = tracing.summary(t.trace)
-        counts, moved = got["span_counts"], got["transfer_bytes"]
-        man = manifest.build(job_id="j", method="m", trace=t.trace)
-        assert man["span_counts"] == counts
-        assert man["transfer_bytes"] == moved
-        assert set(man["phase_seconds"]) == set(counts)
-        # one MSM a committed column: advice and lookup advice, two permuted
-        # columns a lookup, a grand product a permutation chunk and a
-        # lookup, the quotient's chunks, W1 and W2; all but W1 and W2 reach
-        # the backend in lists, each list one run of MSM_WIDTH columns here
-        commits = (cfg.num_advice + cfg.num_lookup_advice
-                   + 2 * cfg.num_lookup_advice
-                   + cfg.num_perm_chunks + cfg.num_lookup_advice
-                   + NUM_H_CHUNKS + 2)
-        assert counts["backend/msm"] == 2
-        runs = counts["backend/msm"] + counts["backend/msm_many"]
-        assert got["msm_columns"] == man["msm_columns"] == {
-            "real": commits, "padded": MSM_WIDTH * runs - commits}
-        real = 0
-        for s in _walk(t.trace.root):
-            if s.name in ("backend/msm", "backend/msm_many"):
-                assert s.meta["width"] == MSM_WIDTH
-                assert 1 <= s.meta["batch"] <= MSM_WIDTH
-                real += s.meta["batch"]
-        assert real == commits
-        for op in ("msm", "msm_many"):
-            c = counts[f"backend/{op}"]
-            assert [counts[f"backend/{op}/{w}"] for w in CHUNK_MSM_STAGES] \
-                == [c] * 4
-        for op in ("ntt", "intt", "intt_many"):
-            c = counts[f"backend/{op}"]
-            assert counts[f"backend/{op}/encode"] == 2 * c
-            assert counts[f"backend/{op}/wait"] == 2 * c
-            assert counts[f"backend/{op}/decode"] == c
-        assert counts["job/blind"] == counts["quotient/wait"] == 1
-        assert counts["grand_products/perm_chunk"] == cfg.num_perm_chunks
-        assert counts["grand_products/lookup"] == cfg.num_lookup_advice
-        # bytes: 64 a field element either way; an NTT kind ships its rows
-        # up twice and down twice; a batch is padded to a power of two;
-        # the quotient ships 3 synthetic rows, then whole chunks, and reads
-        # the extended domain once
-        n, m = cfg.n, t.pk.vk.domain.n_ext
-        rows = n * (counts["backend/ntt"] + counts["backend/intt"])
-        for s in _walk(t.trace.root):
-            if s.name == "backend/intt_many":
-                rows += n * (1 << (s.meta["batch"] - 1).bit_length())
-        lde_rows = 3 + _ext_chunk(m) * (counts["quotient/extend/encode"] - 1)
-        assert moved == {
-            "h2d": 64 * (n * commits + 2 * rows + m * lde_rows),
-            "d2h": 64 * (3 * MSM_WIDTH * runs + 2 * rows + m)}
-
-    def test_span_meta_is_allocated_on_first_use(self):
-        with tracing.trace("t-meta") as tr:
-            with tracing.span("bare"):
-                pass
-            with tracing.span("shipped", bytes=8):
-                tracing.annotate(n=2)
-        bare, shipped = tr.root.children
-        assert bare.meta is None and tr.root.meta is None
-        assert shipped.meta == {"bytes": 8, "n": 2}
-        ev = {e["name"]: e for e in tracing.chrome_trace(tr)["traceEvents"]}
-        assert "args" not in ev["bare"]
-        assert ev["shipped"]["args"] == {"bytes": 8, "n": 2}
-
-    def test_profiler_session_holds_the_program_s_annotations(
-            self, tiny_tpu_prove, tmp_path):
-        """The shared clock: a jax.profiler session round one ntt, outside
-        every job trace, holds the call and its stages by name."""
-        import glob
-
-        import jax
-        from jax.profiler import ProfileData
-
-        from spectre_tpu.plonk import backend as B
-
-        t = tiny_tpu_prove
-        arr = B.to_arr(range(t.pk.vk.config.n))
-        assert tracing.active() is None
-        jax.profiler.start_trace(str(tmp_path))
-        try:
-            t.bk.ntt(arr, t.pk.vk.domain.omega)
-        finally:
-            jax.profiler.stop_trace()
-        (pb,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
-                          recursive=True)
-        seen: dict = {}
-        for plane in ProfileData.from_file(pb).planes:
-            for line in plane.lines:
-                for ev in line.events:
-                    if ev.name.startswith("backend/"):
-                        seen[ev.name] = seen.get(ev.name, 0) + 1
-        assert seen == {"backend/ntt": 1, "backend/ntt/encode": 2,
-                        "backend/ntt/dispatch": 2, "backend/ntt/wait": 2,
-                        "backend/ntt/decode": 1}
-
-    def test_no_jitted_program_of_the_served_path_is_a_lambda(
-            self, tiny_tpu_prove):
-        from spectre_tpu.plonk import backend as B
-        from spectre_tpu.plonk import quotient_device as QD
-
-        B._mont_fns()
-        names = {k: fn.__name__ for k, fn in B._mont_jits.items()}
-        assert names == {"to": "to_mont_fr", "from": "from_mont_fr",
-                         "toq": "to_mont_fq"}
-        helpers = {k: fn.__name__ for k, fn in QD._helpers().items()}
-        assert len(helpers) == 8
-        assert all(n.startswith("quotient_") for n in helpers.values())
-        assert len(set(helpers.values())) == 8     # one name a program

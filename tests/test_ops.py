@@ -10,8 +10,10 @@ import jax
 import jax.numpy as jnp
 
 from spectre_tpu.fields import bn254 as bn
-from spectre_tpu.ops import ec, field_ops as F, limbs as L, msm as MSM
+from spectre_tpu.ops import ec, field_ops as F, limbs as L
 from spectre_tpu.ops import ntt as NTT, poseidon as POS, sha256 as SHA
+
+from _shapes import MSM_N_OTHER_MODES, check_msm_case
 
 
 def rand_fr(n):
@@ -113,45 +115,17 @@ class TestEC:
         want = [bn.g1_curve.add(a, b) for a, b in zip(pts_a, pts_b)]
         assert got == [None if w is None else (int(w[0]), int(w[1])) for w in want]
 
-    def test_scalar_mul(self):
-        got = ec.decode_points(jax.jit(lambda p: ec.scalar_mul(p, 999))(
-            ec.encode_points([bn.G1_GEN])))
-        w = bn.g1_curve.mul(bn.G1_GEN, 999)
-        assert got == [(int(w[0]), int(w[1]))]
 
-
-class TestMSM:
-    def _run(self, pts, scalars, c=None):
-        pp = ec.encode_points(pts)
-        ss = jnp.asarray(L.ints_to_limbs16(scalars))
-        got = ec.decode_points(MSM.msm(pp, ss, c)[None])[0]
-        want = bn.g1_curve.msm(pts, scalars)
-        want = None if want is None else (int(want[0]), int(want[1]))
-        assert got == want
-
-    def test_random(self):
-        n = 64
-        g = bn.G1_GEN
-        pts = [bn.g1_curve.mul(g, secrets.randbelow(bn.R)) for _ in range(n)]
-        pts[3] = None
-        scalars = rand_fr(n)
-        scalars[5] = 0
-        self._run(pts, scalars)
-
-    def test_skewed_scalars(self):
-        # all-equal scalars: the adversarial case for padded-bucket designs
-        n = 64
-        pts = [bn.g1_curve.mul(bn.G1_GEN, k + 1) for k in range(n)]
-        self._run(pts, [7] * n)
-
-    def test_all_zero(self):
-        pts = [bn.g1_curve.mul(bn.G1_GEN, k + 1) for k in range(8)]
-        pp = ec.encode_points(pts)
-        ss = jnp.asarray(L.ints_to_limbs16([0] * 8))
-        assert ec.decode_points(MSM.msm(pp, ss, 4)[None])[0] is None
-
-    def test_single_point(self):
-        self._run([bn.G1_GEN], [secrets.randbelow(bn.R)], c=4)
+class TestMSMWindowOverride:
+    def test_override_result_unchanged(self, monkeypatch):
+        """An overridden window changes the work shape, never the point:
+        the default mode under SPECTRE_MSM_WINDOW=3 against the host
+        curve's MSM. A program of its own whatever the shape (no other
+        test runs `msm_windows` at c = 3), so at the small one: some 15 s
+        with a cold compile cache. The default window's cases are
+        tests/test_device_prove.py::TestDefaultMsm."""
+        monkeypatch.setenv("SPECTRE_MSM_WINDOW", "3")
+        check_msm_case("vanilla", "random", n=MSM_N_OTHER_MODES)
 
 
 class TestSHA256:
@@ -216,29 +190,6 @@ class TestPoseidon:
         assert sp.squeeze() == 0x1B7F414A1AC0F4662FA50E8BA7BD7ED853D2591C20DF0ED3F4610CCDC9048C9E
         assert POS.permute_native([0] * 12)[0] == \
             0x24DA301E2F781BD5A7CD94470F24A69843EEEF45AE7FAE411482F431567A2A44
-
-
-class TestMSMBatch:
-    def test_matches_single(self):
-        n, m = 32, 3
-        g = bn.G1_GEN
-        pts = [bn.g1_curve.mul(g, k + 1) for k in range(n)]
-        pp = ec.encode_points(pts)
-        scs = [[(i * 131 + k * 7 + 1) % bn.R for k in range(n)] for i in range(m)]
-        batch = jnp.stack([jnp.asarray(L.ints_to_limbs16(sc)) for sc in scs])
-        res = MSM.msm_batch(pp, batch, c=4)
-        got = ec.decode_points(res)
-        for sc, g_pt in zip(scs, got):
-            want = bn.g1_curve.msm(pts, sc)
-            assert g_pt == (int(want[0]), int(want[1]))
-        # the one-chip commit path's shape: a window phase a column, the
-        # combine at a fixed width with identity window sums for the rest
-        wins = tuple(MSM.msm_windows(pp, sc, 4) for sc in batch)
-        padded = MSM.pad_window_sums(wins, 8)
-        assert padded.shape == (8,) + wins[0].shape
-        assert ec.decode_points(MSM.combine_windows_batch(padded, 4)) \
-            == got + [None] * 5
-        assert MSM.pad_window_sums(wins, m).shape[0] == m
 
 
 class TestMxuField:
@@ -388,7 +339,6 @@ class TestField384:
             assert (int(x), int(y)) == g
 
     def test_decompress_rejects_off_curve(self):
-        import pytest as _pytest
         from spectre_tpu.fields import bls12_381 as bls
         from spectre_tpu.ops.field384 import g1_decompress_batch
         good = bls.g1_compress(bls.sk_to_pk(5))
@@ -399,5 +349,5 @@ class TestField384:
                 break
         bad = bytearray(int(bad_x).to_bytes(48, "big"))
         bad[0] |= 0x80
-        with _pytest.raises(AssertionError):
+        with pytest.raises(AssertionError):
             g1_decompress_batch([good, bytes(bad)])

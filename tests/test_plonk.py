@@ -17,12 +17,13 @@ from spectre_tpu.plonk.srs import SRS
 from spectre_tpu.plonk.transcript import Blake2bTranscript, KeccakTranscript, keccak256
 from spectre_tpu.plonk.verifier import verify
 
-K = 7
+from _shapes import TINY_K as K, seeded_blinding
+from _shapes import tiny_circuit as _tiny_circuit
 
 
 @pytest.fixture(scope="module")
-def srs():
-    return SRS.unsafe_setup(K)
+def srs(tiny):
+    return tiny.srs
 
 
 class TestTranscript:
@@ -121,203 +122,19 @@ class TestSHPLONK:
         assert not kzg.shplonk_verify(srs, [kzg.OpenEntry(None, C1, (x,), f)], tr)
 
 
-class TestMsmModeCommitments:
-    """The ISSUE-2 correctness gate: KZG commitments through the device
-    backend are byte-identical across every MSM mode (GLV, signed digits,
-    fixed-base tables) AND match the native CPU oracle — the modes change
-    work shape, never the committed group element. Commitment-level (not
-    full-prove) in the default tier on purpose: this box's XLA CPU client
-    segfaults in LLVM under repeated full-prove compile churn; the
-    full-prove cross-mode equality is the SPECTRE_BYTEEQ_FULL tier in
-    TestBackendByteEquality. Placed before the prove suites so it runs
-    with minimal accumulated compile state."""
-
-    def test_msm_mode_commitments_byte_identical(self, srs, monkeypatch):
-        import random
-        rng = random.Random(0xD16E57)
-        n = srs.n
-        coeffs = np.zeros((n, 4), dtype=np.uint64)
-        for i in range(n):
-            v = rng.randrange(bn.R)
-            for j in range(4):
-                coeffs[i, j] = (v >> (64 * j)) & 0xFFFFFFFFFFFFFFFF
-        oracle = kzg.commit(srs, coeffs, B.get_backend("cpu"))
-        bk = B.get_backend("tpu")
-        for mode in ("vanilla", "glv", "glv+signed", "fixed"):
-            monkeypatch.setenv("SPECTRE_MSM_MODE", mode)
-            got = kzg.commit(srs, coeffs, bk)
-            assert got == oracle, \
-                f"SPECTRE_MSM_MODE={mode} commitment diverged from oracle"
-
-    @pytest.mark.slow
-    def test_pallas_impl_commitments_byte_identical(self, srs, monkeypatch):
-        """ISSUE 17 tier of the same gate, impl axis: every mode under
-        SPECTRE_MSM_IMPL=pallas (interpret mode off-TPU) commits to the
-        SAME bytes as the CPU oracle through the device backend, and none
-        of the four modes falls back to XLA (zero unsupported-mode
-        events). Slow tier: four interpret-mode pallas compile chains at
-        K=7 cost ~100s on the 1-core box; the fast tier covers the same
-        matrix at MSM level in test_msm_modes."""
-        import random
-
-        from spectre_tpu.ops import msm as MSM
-        rng = random.Random(0xD16E57)
-        n = srs.n
-        coeffs = np.zeros((n, 4), dtype=np.uint64)
-        for i in range(n):
-            v = rng.randrange(bn.R)
-            for j in range(4):
-                coeffs[i, j] = (v >> (64 * j)) & 0xFFFFFFFFFFFFFFFF
-        oracle = kzg.commit(srs, coeffs, B.get_backend("cpu"))
-        events = []
-        orig = MSM._record_event
-        monkeypatch.setattr(
-            MSM, "_record_event",
-            lambda name, **kw: (events.append((name, kw)),
-                                orig(name, **kw)))
-        monkeypatch.setenv("SPECTRE_MSM_IMPL", "pallas")
-        bk = B.get_backend("tpu")
-        for mode in ("glv+signed", "glv", "fixed", "vanilla"):
-            monkeypatch.setenv("SPECTRE_MSM_MODE", mode)
-            got = kzg.commit(srs, coeffs, bk)
-            assert got == oracle, \
-                f"impl=pallas mode={mode} commitment diverged from oracle"
-        bad = [e for e in events if e[0] == "msm_pallas_unsupported_mode"]
-        assert not bad, f"pallas path degraded to XLA: {bad}"
-
-
-class TestOneChipBatchedCommit:
-    """ISSUE 30: on ONE device, in the default MSM mode, `TpuBackend`
-    commits a list MSM.CHUNK_WIDTH columns a device run (`_msm_chunks`: a
-    window phase a column, one combine, one affine conversion and one read
-    a run) and a single column as a chunk of one. Same group elements as
-    the one-column kernels and as the native Pippenger; bytes and counts
-    only."""
-
-    N = 32
-
-    @pytest.fixture(scope="class")
-    def base(self):
-        pts = [bn.g1_curve.mul(bn.G1_GEN, 3 * k + 2) for k in range(self.N)]
-        pts[5] = None                      # an infinity in the base
-        return host.points_to_limbs(pts)
-
-    @pytest.fixture(scope="class")
-    def columns(self):
-        import random
-        rng = random.Random(30)
-        cols = [B.to_arr([rng.randrange(bn.R) for _ in range(self.N)])
-                for _ in range(17)]
-        cols[1] = B.zeros(self.N)          # an all-zero column
-        cols[2] = cols[2][:self.N - 5]     # shorter than the base
-        return cols
-
-    @pytest.fixture()
-    def one_chip(self, monkeypatch):
-        monkeypatch.setenv("SPECTRE_MESH_SHAPE", "1x1")
-        monkeypatch.delenv("SPECTRE_MSM_MODE", raising=False)
-        monkeypatch.delenv("SPECTRE_MSM_IMPL", raising=False)
-        return B.TpuBackend()
-
-    @pytest.mark.parametrize("length", [1, 2, 3, 10, 16, 17])
-    def test_chunks_equal_the_loop_and_the_cpu(self, base, columns,
-                                               one_chip, length):
-        import jax.numpy as jnp
-
-        from spectre_tpu.observability import tracing
-        from spectre_tpu.ops import ec, limbs as L16, msm as MSM
-
-        cols = columns[:length]
-        with tracing.trace(f"chunks-{length}") as tr:
-            got = one_chip.msm_many(base, cols)
-        assert got == B.get_backend("cpu").msm_many(base, cols)
-        # the loop the batched path replaced: one column a program
-        pts = one_chip._base_points(base, self.N)
-        for col, pt in list(zip(cols, got))[:3]:
-            m = col.shape[0]
-            sc16 = jnp.asarray(L16.u64limbs_to_u16limbs(col))
-            assert ec.decode_points(MSM.msm(pts[:m], sc16)[None])[0] == pt
-        if length > 1:
-            assert got[1] is None                   # the all-zero column
-        # a list longer than the width is split, a shorter one padded
-        runs = tr.root.children
-        assert [(r.name, r.meta["batch"], r.meta["width"]) for r in runs] \
-            == [("backend/msm_many", min(16, length - at), 16)
-                for at in range(0, length, 16)]
-        assert tracing.summary(tr)["msm_columns"] == {
-            "real": length, "padded": 16 * len(runs) - length}
-
-    def test_msm_is_a_chunk_of_one(self, base, columns, one_chip):
-        from spectre_tpu.observability import tracing
-        cpu = B.get_backend("cpu")
-        with tracing.trace("chunk-of-one") as tr:
-            for col in columns[:3]:
-                assert one_chip.msm(base, col) == cpu.msm(base, col)
-        assert [(r.name, r.meta["batch"], r.meta["width"])
-                for r in tr.root.children] == [("backend/msm", 1, 16)] * 3
-
-    @pytest.mark.parametrize("var,value", [
-        ("SPECTRE_MSM_MODE", "glv"), ("SPECTRE_MESH_SHAPE", None)])
-    def test_other_modes_and_meshes_keep_their_paths(self, base, columns,
-                                                     one_chip, monkeypatch,
-                                                     var, value):
-        """Default mode on one device only: another MSM mode loops `msm`
-        on its own kernels, a mesh (all 8 virtual devices once the 1x1
-        shape is unset) keeps the data-parallel branch."""
-        from spectre_tpu.observability import tracing
-        if value is None:
-            monkeypatch.delenv(var)
-        else:
-            monkeypatch.setenv(var, value)
-        with tracing.trace("not-batched") as tr:
-            got = one_chip.msm_many(base, columns[:2])
-        assert got == B.get_backend("cpu").msm_many(base, columns[:2])
-        assert tracing.summary(tr)["msm_columns"] == {"real": 0, "padded": 0}
-
-
-def _tiny_circuit(cfg):
-    """x + x*y = out, x range-checked, one constant pin."""
-    n = cfg.n
-    x_w, y_w = 7, 3
-    out = x_w + x_w * y_w
-    advice = [[0] * n for _ in range(cfg.num_advice)]
-    advice[0][0], advice[0][1], advice[0][2], advice[0][3] = x_w, x_w, y_w, out
-    advice[0][4] = 5
-    selectors = [[0] * n for _ in range(cfg.num_advice)]
-    selectors[0][0] = 1
-    lookup = [[0] * n for _ in range(cfg.num_lookup_advice)]
-    lookup[0][0] = x_w
-    fixed = [[0] * n for _ in range(cfg.num_fixed)]
-    fixed[0][0] = 5
-    copies = [
-        ((cfg.col_instance(0), 0), (cfg.col_gate_advice(0), 3)),
-        ((cfg.col_fixed(0), 0), (cfg.col_gate_advice(0), 4)),
-        ((cfg.col_gate_advice(0), 0), (cfg.col_lookup_advice(0), 0)),
-    ]
-    return advice, lookup, fixed, selectors, copies, out
-
-
 class TestProveVerify:
-    def test_end_to_end(self, srs):
-        cfg = CircuitConfig(k=K, num_advice=1, num_lookup_advice=1, num_fixed=1,
-                            lookup_bits=4)
-        advice, lookup, fixed, selectors, copies, out = _tiny_circuit(cfg)
-        pk = keygen(srs, cfg, fixed, selectors, copies)
-        asg = Assignment(cfg, advice, lookup, fixed, selectors, [[out]], copies)
-        proof = prove(pk, srs, asg)
+    def test_end_to_end(self, tiny):
+        pk, srs, out = tiny.pk, tiny.srs, tiny.out
+        proof = prove(pk, srs, tiny.asg)
         assert verify(pk.vk, srs, [[out]], proof)
         assert not verify(pk.vk, srs, [[out + 1]], proof)
 
-    def test_malformed_proof_bytes_reject_not_raise(self, srs):
+    def test_malformed_proof_bytes_reject_not_raise(self, tiny,
+                                                    tiny_cpu_proof):
         """Untrusted proof bytes must yield a boolean reject, never an
         exception: truncated, trailing-garbage, and non-canonical-scalar
         proofs all return False."""
-        cfg = CircuitConfig(k=K, num_advice=1, num_lookup_advice=1, num_fixed=1,
-                            lookup_bits=4)
-        advice, lookup, fixed, selectors, copies, out = _tiny_circuit(cfg)
-        pk = keygen(srs, cfg, fixed, selectors, copies)
-        asg = Assignment(cfg, advice, lookup, fixed, selectors, [[out]], copies)
-        proof = prove(pk, srs, asg)
+        pk, srs, out, proof = tiny.pk, tiny.srs, tiny.out, tiny_cpu_proof
         assert not verify(pk.vk, srs, [[out]], proof + b"\x00" * 7)
         assert not verify(pk.vk, srs, [[out]], proof[:-5])
         assert not verify(pk.vk, srs, [[out]], b"")
@@ -380,14 +197,10 @@ class TestProveVerify:
         with pytest.raises(AssertionError, match="permutation product"):
             prove(pk, srs, asg)
 
-    def test_proof_is_zk_randomized(self, srs):
-        cfg = CircuitConfig(k=K, num_advice=1, num_lookup_advice=1, num_fixed=1,
-                            lookup_bits=4)
-        advice, lookup, fixed, selectors, copies, out = _tiny_circuit(cfg)
-        pk = keygen(srs, cfg, fixed, selectors, copies)
-        asg = Assignment(cfg, advice, lookup, fixed, selectors, [[out]], copies)
-        p1 = prove(pk, srs, asg)
-        p2 = prove(pk, srs, asg)
+    def test_proof_is_zk_randomized(self, tiny):
+        pk, srs, out = tiny.pk, tiny.srs, tiny.out
+        p1 = prove(pk, srs, tiny.asg)
+        p2 = prove(pk, srs, tiny.asg)
         assert p1 != p2  # blinding rows differ
         assert verify(pk.vk, srs, [[out]], p1) and verify(pk.vk, srs, [[out]], p2)
 
@@ -569,35 +382,13 @@ class TestDeviceQuotient:
         self._check(build, k=9, lookup_bits=5, srs_k=11)
 
 
-@pytest.mark.skipif(not os.environ.get("RUN_SLOW"),
-                    reason="minutes of device-kernel compile")
-class TestTpuBackendPath:
-    def test_prove_via_device_kernels(self, srs):
-        """The --backend tpu wiring: MSM/NTT through the JAX limb kernels
-        (runs on whatever JAX backend is active — CPU in CI)."""
-        cfg = CircuitConfig(k=K, num_advice=1, num_lookup_advice=1, num_fixed=1,
-                            lookup_bits=4)
-        advice, lookup, fixed, selectors, copies, out = _tiny_circuit(cfg)
-        bk = B.get_backend("tpu")
-        pk = keygen(srs, cfg, fixed, selectors, copies, bk)
-        pk_cpu = keygen(srs, cfg, fixed, selectors, copies, B.get_backend("cpu"))
-        assert pk.vk.digest() == pk_cpu.vk.digest()
-        asg = Assignment(cfg, advice, lookup, fixed, selectors, [[out]], copies)
-        proof = prove(pk, srs, asg, bk)
-        assert verify(pk.vk, srs, [[out]], proof)
-
-
 class TestKeccakTranscriptPath:
     """The EVM-oriented transcript (Keccak-256) through full prove/verify —
     the reference's gen_evm_proof path uses exactly this hash for challenges."""
 
-    def test_prove_verify_keccak(self, srs):
-        cfg = CircuitConfig(k=K, num_advice=1, num_lookup_advice=1, num_fixed=1,
-                            lookup_bits=4)
-        advice, lookup, fixed, selectors, copies, out = _tiny_circuit(cfg)
-        pk = keygen(srs, cfg, fixed, selectors, copies)
-        asg = Assignment(cfg, advice, lookup, fixed, selectors, [[out]], copies)
-        proof = prove(pk, srs, asg, transcript=KeccakTranscript())
+    def test_prove_verify_keccak(self, tiny):
+        pk, srs, out = tiny.pk, tiny.srs, tiny.out
+        proof = prove(pk, srs, tiny.asg, transcript=KeccakTranscript())
         assert verify(pk.vk, srs, [[out]], proof, transcript_cls=KeccakTranscript)
         # a keccak proof must NOT verify under the blake2b transcript
         assert not verify(pk.vk, srs, [[out]], proof)
@@ -609,26 +400,7 @@ class TestBackendByteEquality:
     differ only in WHERE the math runs, never in WHAT they compute. Default
     tier (shapes shared with TestProveVerify for a warm compile cache)."""
 
-    @staticmethod
-    def _seeded_rng(seed: int):
-        import random
-        r = random.Random(seed)
-        return lambda: r.randrange(bn.R)
-
-    def test_cpu_tpu_proof_bytes_identical(self, srs):
-        cfg = CircuitConfig(k=K, num_advice=1, num_lookup_advice=1, num_fixed=1,
-                            lookup_bits=4)
-        advice, lookup, fixed, selectors, copies, out = _tiny_circuit(cfg)
-        asg = Assignment(cfg, advice, lookup, fixed, selectors, [[out]], copies)
-        proofs = {}
-        for name in ("cpu", "tpu"):
-            bk = B.get_backend(name)
-            pk = keygen(srs, cfg, fixed, selectors, copies, bk)
-            proofs[name] = prove(pk, srs, asg, bk,
-                                 blinding_rng=self._seeded_rng(0xC0FFEE))
-            assert verify(pk.vk, srs, [[out]], proofs[name])
-        assert proofs["cpu"] == proofs["tpu"], \
-            "backend proof bytes diverge (transcript/serialization drift)"
+    _seeded_rng = staticmethod(seeded_blinding)
 
     @pytest.mark.skipif(not os.environ.get("SPECTRE_BYTEEQ_FULL"),
                         reason="this box's XLA CPU LLVM segfaults under "
@@ -690,12 +462,8 @@ class TestBackendByteEquality:
                        if e[0] == "msm_pallas_unsupported_mode"]
                 assert not bad, f"mode={mode} degraded to XLA: {bad}"
 
-    def test_seeded_blinding_is_deterministic_and_fresh_is_not(self, srs):
-        cfg = CircuitConfig(k=K, num_advice=1, num_lookup_advice=1, num_fixed=1,
-                            lookup_bits=4)
-        advice, lookup, fixed, selectors, copies, out = _tiny_circuit(cfg)
-        asg = Assignment(cfg, advice, lookup, fixed, selectors, [[out]], copies)
-        pk = keygen(srs, cfg, fixed, selectors, copies)
+    def test_seeded_blinding_is_deterministic_and_fresh_is_not(self, tiny):
+        pk, srs, asg, out = tiny.pk, tiny.srs, tiny.asg, tiny.out
         p1 = prove(pk, srs, asg, blinding_rng=self._seeded_rng(1))
         p2 = prove(pk, srs, asg, blinding_rng=self._seeded_rng(1))
         assert p1 == p2

@@ -456,7 +456,6 @@ class TestAsyncRPC:
         circuit breaker turns the readiness probe into a 503 with the
         breaker state in the body; once the breaker leaves the open state
         (cooldown -> half-open trial) readiness returns to 200."""
-        import time
         import urllib.error
 
         from spectre_tpu.preprocessor.beacon import (BeaconClient,
@@ -469,6 +468,11 @@ class TestAsyncRPC:
         client = BeaconClient("http://127.0.0.1:9/", retries=0,
                               breaker_threshold=1, breaker_cooldown=0.2,
                               total_timeout=5.0, sleep=lambda _s: None)
+        # the breaker's clock is the test's (the injectable clock the fault
+        # tier uses): "open" lasts through the HTTP round trip below
+        # however loaded the host is
+        now = [1000.0]
+        client._breaker._clock = lambda: now[0]
         try:
             faults.install_plan("beacon.fetch:connreset:1")
             # threshold=1: the injected failure trips the breaker mid-call
@@ -484,7 +488,7 @@ class TestAsyncRPC:
             assert any(b["state"] == "open"
                        for b in body["beacon_breakers"])
             # cooldown elapses -> half-open admits a trial -> ready again
-            time.sleep(0.25)
+            now[0] += 0.25
             assert client.breaker_state == "half-open"
             with urllib.request.urlopen(
                     f"http://127.0.0.1:{port}/healthz", timeout=60) as resp:
@@ -839,7 +843,10 @@ class TestOverloadRPC:
 
     def test_job_not_done_moved_to_32002(self):
         from spectre_tpu.prover_service.rpc import serve
-        server = serve(_FakeState(TINY, delay=0.5), port=0, background=True)
+        import threading
+        done = threading.Event()     # the prove ends when the test says so
+        server = serve(_FakeState(TINY, gate=lambda: done.wait(60)), port=0,
+                       background=True)
         port = server.server_address[1]
         try:
             sub = _rpc_post(port, {
@@ -854,6 +861,7 @@ class TestOverloadRPC:
             # -32001 now means "service overloaded"; pending moved here
             assert err["code"] == -32002
         finally:
+            done.set()
             server.shutdown()
 
     def test_deadline_s_threads_through_rpc(self):
