@@ -276,11 +276,13 @@ def summary(tr: Trace | None) -> dict:
     `msm_columns`, over the spans that carry a `batch` and a `width` (a
     run of the one-device MSM path, plonk/backend.py `_msm_chunks`): the
     columns committed (`real`) and the identity columns that filled the
-    runs up to their width (`padded`)."""
+    runs up to their width (`padded`); `msm_window`, the Pippenger windows
+    `c` those runs carried (one, on a prove of one size of commitment)."""
     seconds: dict[str, float] = {}
     counts: dict[str, int] = {}
     moved = {"h2d": 0, "d2h": 0}
     columns = {"real": 0, "padded": 0}
+    windows = set()
     way = {ENCODE: "h2d", WAIT: "d2h"}
 
     def walk(s: Span):
@@ -295,6 +297,7 @@ def summary(tr: Trace | None) -> dict:
             if c.meta and "width" in c.meta:
                 columns["real"] += int(c.meta["batch"])
                 columns["padded"] += int(c.meta["width"] - c.meta["batch"])
+                windows.add(int(c.meta["c"]))
             walk(c)
 
     if tr is not None:
@@ -303,7 +306,8 @@ def summary(tr: Trace | None) -> dict:
                               for k, v in sorted(seconds.items())},
             "span_counts": dict(sorted(counts.items())),
             "transfer_bytes": moved,
-            "msm_columns": columns}
+            "msm_columns": columns,
+            "msm_window": sorted(windows)}
 
 
 def phase_seconds(tr: Trace) -> dict[str, float]:

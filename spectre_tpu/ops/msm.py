@@ -17,9 +17,12 @@ Per window (processed under `lax` control flow so the graph stays small):
      bucket with ALL n points still reduces in log2(n) levels with O(n) work,
      unlike padded-gather schemes whose memory explodes.
   4. bucket totals = tree-reduce of the emission array over levels
-  5. weighted bucket aggregation sum_b b*B_b via bit decomposition: for each
-     digit bit j, tree-reduce the masked buckets, then a 13-step double-and-add
-     — depth log(nbuckets) instead of a 2^c-step serial scan.
+  5. weighted bucket aggregation sum_b b*B_b by split digits: the bucket id
+     is hi*2^l + lo, so the buckets are a [2^h, 2^l] grid whose row and
+     column sums (one tree) carry every bit of the id; the c bit sums are a
+     second tree over those 2^h + 2^l sums, then a c-step double-and-add —
+     about 2 * 2^c additions a window and depth c, where a serial scan is
+     2^c deep and masking the buckets themselves for each bit c * 2^c wide.
   6. window combine: fori_loop of c doublings + add.
 
 Complete RCB addition (ops.ec) makes every step branchless; infinity is the
@@ -101,18 +104,22 @@ def msm_impl() -> str:
 
 def window_override() -> int | None:
     """Operator window override from SPECTRE_MSM_WINDOW (1..13, empty/unset
-    = autotuned table). The device retuning knob: `bench.py --sweep-window`
-    emits per-c points/s so a real-TPU run can pick the value, and every
-    `default_window*` consumer (ops/msm.py, parallel/batch_msm.py,
-    plonk/backend.py) honors it without plumbing c by hand."""
+    = the tuned table). The device retuning knob: every `default_window*`
+    consumer (ops/msm.py, parallel/batch_msm.py, plonk/backend.py) honors
+    it without plumbing c by hand. The ceiling of 13 is not a memory limit
+    (the aggregation's widest tensor, [nwin, 2^h + 2^l, 2^l] points, is
+    47 MB at c = 13): it is the widest window any table entry or test has
+    run, and since the emission array [levels + 1, 2^c] charges every
+    window ~levels * 2^c additions, no size this prover commits
+    (n <= 2^23) is served by a wider one."""
     v = os.environ.get("SPECTRE_MSM_WINDOW")
     if v is None or v == "":
         return None
     c = int(v)
     if not 1 <= c <= 13:
         raise ValueError(
-            f"SPECTRE_MSM_WINDOW={v}: expected 1..13 (c > 13 OOMs the "
-            "bucket aggregation — see default_window)")
+            f"SPECTRE_MSM_WINDOW={v}: expected 1..13 (the widest window "
+            "that has run — see window_override)")
     return c
 
 
@@ -141,6 +148,20 @@ def signed_digit_stream(scalars, c: int, nwin: int):
     _carry, digs = jax.lax.scan(
         step, jnp.zeros(scalars.shape[0], dtype=jnp.int32), jnp.arange(nwin))
     return digs
+
+
+def _tree_sum(pts, axis: int):
+    """Sum points over one axis by halving it (an odd width carries its
+    last slice to the next level); the axis goes."""
+    while pts.shape[axis] > 1:
+        k = pts.shape[axis]
+        half = k // 2
+        merged = ec.padd(jax.lax.slice_in_dim(pts, 0, half, axis=axis),
+                         jax.lax.slice_in_dim(pts, half, 2 * half, axis=axis))
+        pts = jnp.concatenate(
+            [merged, jax.lax.slice_in_dim(pts, 2 * half, k, axis=axis)],
+            axis=axis) if k % 2 else merged
+    return jnp.squeeze(pts, axis)
 
 
 def _segmented_bucket_sums(points, digits, nbuckets: int):
@@ -179,36 +200,52 @@ def _segmented_bucket_sums(points, digits, nbuckets: int):
     # final survivor
     emissions = emissions.at[levels, buckets[0]].set(pts[0], mode="drop")
 
-    # tree-reduce emissions over the level axis
-    acc = emissions
-    while acc.shape[0] > 1:
-        k = acc.shape[0]
-        half = k // 2
-        merged = ec.padd(acc[:half], acc[half:2 * half])
-        acc = jnp.concatenate([merged, acc[2 * half:]], axis=0) \
-            if k % 2 else merged
-    return acc[0]
+    # bucket totals: the emissions summed over the level axis
+    return _tree_sum(emissions, 0)
 
 
 def _aggregate_buckets(bucket_sums, c: int):
-    """sum_b b * B_b for each window via bit decomposition.
+    """sum_b b * B_b for each window, by split digits.
 
     bucket_sums: [nwin, nbuckets, 3, 16] -> [nwin, 3, 16]. nbuckets may be
-    any size with ids < 2^c (the signed paths pass 2^(c-1)+1)."""
+    any size with ids < 2^c (the signed paths pass 2^(c-1)+1): the axis is
+    filled to 2^c with the identity.
+
+    The id splits as b = hi * 2^l + lo (l = c // 2, h = c - l), the buckets
+    are a [2^h, 2^l] grid, and
+        sum_b b * B_b = 2^l * sum_hi hi * R_hi + sum_lo lo * C_lo
+    with R_hi the grid's row sums and C_lo its column sums: bit j of b is
+    bit j of lo (j < l) or bit j - l of hi, so the sum over the buckets
+    whose id has bit j is a sum over 2^l columns or 2^h rows, not over 2^c
+    buckets. Rows and columns are ONE tree over the grid's lo axis and its
+    transpose's hi axis (for odd c the hi axis is halved once first, which
+    makes the two as long), the c bit sums one tree over [c, 2^h], then
+    the double-and-add: about 2 * 2^c + c * 2^h + 2c additions a window
+    (masking the buckets themselves for each bit costs c * 2^c)."""
     nwin, nbuckets = bucket_sums.shape[0], bucket_sums.shape[1]
-    idx = jnp.arange(nbuckets)
-    # [nwin, c, nbuckets, 3, 16] masked by bit j of the bucket index
-    masks = ((idx[None, :] >> jnp.arange(c)[:, None]) & 1).astype(bool)  # [c, nbuckets]
-    sel = ec.select_point(masks[None, :, :], bucket_sums[:, None],
-                          ec.inf_point((1, 1, 1)))
-    # tree-reduce over the bucket axis
-    while sel.shape[2] > 1:
-        k = sel.shape[2]
-        half = k // 2
-        merged = ec.padd(sel[:, :, :half], sel[:, :, half:2 * half])
-        sel = jnp.concatenate([merged, sel[:, :, 2 * half:]], axis=2) \
-            if k % 2 else merged
-    bit_sums = sel[:, :, 0]                      # [nwin, c, 3, 16]
+    l = c // 2
+    h = c - l
+    if nbuckets < 1 << c:
+        bucket_sums = jnp.concatenate(
+            [bucket_sums, ec.inf_point((nwin, (1 << c) - nbuckets))], axis=1)
+    grid = bucket_sums.reshape((nwin, 1 << h, 1 << l) + bucket_sums.shape[2:])
+    cols = ec.padd(grid[:, :1 << l], grid[:, 1 << l:]) if h > l else grid
+    sums = _tree_sum(
+        jnp.concatenate([grid, jnp.swapaxes(cols, 1, 2)], axis=1), 2)
+    row_sums, col_sums = sums[:, :1 << h], sums[:, 1 << h:]
+    if h > l:
+        col_sums = jnp.concatenate(
+            [col_sums, ec.inf_point((nwin, (1 << h) - (1 << l)))], axis=1)
+    # [nwin, c, 2^h]: for bit j the column (j < l) or row sums whose index
+    # has the bit
+    shifts = np.concatenate([np.arange(l), np.arange(h)])
+    masks = ((np.arange(1 << h)[None, :] >> shifts[:, None]) & 1).astype(bool)
+    src = jnp.concatenate(
+        [jnp.broadcast_to(col_sums[:, None], (nwin, l) + col_sums.shape[1:]),
+         jnp.broadcast_to(row_sums[:, None], (nwin, h) + row_sums.shape[1:])],
+        axis=1)
+    bit_sums = _tree_sum(
+        ec.select_point(masks[None], src, ec.inf_point((1, 1, 1))), 2)
     # acc = sum_j 2^j bit_sums[:, j] by high-to-low double-and-add
     # (a scan, not c unrolled steps: the same chain of additions as one loop
     # body where the unrolled form is 2c copies of every field operation's
@@ -589,14 +626,7 @@ def msm_fixed_run(table, scalars, neg, c: int, nbits: int):
 
     bucket_sums = jax.lax.map(one_window, (table, digs))  # [nwin, nb, 3, 16]
     # cross-window bucket merge: tree-fold the window axis
-    acc = bucket_sums
-    while acc.shape[0] > 1:
-        k = acc.shape[0]
-        half = k // 2
-        merged = ec.padd(acc[:half], acc[half:2 * half])
-        acc = jnp.concatenate([merged, acc[2 * half:]], axis=0) \
-            if k % 2 else merged
-    return _aggregate_buckets(acc, c)[0]
+    return _aggregate_buckets(_tree_sum(bucket_sums, 0)[None], c)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -606,12 +636,21 @@ def msm_fixed_run(table, scalars, neg, c: int, nbits: int):
 def default_window(n: int, signed: bool = False) -> int:
     """Pippenger window size for n points (the EXPANDED count under GLV).
 
-    c > 13 OOMs in _aggregate_buckets (the bit-decomposition select
-    materializes [nwin, c, nbuckets, 3, 16]); 13 is the practical ceiling.
-    With signed digits the bucket array is 2^(c-1)+1 — the aggregation and
-    emission terms that cap c relax by one bucket-doubling, so each size
-    class affords a larger window (pinned by tests/test_msm_modes.py).
-    SPECTRE_MSM_WINDOW overrides the whole table (device retuning)."""
+    The unsigned entry for 2^12 <= n < 2^18 was chosen on the chip (TPU v5
+    lite, PR 32, chip call 1: `msm_windows` with the split-digit aggregate,
+    the median of three repeats): at n = 2^14, c = 7 / 8 / 9 / 10 / 11 read
+    0.345 / 0.309 / 0.293 / 0.280 / 0.309 s; at 2^15, c = 8 / 9 / 10 read
+    0.427 / 0.398 / 0.375; at 2^16, c = 8 / 9 / 10 / 11 read 0.685 / 0.636 /
+    0.594 / 0.608. The count of additions alone says 8 at 2^14; what it
+    leaves out is that a window costs ~9 ms there whatever its buckets
+    (its sort, gathers, scatters and narrow levels), so fewer, wider
+    windows win until the emission tree's levels * 2^c outgrows that.
+    Every other entry (signed, n >= 2^18, n < 2^12) dates from XLA:CPU
+    sweeps and has not run on the chip. With signed digits the bucket array
+    is 2^(c-1)+1, so the emission term that caps c relaxes by one bucket-
+    doubling and each size class affords a larger window (pinned by
+    tests/test_msm_modes.py). SPECTRE_MSM_WINDOW overrides the whole table
+    (the sweep's knob; 1..13, see window_override)."""
     ov = window_override()
     if ov is not None:
         return ov
@@ -658,8 +697,8 @@ def _pallas_bucket_bytes(c: int, nbits: int) -> int:
 def default_window_pallas(n: int, signed: bool = False) -> int:
     """Window table for the pallas bucket kernel (SPECTRE_MSM_IMPL=pallas).
 
-    The XLA table tunes around _aggregate_buckets' materialized select; the
-    bucket kernel's binding constraint is VMEM residency instead, so it gets
+    The XLA table tunes around a window's fixed cost and its emission
+    tree; the bucket kernel's binding constraint is VMEM residency, so it gets
     its own table: start from the XLA width for the size class and shrink
     until the resident buckets fit _PALLAS_BUCKET_VMEM_BUDGET. 254-bit
     vanilla scalars (nwin ~ 254/c, roughly double the GLV window count)

@@ -42,13 +42,7 @@ from .plan import ShardingPlan, plan_for_mesh
 
 def _fold_points(stacked):
     """Tree-fold [k, *, 3, 16] partial sums -> [*, 3, 16]."""
-    acc = stacked
-    while acc.shape[0] > 1:
-        k = acc.shape[0]
-        half = k // 2
-        merged = ec.padd(acc[:half], acc[half:2 * half])
-        acc = jnp.concatenate([merged, acc[2 * half:]], axis=0) if k % 2 else merged
-    return acc[0]
+    return MSM._tree_sum(stacked, 0)
 
 
 # compiled SPMD programs, one per (plan, shape-class). Keys embed plan.key

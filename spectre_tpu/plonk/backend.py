@@ -531,7 +531,8 @@ class TpuBackend(CpuBackend):
         ONE width: a run of fewer columns is filled with identity window
         sums, whose points are read with the rest and dropped; a longer
         list is split; a shorter scalar vector is zero-extended. One span
-        named `call` a run, with `batch` (its columns) and `width` on it."""
+        named `call` a run, with `batch` (its columns), `width` and the
+        window `c` its columns were committed with on it."""
         import jax.numpy as jnp
 
         from ..ops import ec, limbs as L16, msm as MSM
@@ -542,7 +543,7 @@ class TpuBackend(CpuBackend):
         out = []
         for at in range(0, len(scalars_list), width):
             chunk = scalars_list[at:at + width]
-            with span(call, n=n, batch=len(chunk), width=width):
+            with span(call, n=n, batch=len(chunk), width=width, c=c):
                 with span(call + "/encode"):
                     pts = self._base_points(points, n)
                     cols, sent = [], 0

@@ -210,6 +210,10 @@ class TestDeviceBoundarySpans:
             batch = 1 if op == "msm" else 2
             assert (call.meta["batch"], call.meta["width"]) \
                 == (batch, MSM_WIDTH)
+            # the window its columns were committed with, on the span and
+            # in the manifest's sums
+            assert call.meta["c"] == MSM_WINDOWS["vanilla"]
+            assert got["msm_window"] == [call.meta["c"]]
             assert moved == {"h2d": 64 * n * batch,
                              "d2h": MSM_WIDTH * 3 * 64}
             assert got["msm_columns"] == {"real": batch,
@@ -217,6 +221,7 @@ class TestDeviceBoundarySpans:
         elif op == "msm":
             assert moved == {"h2d": 64 * n, "d2h": 3 * 64}
             assert got["msm_columns"] == {"real": 0, "padded": 0}
+            assert got["msm_window"] == []
         elif op == "msm_many":
             assert call.meta["batch"] == 2 and moved["d2h"] == 2 * 6 * 64
         else:
@@ -302,6 +307,8 @@ class TestDeviceBoundarySpans:
         runs = counts["backend/msm"] + counts["backend/msm_many"]
         assert got["msm_columns"] == man["msm_columns"] == {
             "real": commits, "padded": MSM_WIDTH * runs - commits}
+        assert got["msm_window"] == man["msm_window"] \
+            == [MSM_WINDOWS["vanilla"]]
         real = 0
         for s in _walk(t.trace.root):
             if s.name in ("backend/msm", "backend/msm_many"):

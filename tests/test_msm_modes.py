@@ -4,6 +4,7 @@ The contract every mode must honor: identical group element out (the
 commitment byte-equality gate rides on this), only the work shape differs.
 """
 
+import functools
 import secrets
 
 import numpy as np
@@ -18,7 +19,8 @@ from spectre_tpu.plonk import backend as B, kzg
 from spectre_tpu.utils.health import HEALTH
 
 from _shapes import (MSM_CASES, MSM_N, MSM_N_OTHER_MODES, MSM_WINDOWS,
-                     check_msm_case, encode_msm, kernel_programs, msm_case)
+                     check_msm_case, encode_msm, kernel_programs, msm_base,
+                     msm_case)
 
 
 def _edge_scalars():
@@ -205,6 +207,61 @@ class TestMSMModes:
             MSM.msm_mode()
 
 
+AGGREGATE_INPUTS = ("random", "all_infinity", "single_bucket")
+
+
+@functools.lru_cache(maxsize=None)
+def _aggregated(c: int, nbuckets: int):
+    """(got, want) of `_aggregate_buckets` over one [3, nbuckets] stack, a
+    window an input of AGGREGATE_INPUTS: the program of a (c, nbuckets) is
+    compiled once for the three. `want` is the plain host sum
+    sum_b b * B_b on the host curve."""
+    import random
+    rng = random.Random(1000 * c + nbuckets)
+    base = [p for p in msm_base()[:MSM_N_OTHER_MODES] if p is not None]
+    mixed = [rng.choice(base) if rng.random() < 0.7 else None
+             for _ in range(nbuckets)]
+    mixed[0] = base[0]                   # bucket 0 weighs nothing
+    single = [None] * nbuckets
+    single[nbuckets - 1] = base[1]       # the highest id: every bit it has
+    windows = [mixed, [None] * nbuckets, single]
+
+    def host_sum(buckets):
+        acc = None
+        for b, pt in enumerate(buckets):
+            if pt is not None:
+                acc = bn.g1_curve.add(acc, bn.g1_curve.mul(pt, b))
+        return None if acc is None else (int(acc[0]), int(acc[1]))
+
+    got = ec.decode_points(
+        jax.jit(MSM._aggregate_buckets, static_argnums=1)(
+            jnp.stack([ec.encode_points(w) for w in windows]), c))
+    return got, [host_sum(w) for w in windows]
+
+
+class TestAggregateBuckets:
+    """`_aggregate_buckets` by split digits against the plain host sum, at
+    the two windows the mode cases of this file run (MSM_WINDOWS: 4, even,
+    l = h; 5, odd, the hi axis halved once first), for the unsigned bucket
+    count 2^c and the signed one 2^(c-1) + 1 (filled to 2^c with the
+    identity). Four small programs, each one tree of the grid and one of
+    the bit sums."""
+
+    @pytest.mark.parametrize("kind", AGGREGATE_INPUTS)
+    @pytest.mark.parametrize("signed", [False, True],
+                             ids=["2^c", "2^(c-1)+1"])
+    @pytest.mark.parametrize("c", sorted(set(MSM_WINDOWS.values())))
+    def test_matches_host_sum(self, c, signed, kind):
+        nbuckets = (1 << (c - 1)) + 1 if signed else 1 << c
+        got, want = _aggregated(c, nbuckets)
+        i = AGGREGATE_INPUTS.index(kind)
+        assert got[i] == want[i], (c, nbuckets, kind)
+        if kind == "all_infinity":
+            assert want[i] is None
+        else:
+            assert want[i] is not None
+
+
 @pytest.fixture(scope="module", autouse=True)
 def programs_before():
     """Programs each mode's kernel held when this file's first test began
@@ -234,6 +291,10 @@ class TestFixedTableCache:
 
 class TestDefaultWindowTuning:
     def test_pinned_unsigned(self):
+        # 10 for 2^12 <= n < 2^18 is the chip's choice (PR 32, chip call 1:
+        # `msm_windows` at 2^14, c = 7..11, read 0.345 / 0.309 / 0.293 /
+        # 0.280 / 0.309 s; 10 also won at 2^15 and 2^16): the sweep kept
+        # the value the XLA:CPU table had
         assert [MSM.default_window(n) for n in
                 (1 << 6, 1 << 7, 1 << 12, 1 << 16, 1 << 18)] == \
             [4, 7, 10, 10, 13]
@@ -289,7 +350,8 @@ class TestWindowOverride:
         assert MSM.window_override() is None
         monkeypatch.setenv("SPECTRE_MSM_WINDOW", "")
         assert MSM.window_override() is None
-        assert MSM.default_window(1 << 12) == 10     # table still pinned
+        # the table's value: the winner of PR 32's sweep on the chip (call 1)
+        assert MSM.default_window(1 << 12) == 10
 
     @pytest.mark.parametrize("bad", ["0", "14", "-3"])
     def test_out_of_range_rejected(self, bad, monkeypatch):
@@ -506,6 +568,55 @@ class TestMsmTableBudgetDegrade:
         nwin = (nbits + c) // c
         assert MSM._fixed_table_bytes(n, c, nbits) == \
             nwin * 2 * n * 3 * 16 * 4
+
+
+# `ec.padd` call sites of the served window program (2^14 points, the
+# table's c = 10): 14 + 4 + 5 + 5 + 2
+WINDOW_PADD_SITES = 30
+
+
+def _padd_call_sites(monkeypatch, n: int, c: int) -> list:
+    """The shapes `ec.padd` is traced with, call site by call site, in
+    `msm_windows`' program at (n, c): a loop's body is traced once, so this
+    is what the program holds to lower, not what it runs. Traced only, on
+    shapes: nothing is compiled."""
+    sites = []
+    padd = ec.padd
+
+    def counting(p, q):
+        sites.append(p.shape[:-2])
+        return padd(p, q)
+
+    monkeypatch.setattr(ec, "padd", counting)
+    jax.make_jaxpr(MSM.msm_windows.__wrapped__, static_argnums=2)(
+        jax.ShapeDtypeStruct((n, 3, 16), jnp.uint32),
+        jax.ShapeDtypeStruct((n, 16), jnp.uint32), c)
+    return sites
+
+
+class TestWindowProgramSize:
+    """What `setup_s` pays for: every `ec.padd` call site of the window
+    program is 39 loops to lower, compile and load (PERF.md section 5), and
+    the served commit's program, `msm_windows` at 2^14 points and the
+    table's window, is most of a process's set-up. An edit that adds call
+    sites to it (an unrolled chain, a tree split in two, a recursion of the
+    aggregate) fails here by name."""
+
+    N = 1 << 14
+
+    def test_padd_call_sites_of_the_served_program(self, monkeypatch):
+        monkeypatch.delenv("SPECTRE_MSM_WINDOW", raising=False)
+        c = MSM.default_window(self.N)
+        sites = _padd_call_sites(monkeypatch, self.N, c)
+        nwin = (254 + c - 1) // c
+        # the segmented halving's 14 levels, the emission tree over 15
+        # levels' slots (7, 4, 2, 1), then the aggregate, at nwin windows
+        # wide: the grid's rows and columns in one tree of c // 2 levels,
+        # the c bit sums in one tree of c - c // 2, the two of the chain
+        assert sites[:14] == [(self.N >> (lvl + 1),) for lvl in range(14)]
+        assert sites[14:18] == [(k, 1 << c) for k in (7, 4, 2, 1)]
+        assert {s[0] for s in sites[18:]} == {nwin}
+        assert len(sites) == WINDOW_PADD_SITES
 
 
 class TestKernelShapesPinned:
