@@ -1,6 +1,6 @@
 # Build orchestration (reference parity: `justfile` recipes).
 
-.PHONY: all native test test-slow test-faults test-farm test-farm-proc test-gateway fixtures bench bench-fast bench-multichip bench-serve bench-quotient bench-quotient-multichip setup-committee setup-step lint lint-fast lint-deep report-ci
+.PHONY: all native test test-slow test-faults test-farm test-farm-proc test-gateway fixtures setup-committee setup-step lint lint-fast lint-deep report-ci
 
 all: native
 
@@ -9,7 +9,7 @@ native:
 
 # the driver's tier-1 form (ROADMAP "Tier-1 verify"): what a builder runs
 # here is what is counted there. `test-slow` below is the whole ladder.
-test: native lint lint-deep test-faults test-farm test-farm-proc test-gateway bench-fast
+test: native lint lint-deep test-faults test-farm test-farm-proc test-gateway
 	JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' -p xdist -n 6 --dist loadfile
 
 # fault-injection tier (PR 3, grown in PR 6): deterministic resilience
@@ -81,45 +81,6 @@ setup-committee:
 setup-step:
 	python -m spectre_tpu.prover_service.cli --spec tiny circuit sync-step setup --k 17
 
-bench: native
-	python bench.py
-
-# CI perf tier: seconds-scale 2^12 MSM on pinned CPU (no device probing),
-# gated against the checked-in floor in bench_floor.json — fails on a >20%
-# throughput regression so `make test` surfaces perf rot without the 2^16 run
-bench-fast: native
-	python bench.py --fast
-
-# multi-chip gate (PR 13): 8 simulated devices (XLA host-platform flag),
-# sharded MSM + NTT micro-floors AND a complete byte-checked k=13 mesh
-# prove, all under one hard wall-clock budget (BENCH_MULTICHIP_TIMEOUT,
-# default 2700s) — the regression gate for the historical rc=124 where
-# per-call shard_map re-jitting made the 8-device prove never finish.
-# Knobs: SPECTRE_BENCH_DEVICES (8), SPECTRE_MESH_SHAPE, BENCH_MULTICHIP_K.
-bench-multichip: native
-	BENCH_METRIC=multichip python bench.py --fast
-
-# quotient tier (ISSUE 19): the quotient phase timed with PRODUCTION
-# inputs (a real prove runs with the host quotient hooked), every timed
-# run byte-checked against the host h coefficients. bench-quotient gates
-# k=11 single-device against bench_floor.json (and rides `make bench-fast`
-# via BENCH_METRIC=all); the multichip variant runs the k=13 quotient
-# SHARDED on 8 simulated devices — any quotient_sharded_degraded tick is
-# a hard error. Knobs: BENCH_QUOTIENT_K(S), BENCH_QUOTIENT_TIMEOUT,
-# SPECTRE_BENCH_DEVICES (8), SPECTRE_MESH_SHAPE.
-bench-quotient: native
-	BENCH_METRIC=quotient python bench.py --fast
-
-bench-quotient-multichip: native
-	BENCH_METRIC=quotient_multichip python bench.py --fast
-
-# gateway read-plane tier (PR 14): 10^4-client in-process Zipf drill over
-# a synthetic sealed store — requests/s gated against bench_floor.json,
-# zero sealed-period store fallbacks asserted unconditionally. Knobs:
-# BENCH_SERVE_CLIENTS (10000), BENCH_SERVE_REQUESTS, BENCH_SERVE_PERIODS.
-bench-serve: native
-	JAX_PLATFORMS=cpu BENCH_METRIC=serve python bench.py --fast
-
 # manifest CI gate (PR 10): diff a candidate provenance manifest against
 # a baseline and exit 3 on a prove_s regression (> 10% by default) or any
 # new backend compile. Point the vars at manifest files or job ids:
@@ -135,7 +96,7 @@ report-ci:
 # (see README). --no-probes: the dynamic retrace probes are the lint-deep
 # tier below, so `make test` (which runs both) compiles them only once.
 lint:
-	python -m compileall -q spectre_tpu tests bench.py __graft_entry__.py chip_smoke.py
+	python -m compileall -q spectre_tpu tests __graft_entry__.py chip_smoke.py
 	JAX_PLATFORMS=cpu python -m spectre_tpu.analysis --fail-on error --no-probes
 
 # kernel-lint only (seconds; the full `lint` builds three tiny circuits)

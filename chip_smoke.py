@@ -51,8 +51,7 @@ T0 = time.time()
 ZERO_COUNTERS = (
     "prove_cpu_fallbacks_oom", "prove_cpu_fallbacks_compile",
     "proofs_sdc_retried", "proofs_verify_failed", "msm_fixed_degraded",
-    "msm_pallas_degraded", "quotient_sharded_degraded",
-    "self_check_failures",
+    "quotient_sharded_degraded", "self_check_failures",
 )
 SHARD_GATES = ("SPECTRE_SHARD_MSM_MIN_LOGN", "SPECTRE_SHARD_NTT_MIN_LOGN",
                "SPECTRE_SHARD_QUOTIENT_MIN_LOGN")

@@ -648,34 +648,6 @@ def _build_coset_intt_std_vinv():
     return build
 
 
-def _build_pallas_padd():
-    def build():
-        import jax.numpy as jnp
-        from ..ops import msm_pallas as MP
-        p = jnp.asarray(_u32((48, 4)))
-        q = jnp.asarray(_u32((48, 4)))
-        return (lambda a, b: MP._k_padd(a, b)), (p, q)
-    return build
-
-
-def _build_pallas_bucket():
-    def build():
-        import jax.numpy as jnp
-        from ..ops import msm_pallas as MP
-        # c=4: nb=2^(c-1)=8 signed-digit buckets, 2 windows, 4 points —
-        # the exact body _bucket_kernel runs per block (pts/digs/negs/
-        # buckets ref loads), traced here so KL rules walk the nested
-        # fori_loops and prove the uint32 accumulators hold
-        pts = jnp.asarray(_u32((1, 48, 4)))
-        digs = jnp.zeros((2, 4), jnp.int32)
-        negs = jnp.asarray(_u32((1, 4)))
-        buckets = jnp.asarray(_u32((2, 48, 8)))
-        return (lambda p, d, g, b:
-                MP._k_bucket_accumulate(p, d, g, b)), (pts, digs, negs,
-                                                       buckets)
-    return build
-
-
 def _build_glv_device():
     def build():
         import jax.numpy as jnp
@@ -952,16 +924,6 @@ KERNELS = [
                _build_dft_matmul_split()),
     KernelSpec("ntt.coset_intt_std_vinv", "spectre_tpu/ops/ntt.py",
                _build_coset_intt_std_vinv()),
-    # Pallas MSM complete-add body: the exact jaxpr pallas_call runs per
-    # block, traced directly so KL rules see the CIOS scans
-    KernelSpec("msm_pallas.padd_body", "spectre_tpu/ops/msm_pallas.py",
-               _build_pallas_padd()),
-    # VMEM-resident bucket accumulation body (this PR): signed digits are
-    # int32 lanes (|d| <= 2^(c-1), declared 4 bits at the c=4 probe shape),
-    # the GLV sign mask is 1 bit, and the resident bucket tensor must stay
-    # a sound 16-bit-limb uint32 accumulator through the cneg+padd chain
-    KernelSpec("msm_pallas.bucket_body", "spectre_tpu/ops/msm_pallas.py",
-               _build_pallas_bucket(), in_bits=[16, 4, 1, 16]),
     # on-device GLV Babai rounding (this PR): exact Barrett floor division
     # + mod-2^144 two's-complement residuals, all in uint32 limb lanes
     KernelSpec("glv.decompose_device", "spectre_tpu/ops/glv.py",

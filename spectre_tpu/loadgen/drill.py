@@ -7,7 +7,7 @@ requests/s, the 304 ratio, and the gateway's own counters
 the paper's serving story in miniature:
 
 * **population** — ``clients`` simulated light clients (default 10^6
-  from the CLI, 10^4 in the bench tier). Each client keeps a small
+  from the CLI, 10^4 in the tests). Each client keeps a small
   client-side digest cache (the ETag of every response it has seen) and
   sends ``If-None-Match`` on revisits — exactly what
   ``rpc_client.ProverClient.get_update_cached`` does for real clients.
@@ -22,7 +22,7 @@ the paper's serving story in miniature:
 
 Targets are duck-typed: :class:`InProcessTarget` drives a
 :class:`~spectre_tpu.gateway.Gateway` directly (zero HTTP overhead —
-what the bench tier measures), :class:`HttpTarget` drives a live
+what the tests drive), :class:`HttpTarget` drives a live
 server's ``/v1/*`` routes over urllib. Everything is stdlib; no numpy
 on the request path.
 """
@@ -57,7 +57,7 @@ class ZipfSampler:
 
 
 class InProcessTarget:
-    """Drives a Gateway object directly — the bench tier's target."""
+    """Drives a Gateway object directly — the tests' target."""
 
     def __init__(self, gateway):
         self.gateway = gateway

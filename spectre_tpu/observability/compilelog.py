@@ -47,9 +47,9 @@ BACKEND_COMPILE = "backend_compile"
 
 # plain (duration-less) jax.monitoring events fired by the PERSISTENT
 # compilation cache on every lookup: a hit means the XLA compile step was
-# skipped entirely (tracing/lowering still ran). Surfaced so bench JSON
+# skipped entirely (tracing/lowering still ran). Surfaced so a manifest
 # can distinguish "warm disk cache" from "genuinely recompiled" — the
-# multichip SPMD programs are minutes-scale compiles on this box
+# multichip SPMD programs are minutes-scale compiles
 PERSISTENT_CACHE_EVENTS = {
     "/jax/compilation_cache/cache_hits": "hit",
     "/jax/compilation_cache/cache_misses": "miss",

@@ -46,7 +46,7 @@ MANIFEST_SUFFIX = ".manifest.json"
 # two manifests always diff key-for-key
 ENV_KNOBS = (
     "SPECTRE_MSM_MODE", "SPECTRE_NTT_MODE",
-    "SPECTRE_NTT_KERNEL", "SPECTRE_MSM_IMPL", "SPECTRE_MSM_WINDOW",
+    "SPECTRE_NTT_KERNEL", "SPECTRE_MSM_WINDOW",
     "SPECTRE_QUOTIENT_FUSED_VINV",
     "SPECTRE_MSM_TABLE_MB", "SPECTRE_NTT_TABLE_MB",
     "SPECTRE_QUOTIENT_CACHE_MB", "SPECTRE_FIELD_IMPL",

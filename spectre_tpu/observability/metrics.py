@@ -249,8 +249,7 @@ PROVE_LATENCY = REGISTRY.histogram(
     "End-to-end prove latency per completed job (seconds)",
     LATENCY_BUCKETS)
 
-# per-phase wall clock, fed by utils/profiling.phase — the production
-# counterpart of bench.py's MSM/NTT phase decomposition
+# per-phase wall clock, fed by utils/profiling.phase
 PHASE_SECONDS = REGISTRY.histogram_vec(
     "spectre_phase_seconds",
     "Wall-clock seconds per instrumented prover phase",

@@ -75,7 +75,7 @@ class Span:
 
 
 class Trace:
-    """One span tree; trace id = job id (or a bench run label)."""
+    """One span tree; trace id = job id (or a caller's own label)."""
 
     def __init__(self, trace_id: str):
         self.trace_id = trace_id
@@ -114,7 +114,7 @@ def _keep() -> int:
 def trace(trace_id: str):
     """Open a trace on the current thread; on exit it is finished,
     registered for `getTrace`, and (optionally) written to the file
-    sink. Nesting restores the previous trace (bench wraps sub-runs)."""
+    sink. Nesting restores the previous trace."""
     prev_trace, prev_stack = _local.trace, _local.stack
     tr = Trace(trace_id)
     _local.trace, _local.stack = tr, [tr.root]
@@ -269,8 +269,7 @@ def chrome_trace(tr: Trace) -> dict:
 def summary(tr: Trace | None) -> dict:
     """One walk of the tree (root excluded; no trace, nothing) for the
     manifest:
-    `phase_seconds`, total seconds per span name — the shared schema
-    between production traces and bench.py's `phase_seconds` key;
+    `phase_seconds`, total seconds per span name;
     `span_counts`, spans per name; `transfer_bytes`, the `bytes` metadata
     summed over the `encode` spans (`h2d`) and the `wait` spans (`d2h`);
     `msm_columns`, over the spans that carry a `batch` and a `width` (a

@@ -171,8 +171,7 @@ def cneg(mask, p):
     `padd` — the complete formulas treat any Z = 0 input as the identity —
     but it means bucket/accumulator states are only representative-equal,
     never bit-equal, once a masked infinity has passed through. Compare
-    via decode_points (or a Z-normalizing hash), not raw limbs; the
-    in-kernel mirror `msm_pallas._k_cneg` inherits the same contract."""
+    via decode_points (or a Z-normalizing hash), not raw limbs."""
     return select_point(mask, pneg(p), p)
 
 

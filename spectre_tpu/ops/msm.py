@@ -51,8 +51,10 @@ optimizations (`SPECTRE_MSM_MODE`, see `msm_mode()`):
               that would exceed the budget BY ITSELF degrades the call to
               glv+signed instead of thrashing (see _degrade_fixed).
 
-All modes produce the identical group element (the byteeq harness pins
-byte-identical commitments); they differ only in work shape.
+All modes produce the identical group element (tests/test_msm_modes.py
+holds each to the host curve, tests/test_device_prove.py the default inside
+byte-equal proofs); they differ only in work shape. Only `vanilla` has run
+on the chip (PERF.md section 7).
 """
 
 from __future__ import annotations
@@ -74,7 +76,7 @@ MSM_MODES = ("vanilla", "glv", "glv+signed", "fixed")
 
 def msm_mode() -> str:
     """Active MSM mode from SPECTRE_MSM_MODE (default: vanilla). Read per
-    call so tests/benches can flip it without reimporting."""
+    call so a test can flip it without reimporting."""
     mode = os.environ.get("SPECTRE_MSM_MODE", "vanilla")
     if mode not in MSM_MODES:
         raise ValueError(
@@ -82,36 +84,17 @@ def msm_mode() -> str:
     return mode
 
 
-MSM_IMPLS = ("xla", "pallas")
-
-
-def msm_impl() -> str:
-    """Active MSM implementation from SPECTRE_MSM_IMPL (default: xla).
-
-    `pallas` routes EVERY mode's bucket phase through the VMEM-resident
-    bucket kernel (`ops/msm_pallas.py`; interpret-mode off-TPU): vanilla
-    recodes the full scalars to signed digits, glv/glv+signed decompose
-    on device (glv.decompose_device) and share the signed kernel, fixed
-    feeds its endo-expanded window tables as SoA blocks. Only the mesh-
-    sharded and DP-batch runners stay XLA — those degrade visibly
-    (_record_pallas_degrade)."""
-    impl = os.environ.get("SPECTRE_MSM_IMPL", "xla")
-    if impl not in MSM_IMPLS:
-        raise ValueError(
-            f"SPECTRE_MSM_IMPL={impl!r}: expected one of {MSM_IMPLS}")
-    return impl
-
-
 def window_override() -> int | None:
     """Operator window override from SPECTRE_MSM_WINDOW (1..13, empty/unset
-    = the tuned table). The device retuning knob: every `default_window*`
-    consumer (ops/msm.py, parallel/batch_msm.py, plonk/backend.py) honors
-    it without plumbing c by hand. The ceiling of 13 is not a memory limit
-    (the aggregation's widest tensor, [nwin, 2^h + 2^l, 2^l] points, is
-    47 MB at c = 13): it is the widest window any table entry or test has
-    run, and since the emission array [levels + 1, 2^c] charges every
-    window ~levels * 2^c additions, no size this prover commits
-    (n <= 2^23) is served by a wider one."""
+    = the tuned table). The device retuning knob: every caller of
+    `default_window` / `default_window_fixed` (`msm` here,
+    parallel/batch_msm.py, plonk/backend.py) honors it without plumbing c
+    by hand. The ceiling of 13 is not a memory limit (the aggregation's
+    widest tensor, [nwin, 2^h + 2^l, 2^l] points, is 47 MB at c = 13): it
+    is the widest window any table entry or test has run, and since the
+    emission array [levels + 1, 2^c] charges every window ~levels * 2^c
+    additions, no size this prover commits (n <= 2^23) is served by a
+    wider one."""
     v = os.environ.get("SPECTRE_MSM_WINDOW")
     if v is None or v == "":
         return None
@@ -347,7 +330,7 @@ def _apply_sign(points, neg):
 def _glv_scalars_device(scalars):
     """(sc2 [2n, 8], neg [2n]) via the TRACED decomposition — no host
     round trip (glv.decompose_device matches decompose_batch bit-exactly,
-    so every impl/mode keeps byte-identical results)."""
+    so every mode keeps byte-identical results)."""
     from . import glv
     a1, a2, n1, n2 = glv.decompose_device(jnp.asarray(scalars))
     return (jnp.concatenate([a1, a2], axis=0),
@@ -364,44 +347,6 @@ def glv_split(points, scalars):
     device windows."""
     sc2, neg = _glv_scalars_device(scalars)
     return _expand_endo(points), sc2, neg
-
-
-def _msm_pallas(points, scalars, c, mode: str, base_key):
-    """SPECTRE_MSM_IMPL=pallas dispatch: every mode through the
-    VMEM-resident bucket kernel (ops/msm_pallas). Mode differences that
-    change the group-element computation shape are preserved (GLV point
-    expansion, fixed-base tables, table-budget degrade); digit recoding is
-    canonicalized to signed digits in-kernel — the same group element with
-    half the bucket columns, pinned byte-identical by tests."""
-    from . import msm_pallas as MP
-
-    n = points.shape[0]
-    if mode == "vanilla":
-        # default_window_pallas caps at 11: the kernel keeps all nwin bucket
-        # arrays VMEM-resident and 254-bit scalars double nwin vs the GLV
-        # paths (see the VMEM budget note in msm_pallas)
-        cc = c if c is not None else default_window_pallas(n)
-        return MP.combine_windows_soa(
-            MP.msm_bucket_windows(MP.to_soa(points), scalars, None, cc, 254),
-            cc)
-
-    from . import glv
-    nbits = glv.glv_bits()
-    if mode == "fixed":
-        cf = c if c is not None else default_window_pallas(2 * n, signed=True)
-        if _degrade_fixed(n, cf, nbits):
-            mode = "glv+signed"
-        else:
-            nwin = (nbits + cf) // cf
-            sc2, neg = _glv_scalars_device(scalars)
-            table = fixed_base_table(points, cf, nwin, base_key=base_key)
-            return MP.msm_bucket_fixed(
-                MP.to_soa_windows(table), sc2, neg, cf, nbits)
-
-    cc = c if c is not None else default_window_pallas(2 * n, signed=True)
-    pts2, sc2, neg = glv_split(points, scalars)
-    return MP.combine_windows_soa(
-        MP.msm_bucket_windows(MP.to_soa(pts2), sc2, neg, cc, nbits), cc)
 
 
 # ---------------------------------------------------------------------------
@@ -550,19 +495,6 @@ def _fixed_fits_budget(n: int, c: int, nbits: int) -> bool:
     return _fixed_table_bytes(n, c, nbits) <= _TABLES.budget
 
 
-def _record_pallas_degrade(mode: str, n, c, site: str):
-    """SPECTRE_MSM_IMPL=pallas asked for the fused kernel but `site` has no
-    pallas lowering (the mesh-sharded and DP-batch runners are XLA
-    shard_map programs): fall back to XLA VISIBLY — a ServiceHealth counter
-    (`spectre_msm_pallas_degraded_total` in /metrics) plus a provenance
-    event carrying enough detail (mode, n, c, caller site) to find the
-    half-covered path from a farm manifest."""
-    from ..utils.health import HEALTH
-    HEALTH.incr("msm_pallas_degraded")
-    _record_event("msm_pallas_unsupported_mode", mode=mode, n=int(n),
-                  c=None if c is None else int(c), site=site)
-
-
 def _degrade_fixed(n: int, c: int, nbits: int) -> bool:
     """Graceful degradation (ISSUE 3): when one fixed-base table would
     exceed the SPECTRE_MSM_TABLE_MB budget, fall back to glv+signed
@@ -681,44 +613,6 @@ def default_window_fixed(n: int) -> int:
     return default_window(n, signed=True)
 
 
-# VMEM the pallas bucket kernel may spend on resident bucket arrays. 8 MB
-# leaves half of a 16 MB core for the double-buffered point DMA and the
-# aggregation scratch (see the budget note in msm_pallas).
-_PALLAS_BUCKET_VMEM_BUDGET = 8 << 20
-
-
-def _pallas_bucket_bytes(c: int, nbits: int) -> int:
-    """Bytes of VMEM the kernel's resident buckets claim at window width c:
-    all nwin [48, 2^(c-1)] u32 bucket arrays live for the whole grid."""
-    nwin = (nbits + c) // c
-    return nwin * 48 * (1 << (c - 1)) * 4
-
-
-def default_window_pallas(n: int, signed: bool = False) -> int:
-    """Window table for the pallas bucket kernel (SPECTRE_MSM_IMPL=pallas).
-
-    The XLA table tunes around a window's fixed cost and its emission
-    tree; the bucket kernel's binding constraint is VMEM residency, so it gets
-    its own table: start from the XLA width for the size class and shrink
-    until the resident buckets fit _PALLAS_BUCKET_VMEM_BUDGET. 254-bit
-    vanilla scalars (nwin ~ 254/c, roughly double the GLV window count)
-    land on c <= 11 (~4.5 MB) where c = 13 would claim ~15 MB; the 126-bit
-    signed/GLV paths fit their XLA widths unchanged (c = 13 is 7.5 MB).
-    The CPU interpret-mode sweep in BASELINE.md (PR 19) byte-checks every
-    width and records compile cost; it is NOT a silicon tuning run, so the
-    table is sized by the VMEM budget, not by those timings.
-    SPECTRE_MSM_WINDOW still overrides the whole table (via
-    default_window)."""
-    from . import glv
-    nbits = glv.glv_bits() if signed else 254
-    c = default_window(n, signed=signed)
-    if window_override() is not None:
-        return c
-    while c > 1 and _pallas_bucket_bytes(c, nbits) > _PALLAS_BUCKET_VMEM_BUDGET:
-        c -= 1
-    return c
-
-
 def msm(points, scalars, c: int | None = None, mode: str | None = None,
         base_key=None):
     """Full MSM on one device. points [n,3,16] proj Montgomery
@@ -731,8 +625,6 @@ def msm(points, scalars, c: int | None = None, mode: str | None = None,
     if mode not in MSM_MODES:
         raise ValueError(f"unknown MSM mode {mode!r}")
     n = points.shape[0]
-    if msm_impl() == "pallas":
-        return _msm_pallas(points, scalars, c, mode, base_key)
     if mode == "vanilla":
         if c is None:
             c = default_window(n)

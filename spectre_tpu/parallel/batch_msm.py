@@ -133,15 +133,10 @@ def batch_msm_dp(points, scalars_batch, c: int | None = None,
 
     Window width: explicit `c` wins; otherwise `MSM.default_window`, which
     honors the SPECTRE_MSM_WINDOW override before its tuned table — one env
-    knob sweeps every MSM path (bench.py --sweep-window)."""
+    knob sweeps every MSM path."""
     n = points.shape[0]
     if c is None:
         c = MSM.default_window(n, signed=signed)
-    if MSM.msm_impl() == "pallas":
-        # the DP shard_map runner has no pallas lowering — fall back to
-        # XLA visibly (health counter + provenance event, ops/msm.py)
-        MSM._record_pallas_degrade(MSM.msm_mode(), n, c,
-                                   "parallel.batch_msm_dp")
     mesh = mesh or _batch_mesh()
     ndev = mesh.shape["batch"]
     b = scalars_batch.shape[0]

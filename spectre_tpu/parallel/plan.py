@@ -166,7 +166,7 @@ class ShardingPlan:
     def batch_axis(self) -> str:
         return "batch"
 
-    # -- introspection (bench JSON / manifests) --
+    # -- introspection (manifests) --
 
     def describe(self) -> dict:
         return {

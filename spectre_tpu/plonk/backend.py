@@ -66,8 +66,8 @@ _CACHE_ROOT = os.path.normpath(os.path.join(
 
 
 def setup_compile_cache():
-    """Persistent JAX compile cache (shared policy for bench, backends,
-    tests, and entry points).
+    """Persistent JAX compile cache (shared policy for backends, tests,
+    and entry points).
 
     `JAX_COMPILATION_CACHE_DIR` places the cache from outside: when it is
     set JAX reads it itself and this function sets NO directory in code.
@@ -356,11 +356,6 @@ class TpuBackend(CpuBackend):
         SM = importlib.import_module("spectre_tpu.parallel.sharded_msm")
 
         mode = MSM.msm_mode()
-        if MSM.msm_impl() == "pallas":
-            # the shard_map mesh program has no pallas lowering — fall
-            # back to XLA visibly (health counter + provenance event)
-            MSM._record_pallas_degrade(mode, m, None,
-                                       "backend._msm_sharded")
         plan = current_plan()
         call = "backend/msm_sharded"
 
@@ -421,8 +416,7 @@ class TpuBackend(CpuBackend):
                     ng = np.zeros_like(ng)
                 if mode == "vanilla":
                     # mesh-tuned static window; SPECTRE_MSM_WINDOW still
-                    # wins so a sweep (bench.py --sweep-window) exercises
-                    # the sharded path too
+                    # wins so a sweep exercises the sharded path too
                     c = MSM.window_override() or (
                         13 if mp >= (1 << 18) else 10)
                 else:
@@ -441,14 +435,13 @@ class TpuBackend(CpuBackend):
         (SURVEY §2c(b): inter-proof/column DP). On one device, in the
         default mode, the columns go through `_msm_chunks`, 16 to a device
         run and a read (PERF.md section 5 has the chip's readings of it
-        against a loop of `msm`); the other modes and
-        SPECTRE_MSM_IMPL=pallas, which have not run on the chip, loop
-        `msm`. GLV modes
-        thread the scalar-prep stage through the DP path: half-scalars and
-        sign masks are stacked per batch row against ONE replicated
-        endomorphism-expanded base (`fixed` uses the glv+signed kernels
-        here — replicating a per-window table across the mesh would
-        multiply its memory by the device count)."""
+        against a loop of `msm`); the other modes, which have not run on
+        the chip, loop `msm`. GLV modes thread the scalar-prep stage
+        through the DP path: half-scalars and sign masks are stacked per
+        batch row against ONE replicated endomorphism-expanded base
+        (`fixed` uses the glv+signed kernels here — replicating a
+        per-window table across the mesh would multiply its memory by the
+        device count)."""
         import jax
         import jax.numpy as jnp
 
@@ -511,11 +504,10 @@ class TpuBackend(CpuBackend):
     @staticmethod
     def _one_chip_default() -> bool:
         """One device and the MSM as the chip has run it (SPECTRE_MSM_MODE
-        vanilla on the XLA kernels): what `_msm_chunks` serves."""
+        vanilla): what `_msm_chunks` serves."""
         from ..ops import msm as MSM
         from ..parallel.plan import current_plan
-        return (current_plan().n_devices == 1
-                and MSM.msm_mode() == "vanilla" and MSM.msm_impl() == "xla")
+        return current_plan().n_devices == 1 and MSM.msm_mode() == "vanilla"
 
     def _msm_chunks(self, call: str, points, scalars_list) -> list:
         """Commit `scalars_list` against one resident base, MSM.CHUNK_WIDTH

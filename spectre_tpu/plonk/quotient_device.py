@@ -263,8 +263,8 @@ def _shard_min_logn() -> int:
     and a dev-box 8-virtual-device mesh would otherwise silently route every
     ordinary test prove through the mesh runners on one physical core. The
     default mirrors SHARD_NTT_MIN_LOGN (the quotient is NTT-dominated):
-    high enough that only an explicit opt-in (bench-quotient-multichip, the
-    sharded-quotient tests) engages the mesh on a virtual-device box."""
+    high enough that only an explicit opt-in (the sharded-quotient tests)
+    engages the mesh on a virtual-device box."""
     return int(os.environ.get("SPECTRE_SHARD_QUOTIENT_MIN_LOGN", "18"))
 
 

@@ -184,8 +184,6 @@ def main(argv=None):
     u.add_argument("util", choices=["committee-poseidon"])
     u.add_argument("--beacon-api", help="Beacon REST base URL")
 
-    b = sub.add_parser("bench", help="run the MSM benchmark")
-
     fl = sub.add_parser("faults", help="fault-injection site registry")
     fl.add_argument("--list", action="store_true",
                     help="print the site table (markdown, the source of "
@@ -275,9 +273,6 @@ def main(argv=None):
               **queue_kw)
     elif args.cmd == "utils":
         _utils_cmd(args, spec)
-    elif args.cmd == "bench":
-        import subprocess
-        subprocess.run([sys.executable, "bench.py"], check=True)
     elif args.cmd == "follow":
         _follow_cmd(args, spec)
     elif args.cmd == "faults":

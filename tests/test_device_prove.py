@@ -473,7 +473,6 @@ class TestOneChipBatchedCommit:
     def one_chip(self, monkeypatch):
         monkeypatch.setenv("SPECTRE_MESH_SHAPE", "1x1")
         monkeypatch.delenv("SPECTRE_MSM_MODE", raising=False)
-        monkeypatch.delenv("SPECTRE_MSM_IMPL", raising=False)
         return B.TpuBackend()
 
     @pytest.mark.parametrize("length", [1, 2, 3, 10, 16, 17])

@@ -341,8 +341,7 @@ class TestSdcReroute:
         return d, ids, calls
 
     def test_sdc_reproved_on_different_replica(self, tmp_path, monkeypatch):
-        # an earlier bench run may have left SPECTRE_SELF_VERIFY=off in
-        # the process env; cross-verification honors the same policy knob
+        # cross-verification honors the process's self-verify policy knob
         monkeypatch.setenv("SPECTRE_SELF_VERIFY", "always")
         d, ids, calls = self._farm(tmp_path)
         sdc0 = HEALTH.get("dispatcher_sdc_rerouted")

@@ -515,30 +515,3 @@ class TestScrubberPacing:
                       min_age_s=0, budget_s=0.0)
         sc.last_pass_s = 1e9
         assert sc.next_interval(300.0) == 300.0
-
-
-# ---------------------------------------------------------------------------
-# bench knob (ISSUE 9 small fix)
-# ---------------------------------------------------------------------------
-
-class TestBenchSelfVerifyKnob:
-    def test_bench_defaults_self_verify_off(self, monkeypatch):
-        import bench
-        monkeypatch.delenv("SPECTRE_SELF_VERIFY", raising=False)
-        monkeypatch.setenv("BENCH_METRIC", "none")   # no benches, just setup
-        monkeypatch.setattr(sys, "argv", ["bench.py", "--fast"])
-        bench.main()
-        assert os.environ.get("SPECTRE_SELF_VERIFY") == "off"
-
-    @pytest.mark.slow
-    @pytest.mark.skipif(not RUN_SLOW, reason="bench subprocess (RUN_SLOW=1)")
-    def test_bench_fast_clears_floors_with_self_verify_on(self):
-        env = dict(os.environ, SPECTRE_SELF_VERIFY="always")
-        r = subprocess.run([sys.executable, "bench.py", "--fast"],
-                           capture_output=True, text=True, env=env,
-                           cwd=os.path.dirname(os.path.dirname(
-                               os.path.abspath(__file__))))
-        assert r.returncode == 0, r.stdout + "\n" + r.stderr
-        recs = [json.loads(ln) for ln in r.stdout.splitlines()
-                if ln.startswith("{")]
-        assert any(rec.get("self_verify") == "always" for rec in recs)

@@ -197,8 +197,8 @@ class TestCompilelog:
     def test_persistent_cache_events_counted(self):
         """The plain-event listener (ISSUE 13): persistent compile-cache
         hit/miss events land in the capture sink and in summarize()'s
-        persistent_cache key — the bench-multichip 'warm disk cache vs
-        genuinely recompiled' signal."""
+        persistent_cache key — the 'warm disk cache vs genuinely
+        recompiled' signal."""
         before = compilelog.cache_counts()
         with compilelog.capture() as cev:
             compilelog._event_listener("/jax/compilation_cache/cache_hits")
@@ -609,34 +609,3 @@ class TestReportCli:
         from spectre_tpu.observability.__main__ import main
         base = self._write(tmp_path, "base")
         assert main(["report", str(base), "--ci"]) == 2
-
-
-# ---------------------------------------------------------------------------
-# bench: compile telemetry rides along, floors still gate run time only
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.skipif(not __import__("os").environ.get("RUN_SLOW"),
-                    reason="runs the full bench-fast tier (set RUN_SLOW=1)")
-def test_bench_fast_floors_clear_with_compile_hook(tmp_path):
-    """ISSUE-8 satellite pin: `bench.py --fast` with the compilelog hook
-    installed still clears the checked-in msm/ntt floors (the hook must
-    not slow the gated run loop), and every record carries
-    `compile_seconds` SEPARATELY from the floor-gated throughput."""
-    import os
-    import subprocess
-    import sys
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, "bench.py", "--fast"], env=env,
-        capture_output=True, text=True, timeout=600,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    records = [json.loads(ln) for ln in proc.stdout.splitlines() if ln]
-    assert len(records) >= 2                         # msm + ntt
-    for rec in records:
-        assert rec.get("regression") is False, rec   # floors clear
-        assert rec["compile_seconds"] >= 0.0
-        assert rec["compile_count"] >= 0
-        # gated value is throughput, not wall time including compiles
-        assert rec["value"] > 0

@@ -83,7 +83,8 @@ class TestGLVDecompose:
 class TestGLVDeviceDecompose:
     """The traced on-device Babai rounding (glv.decompose_device) must be
     BIT-EXACT against the host decompose_batch — magnitudes AND signs —
-    or pallas/xla proofs silently diverge."""
+    or the device-decomposed modes silently diverge from the mesh runners,
+    which decompose on the host."""
 
     def _device_vs_host(self, ks):
         limbs = np.asarray(L.ints_to_limbs16(ks), dtype=np.uint32)
@@ -311,31 +312,9 @@ class TestDefaultWindowTuning:
             assert MSM.default_window_fixed(n) == \
                 MSM.default_window(n, signed=True)
 
-    def test_pinned_pallas(self):
-        # pallas buckets are VMEM-resident: 254-bit vanilla scalars double
-        # nwin vs GLV, so the 2^18 class drops 13 -> 11 (~4.5 MB resident
-        # vs ~15 MB); the 126-bit signed paths fit their XLA widths.
-        assert [MSM.default_window_pallas(n) for n in
-                (1 << 6, 1 << 7, 1 << 12, 1 << 18)] == [4, 7, 10, 11]
-        assert [MSM.default_window_pallas(n, signed=True) for n in
-                (1 << 6, 1 << 7, 1 << 12, 1 << 18)] == [5, 8, 11, 13]
-        # every pallas width actually fits the budget
-        for signed, nbits in ((False, 254), (True, 126)):
-            for n in (1 << 6, 1 << 12, 1 << 18):
-                c = MSM.default_window_pallas(n, signed=signed)
-                assert MSM._pallas_bucket_bytes(c, nbits) <= \
-                    MSM._PALLAS_BUCKET_VMEM_BUDGET
-
-    def test_pallas_override_wins(self, monkeypatch):
-        monkeypatch.setenv("SPECTRE_MSM_WINDOW", "12")
-        # the sweep knob must reach the pallas dispatch too, even past the
-        # VMEM table (a real-hardware sweep needs to probe beyond the cap)
-        assert MSM.default_window_pallas(1 << 18) == 12
-
 
 class TestWindowOverride:
-    """SPECTRE_MSM_WINDOW: one env knob retunes every MSM path (the value
-    a bench.py --sweep-window run picks on real hardware)."""
+    """SPECTRE_MSM_WINDOW: one env knob retunes every MSM path."""
 
     def test_override_wins_over_tables(self, monkeypatch):
         monkeypatch.setenv("SPECTRE_MSM_WINDOW", "9")
@@ -358,130 +337,6 @@ class TestWindowOverride:
         monkeypatch.setenv("SPECTRE_MSM_WINDOW", bad)
         with pytest.raises(ValueError):
             MSM.window_override()
-
-
-class TestImplDispatch:
-    """SPECTRE_MSM_IMPL: xla (default) vs the pallas SoA kernel path."""
-
-    def test_env_validation(self, monkeypatch):
-        monkeypatch.delenv("SPECTRE_MSM_IMPL", raising=False)
-        assert MSM.msm_impl() == "xla"
-        monkeypatch.setenv("SPECTRE_MSM_IMPL", "pallas")
-        assert MSM.msm_impl() == "pallas"
-        monkeypatch.setenv("SPECTRE_MSM_IMPL", "cuda")
-        with pytest.raises(ValueError):
-            MSM.msm_impl()
-
-    def test_pallas_routes_vanilla(self, monkeypatch):
-        from spectre_tpu.ops import msm_pallas as MP
-        calls = []
-        wins_sentinel = object()
-        out_sentinel = jnp.zeros((3, 16), dtype=jnp.uint32)
-        monkeypatch.setattr(
-            MP, "msm_bucket_windows",
-            lambda soa, sc, neg, c, nbits:
-                calls.append((soa.shape, neg, int(c), int(nbits)))
-                or wins_sentinel)
-        monkeypatch.setattr(
-            MP, "combine_windows_soa",
-            lambda wins, c: out_sentinel if wins is wins_sentinel else None)
-        monkeypatch.setenv("SPECTRE_MSM_IMPL", "pallas")
-        pts = ec.encode_points(
-            [bn.g1_curve.mul(bn.G1_GEN, k + 1) for k in range(4)])
-        ss = jnp.asarray(L.ints_to_limbs16([k + 1 for k in range(4)]))
-        out = MSM.msm(pts, ss, c=4, mode="vanilla")
-        assert out is out_sentinel
-        assert calls == [((MP.ROWS, 4), None, 4, 254)]
-
-    def test_bucket_kernel_in_jaxpr_not_emission_path(self):
-        """Structural pin for the tentpole: the pallas bucket pipeline's
-        jaxpr contains the pallas_call bucket kernel and NONE of the old
-        XLA argsort/scatter emission ops (the `_segmented_bucket_sums_soa`
-        path this PR deleted)."""
-        from spectre_tpu.ops import msm_pallas as MP
-        sc = jnp.zeros((4, 8), jnp.uint32)
-        soa = MP.inf_soa(4)
-        jaxpr = str(jax.make_jaxpr(
-            lambda p, s: MP._bucket_windows_jit.__wrapped__(
-                p, s, None, 3, 8, True))(soa, sc))
-        assert "pallas_call" in jaxpr
-        # primitive applications print as `sort[`/`scatter...[` — plain
-        # substring would trip on the `indices_are_sorted=` gather param
-        import re
-        assert not re.search(r"\bsort\[|\bscatter", jaxpr)
-        assert not hasattr(MP, "_segmented_bucket_sums_soa")
-
-    @pytest.mark.slow
-    def test_pallas_all_modes_match_oracle_no_degrade(self, monkeypatch):
-        """The mode x impl matrix (tentpole acceptance): every
-        SPECTRE_MSM_MODE under SPECTRE_MSM_IMPL=pallas runs the
-        interpret-mode bucket kernel, matches the host-curve oracle, emits
-        ZERO msm_pallas_unsupported_mode events, and never round-trips
-        scalars through the host GLV decomposition (decompose_limbs16 is
-        poisoned for the duration). slow marker = the four interpret-mode
-        compile chains (~40s, 1-core box); `make test-slow` runs it (plain
-        pytest, no marker filter) — the 870s driver tier keeps only the
-        structural pins above."""
-        events = []
-        monkeypatch.setattr(
-            MSM, "_record_event",
-            lambda kind, **detail: events.append((kind, detail)))
-
-        def _no_host(*a, **k):
-            raise AssertionError(
-                "host glv.decompose_limbs16 called on the pallas path — "
-                "the GLV Babai rounding must stay on device")
-        monkeypatch.setattr(glv, "decompose_limbs16", _no_host)
-        monkeypatch.setenv("SPECTRE_MSM_IMPL", "pallas")
-
-        n = 6
-        pts = [bn.g1_curve.mul(bn.G1_GEN, 5 * k + 2) for k in range(n)]
-        pts[3] = None
-        scalars = [secrets.randbelow(bn.R) for _ in range(n)]
-        scalars[0], scalars[1], scalars[2] = 0, 1, bn.R - 1
-        want = bn.g1_curve.msm(pts, scalars)
-        want = (int(want[0]), int(want[1]))
-        pp = ec.encode_points(pts)
-        ss = jnp.asarray(L.ints_to_limbs16(scalars))
-        # c=3 shared across modes: the padd/bucket compile shapes are
-        # process-cached, keeping the fast-tier matrix seconds-scale
-        for mode in MSM.MSM_MODES:
-            got = ec.decode_points(MSM.msm(pp, ss, c=3, mode=mode)[None])[0]
-            assert got == want, mode
-        assert not [e for e in events
-                    if e[0] == "msm_pallas_unsupported_mode"], events
-
-    def test_dp_runner_records_degrade_event(self, monkeypatch):
-        """The DP shard_map runner stays XLA: under impl=pallas it must
-        fall back VISIBLY — provenance event with n, c, and caller site,
-        plus the msm_pallas_degraded health counter. The SPMD runner is
-        stubbed out (the degrade record happens before dispatch; compiling
-        the real 8-way mesh program costs ~20s and is the trace-lint
-        probes' job)."""
-        from spectre_tpu.parallel import batch_msm as BM
-        from spectre_tpu.parallel.batch_msm import batch_msm_dp
-        from spectre_tpu.utils.health import HEALTH
-        events = []
-        monkeypatch.setattr(
-            MSM, "_record_event",
-            lambda kind, **detail: events.append((kind, detail)))
-        monkeypatch.setattr(
-            BM, "_runner_glv",
-            lambda mesh, c, nbits, signed:
-                lambda p, s, g: jnp.zeros(
-                    (s.shape[0], 3, 16), jnp.uint32))
-        monkeypatch.setenv("SPECTRE_MSM_IMPL", "pallas")
-        before = HEALTH.get("msm_pallas_degraded")
-        pts = jnp.zeros((8, 3, 16), jnp.uint32)
-        sb = jnp.zeros((2, 8, 8), jnp.uint32)
-        ng = jnp.zeros((2, 8), bool)
-        batch_msm_dp(pts, sb, c=2, neg_batch=ng, nbits=4, signed=True)
-        assert HEALTH.get("msm_pallas_degraded") == before + 1
-        kinds = [e for e in events if e[0] == "msm_pallas_unsupported_mode"]
-        assert len(kinds) == 1
-        detail = kinds[0][1]
-        assert detail["n"] == 8 and detail["c"] == 2
-        assert detail["site"] == "parallel.batch_msm_dp"
 
 
 def _seeded_poly(n: int):
@@ -514,34 +369,6 @@ class TestMsmModeCommitments:
             got = kzg.commit(srs, coeffs, bk)
             assert got == oracle, \
                 f"SPECTRE_MSM_MODE={mode} commitment diverged from oracle"
-
-    @pytest.mark.slow
-    def test_pallas_impl_commitments_byte_identical(self, tiny, monkeypatch):
-        """ISSUE 17 tier of the same gate, impl axis: every mode under
-        SPECTRE_MSM_IMPL=pallas (interpret mode off-TPU) commits to the
-        SAME bytes as the CPU oracle through the device backend, and none
-        of the four modes falls back to XLA (zero unsupported-mode
-        events). Slow tier: four interpret-mode pallas compile chains at
-        K=7 cost ~100s on the 1-core box; the fast tier covers the same
-        matrix at MSM level in test_msm_modes."""
-        srs, coeffs = tiny.srs, _seeded_poly(tiny.srs.n)
-        oracle = kzg.commit(srs, coeffs, B.get_backend("cpu"))
-        events = []
-        orig = MSM._record_event
-        monkeypatch.setattr(
-            MSM, "_record_event",
-            lambda name, **kw: (events.append((name, kw)),
-                                orig(name, **kw)))
-        monkeypatch.setenv("SPECTRE_MSM_IMPL", "pallas")
-        bk = B.get_backend("tpu")
-        for mode in ("glv+signed", "glv", "fixed", "vanilla"):
-            monkeypatch.setenv("SPECTRE_MSM_MODE", mode)
-            got = kzg.commit(srs, coeffs, bk)
-            assert got == oracle, \
-                f"impl=pallas mode={mode} commitment diverged from oracle"
-        bad = [e for e in events if e[0] == "msm_pallas_unsupported_mode"]
-        assert not bad, f"pallas path degraded to XLA: {bad}"
-
 
 class TestMsmTableBudgetDegrade:
     """A fixed-base table over the budget degrades the call to glv+signed

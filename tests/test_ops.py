@@ -65,6 +65,18 @@ class TestFieldOps:
         am, bm = jnp.asarray(ctx.encode(a)), jnp.asarray(ctx.encode(b))
         assert ctx.decode(F.mont_mul(ctx, am, bm)) == [x * y % bn.P for x, y in zip(a, b)]
 
+    @pytest.mark.parametrize("field", ["fr", "fq"])
+    def test_sub_zero_is_bit_exact(self, field):
+        """a - 0 is a's own limbs, 0 - 0 the zero limbs: `sub` adds p - b,
+        and p - 0 = p has to fold back to the canonical representative
+        (equal values mod p is what `test_mul_add_sub_neg` decodes; equal
+        limbs was checked only on the Pallas mirror, which is gone)."""
+        ctx, p = (F.fr_ctx(), bn.R) if field == "fr" else (F.fq_ctx(), bn.P)
+        a = [0, 1, p - 1] + [secrets.randbelow(p) for _ in range(5)]
+        am = jnp.asarray(ctx.encode(a))
+        got = jax.jit(lambda x, y: F.sub(ctx, x, y))(am, jnp.zeros_like(am))
+        assert np.array_equal(np.asarray(got), np.asarray(am))
+
 
 class TestNTT:
     def test_vs_native_and_roundtrip(self):
@@ -114,6 +126,36 @@ class TestEC:
             ec.encode_points(pts_a), ec.encode_points(pts_b)))
         want = [bn.g1_curve.add(a, b) for a, b in zip(pts_a, pts_b)]
         assert got == [None if w is None else (int(w[0]), int(w[1])) for w in want]
+
+    @pytest.mark.parametrize("case", ["mixed_mask", "infinity"])
+    def test_cneg(self, case):
+        """`ec.cneg` against the host curve's negation, at the batch of
+        `test_complete_add_cases` (one `padd` program). `mixed_mask`: the
+        masked rows are negated and the others untouched, bit for bit.
+        `infinity`: the caveat of `cneg`'s docstring, -(0:1:0) = (0:p-1:0)
+        is another representative of the identity: it decodes to the
+        identity, and P + cneg(inf) == P. (At the parent only the Pallas
+        mirror `_k_cneg` was compared with anything.)"""
+        g = bn.G1_GEN
+        pts = [g, bn.g1_curve.mul(g, 5), None, bn.g1_curve.mul(g, 9), g, None]
+        enc = ec.encode_points(pts)
+        cneg = jax.jit(ec.cneg)
+        if case == "mixed_mask":
+            mask = [True, False, True, True, False, False]
+            got = cneg(jnp.asarray(mask), enc)
+            want = [bn.g1_curve.neg(p) if m and p is not None else p
+                    for m, p in zip(mask, pts)]
+            assert ec.decode_points(got) == [
+                None if w is None else (int(w[0]), int(w[1])) for w in want]
+            keep = ~np.asarray(mask)
+            assert np.array_equal(np.asarray(got)[keep],
+                                  np.asarray(enc)[keep])
+        else:
+            inf = ec.inf_point((len(pts),))
+            neg_inf = cneg(jnp.ones(len(pts), bool), inf)
+            assert ec.decode_points(neg_inf) == [None] * len(pts)
+            assert ec.decode_points(jax.jit(ec.padd)(enc, neg_inf)) == \
+                ec.decode_points(enc)
 
 
 class TestMSMWindowOverride:

@@ -1,73 +1,137 @@
-"""Multi-device sharding tests on the virtual 8-device CPU mesh."""
+"""Multi-device sharding tests on the virtual CPU mesh.
 
+Tier-1 holds the mesh kernels to their oracles at the smallest shapes that
+still cross a shard boundary, on a 2x2 mesh of four of the eight virtual
+devices (the layout of PERF.md section 7's row-4 cell): `sharded_msm` and
+the signed `batch_msm_dp` against the host curve, `sharded_ntt` against the
+one-device kernel, and `TpuBackend.msm` / `msm_many` routed through the mesh
+in the MSM modes tier-1 can afford. Each MSM runner compiles an SPMD program
+of its own on XLA:CPU, which no one-device kernel's compiled program can
+stand in for, and what it costs is the `padd` call sites it holds, not its
+rows: so the window is the narrowest that still has a bucket of every weight
+(C below), not tests/_shapes.py's, and cases meet in one program where they
+can (CHANGES.md, PR 33, has the seconds). Behind RUN_SLOW stays what needs a
+fifth such program (the 4x1 mesh, unsigned `glv`, the sharded `fixed` table)
+and what costs minutes: whole proves on the mesh (`TestMeshProve`) and
+`__graft_entry__.dryrun_multichip`, which no driver runs.
+"""
+
+import os
 import secrets
 
+import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+from _shapes import MSM_N_OTHER_MODES
 from spectre_tpu.fields import bn254 as bn
 from spectre_tpu.ops import ec, limbs as L
 from spectre_tpu.parallel import make_mesh, sharded_msm
 from spectre_tpu.parallel.sharded_msm import shard_points
 
-import os
+pytestmark = pytest.mark.skipif(len(jax.devices()) < 4,
+                                reason="needs 4 devices")
+needs8 = pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 devices")
+run_slow = pytest.mark.skipif(not os.environ.get("RUN_SLOW"),
+                              reason="SPMD compiles beyond tier-1's "
+                                     "budget; set RUN_SLOW=1")
 
-# These compile an 8-way SPMD program on virtual CPU devices — minutes of XLA
-# compile on this 1-core box. The driver's dryrun_multichip covers the same
-# path; run here only when explicitly requested.
-pytestmark = [
-    pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 devices"),
-    pytest.mark.skipif(not os.environ.get("RUN_SLOW"),
-                       reason="slow SPMD compile; set RUN_SLOW=1"),
-]
+# rows of the tier-1 MSM cases: two shards of four on the 2x2 mesh, four of
+# two on the 4x1
+N = MSM_N_OTHER_MODES
+# their window: buckets 1..3 unsigned, 1..2 signed; 127 or 64 windows, padded
+# to a multiple of the mesh's window axis
+C = 2
+
+
+def _affine(p):
+    return None if p is None else (int(p[0]), int(p[1]))
+
+
+def _points(n):
+    """n small multiples of the generator with an infinity among them (host
+    scalar multiplication is the slow part of these tests: keep it short)."""
+    pts = [bn.g1_curve.mul(bn.G1_GEN, 3 * k + 2) for k in range(n)]
+    pts[n // 2] = None
+    return pts
+
+
+def _scalars(n):
+    """n random scalars with 0, 1 and r - 1 among them, the edge rows in
+    different shards."""
+    scalars = [secrets.randbelow(bn.R) for _ in range(n)]
+    scalars[0], scalars[1], scalars[n - 1] = 0, 1, bn.R - 1
+    return scalars
 
 
 class TestShardedMSM:
-    def test_matches_oracle_on_4x2_mesh(self):
-        mesh = make_mesh(8)
-        assert dict(mesh.shape) == {"data": 4, "win": 2}
-        n = 64
-        g = bn.G1_GEN
-        pts = [bn.g1_curve.mul(g, secrets.randbelow(bn.R)) for _ in range(n)]
-        scalars = [secrets.randbelow(bn.R) for _ in range(n)]
-        pd, sd = shard_points(ec.encode_points(pts),
-                              jnp.asarray(L.ints_to_limbs16(scalars)), mesh)
-        got = ec.decode_points(sharded_msm(pd, sd, 7, mesh)[None])[0]
-        want = bn.g1_curve.msm(pts, scalars)
-        assert got == (int(want[0]), int(want[1]))
+    """`parallel.sharded_msm` against the host curve (at the parent: behind
+    RUN_SLOW, so compared with an oracle in no run the driver makes)."""
 
-    def test_1d_mesh(self):
-        mesh = make_mesh(8, data_axis=8)
-        n = 32
-        pts = [bn.g1_curve.mul(bn.G1_GEN, k + 1) for k in range(n)]
-        scalars = [k * 31 + 1 for k in range(n)]
+    @pytest.mark.parametrize("data_axis", [
+        2, pytest.param(4, marks=run_slow)], ids=["2x2", "4x1"])
+    def test_matches_oracle(self, data_axis):
+        mesh = make_mesh(4, data_axis=data_axis)
+        assert dict(mesh.shape) == {"data": data_axis, "win": 4 // data_axis}
+        pts, scalars = _points(N), _scalars(N)
         pd, sd = shard_points(ec.encode_points(pts),
                               jnp.asarray(L.ints_to_limbs16(scalars)), mesh)
-        got = ec.decode_points(sharded_msm(pd, sd, 4, mesh)[None])[0]
-        want = bn.g1_curve.msm(pts, scalars)
-        assert got == (int(want[0]), int(want[1]))
+        got = ec.decode_points(
+            sharded_msm(pd, sd, C, mesh)[None])[0]
+        assert got == _affine(bn.g1_curve.msm(pts, scalars))
 
 
 class TestBatchMsmDP:
+    """`parallel.batch_msm_dp`, three columns on a four-device batch mesh
+    (padded to four), each against the host curve."""
+
+    BATCH = 3
+
+    def _mesh(self):
+        from spectre_tpu.parallel.batch_msm import _batch_mesh
+        return _batch_mesh(4)
+
     def test_batch_matches_oracle(self):
         from spectre_tpu.parallel.batch_msm import batch_msm_dp
 
-        n, batch = 32, 5     # 5 -> exercises padding to the 8-device mesh
-        pts = [bn.g1_curve.mul(bn.G1_GEN, k + 3) for k in range(n)]
-        enc = ec.encode_points(pts)
-        scalars = [[(k * 7 + b * 13 + 1) for k in range(n)]
-                   for b in range(batch)]
+        pts = _points(N)
+        scalars = [_scalars(N) for _ in range(self.BATCH)]
         sc = jnp.stack([jnp.asarray(L.ints_to_limbs16(s)) for s in scalars])
-        res = batch_msm_dp(enc, sc, c=4)
-        import numpy as np
+        res = batch_msm_dp(ec.encode_points(pts), sc,
+                           c=C, mesh=self._mesh())
         got = ec.decode_points(np.asarray(res))
-        for b in range(batch):
-            want = bn.g1_curve.msm(pts, scalars[b])
-            assert got[b] == (int(want[0]), int(want[1]))
+        for b in range(self.BATCH):
+            assert got[b] == _affine(bn.g1_curve.msm(pts, scalars[b])), b
+
+    def test_signed_batch_matches_oracle(self):
+        """The signed-digit runner (`_runner_glv`, signed=True) as
+        `TpuBackend.msm_many` feeds it: the expanded base, half-scalar
+        magnitudes and sign rows. At the parent its one tier-1 run was
+        stubbed and checked a counter, never the point."""
+        from spectre_tpu.ops import glv, msm as MSM
+        from spectre_tpu.parallel.batch_msm import batch_msm_dp
+
+        pts = _points(N)
+        scalars = [_scalars(N) for _ in range(self.BATCH)]
+        sc = np.zeros((self.BATCH, 2 * N, glv.HALF_LIMBS), np.uint32)
+        ng = np.zeros((self.BATCH, 2 * N), bool)
+        for b, s in enumerate(scalars):
+            a1, a2, n1, n2 = glv.decompose_limbs16(
+                np.asarray(L.ints_to_limbs16(s), np.uint32))
+            sc[b] = np.concatenate([a1, a2], axis=0)
+            ng[b] = np.concatenate([n1, n2], axis=0)
+        res = batch_msm_dp(MSM._expand_endo(ec.encode_points(pts)), sc,
+                           c=C, mesh=self._mesh(),
+                           neg_batch=ng, nbits=glv.glv_bits(), signed=True)
+        got = ec.decode_points(np.asarray(res))
+        for b in range(self.BATCH):
+            assert got[b] == _affine(bn.g1_curve.msm(pts, scalars[b])), b
 
 
+@needs8
+@run_slow
 def test_graft_entry_dryrun():
     import sys
     sys.path.insert(0, "/root/repo")
@@ -79,44 +143,29 @@ def test_graft_entry_dryrun():
 
 
 class TestShardedNTT:
-    def test_matches_single_device_kernel(self):
+    """`parallel.sharded_ntt` on the 2x2 mesh, bytes equal to the one-device
+    kernel's: an even log size (a square matrix) and an odd one (rr != cc)."""
+
+    @pytest.mark.parametrize("logn", [6, 7])
+    def test_matches_single_device_kernel(self, logn):
         from spectre_tpu.ops import field_ops as F, ntt as NTT
         from spectre_tpu.parallel.sharded_ntt import sharded_ntt
-        import numpy as np
-
-        mesh = make_mesh(8)          # data axis = 4 divides 32x32
-        logn = 10
-        n = 1 << logn
         from spectre_tpu.plonk.domain import Domain
+
+        mesh = make_mesh(4)
+        n = 1 << logn
         omega = Domain(logn).omega
-        ctx = F.fr_ctx()
         vals = [(i * 2654435761 + 17) % bn.R for i in range(n)]
-        a = jnp.asarray(ctx.encode_np(vals))
-        want = np.asarray(NTT.ntt(a, omega))
-        got = np.asarray(sharded_ntt(a, omega, mesh))
-        assert np.array_equal(want, got)
-
-    def test_odd_log_size(self):
-        # logn=11 -> 32x64 matrix: exercises rr != cc
-        from spectre_tpu.ops import field_ops as F, ntt as NTT
-        from spectre_tpu.parallel.sharded_ntt import sharded_ntt
-        import numpy as np
-
-        mesh = make_mesh(8)
-        logn = 11
-        n = 1 << logn
-        from spectre_tpu.plonk.domain import Domain
-        omega = Domain(logn).omega
-        ctx = F.fr_ctx()
-        vals = [(i * 40503 + 5) % bn.R for i in range(n)]
-        a = jnp.asarray(ctx.encode_np(vals))
+        a = jnp.asarray(F.fr_ctx().encode_np(vals))
         want = np.asarray(NTT.ntt(a, omega))
         got = np.asarray(sharded_ntt(a, omega, mesh))
         assert np.array_equal(want, got)
 
 
 class TestShardedMsmRouting:
-    @pytest.mark.parametrize("mode", ["vanilla", "glv", "glv+signed", "fixed"])
+    @pytest.mark.parametrize("mode", [
+        "vanilla", pytest.param("glv", marks=run_slow), "glv+signed",
+        pytest.param("fixed", marks=run_slow)])
     def test_backend_routes_large_msm_through_mesh(self, monkeypatch, mode):
         """TpuBackend.msm: >= 2^min_logn points + >1 device -> sharded_msm
         (tiny threshold here; the production default is 2^20). Every MSM
@@ -125,61 +174,56 @@ class TestShardedMsmRouting:
         SHARDED since ISSUE 13 — the window table is built by the mesh
         with rows co-resident with their point shards, and must NOT
         degrade to glv+signed (pinned via the health counter)."""
-        import numpy as np
         from spectre_tpu.plonk import backend as B
         from spectre_tpu.native import host
         from spectre_tpu.utils.health import HEALTH
 
-        monkeypatch.setenv("SPECTRE_SHARD_MSM_MIN_LOGN", "5")
+        monkeypatch.setenv("SPECTRE_MESH_SHAPE", "2x2")
+        monkeypatch.setenv("SPECTRE_SHARD_MSM_MIN_LOGN", "2")
         monkeypatch.setenv("SPECTRE_MSM_MODE", mode)
+        # the mesh's own table would give these few points a window of 10
+        monkeypatch.setenv("SPECTRE_MSM_WINDOW", str(C))
         bk = B.TpuBackend()
-        n = 37          # deliberately not divisible by the data axis (pads)
-        pts = [bn.g1_curve.mul(bn.G1_GEN, 3 * k + 2) for k in range(n)]
-        scs = [(k * 7919 + 5) % bn.R for k in range(n)]
-        pts64 = host.points_to_limbs(pts)
-        sc64 = np.zeros((n, 4), np.uint64)
-        for i, s in enumerate(scs):
-            for j in range(4):
-                sc64[i, j] = (s >> (64 * j)) & 0xFFFFFFFFFFFFFFFF
+        # not divisible by the data axis: padded to N rows, where `vanilla`
+        # meets TestShardedMSM's 2x2 program
+        n = N - 1
+        assert bk._use_mesh(n, bk._shard_min_logn)
+        pts, scs = _points(n), _scalars(n)
         degraded_before = HEALTH.get("msm_fixed_degraded")
-        got = bk.msm(pts64, sc64)
-        want = bn.g1_curve.msm(pts, scs)
-        assert got == (int(want[0]), int(want[1]))
+        got = bk.msm(host.points_to_limbs(pts), host.ints_to_limbs(scs))
+        assert got == _affine(bn.g1_curve.msm(pts, scs))
         if mode == "fixed":
             # the whole point of the sharded table: fixed stays fixed
             assert HEALTH.get("msm_fixed_degraded") == degraded_before
 
 
-class TestBatchMsmGLVModes:
-    def test_msm_many_glv_modes_match_oracle(self, monkeypatch):
-        """TpuBackend.msm_many on the >1-device batch DP path with the GLV
-        scalar-prep stage threaded through (half-scalar + sign-mask batch
-        rows against one replicated endomorphism-expanded base)."""
-        import numpy as np
+class TestBatchMsmManyOnMesh:
+    @pytest.mark.parametrize("mode", [
+        "vanilla", pytest.param("glv", marks=run_slow), "glv+signed",
+        "fixed"])
+    def test_msm_many_matches_oracle(self, monkeypatch, mode):
+        """TpuBackend.msm_many on the >1-device batch DP path, the GLV
+        modes with their scalar-prep stage threaded through (half-scalar +
+        sign-mask batch rows against one replicated endomorphism-expanded
+        base; `fixed` runs the glv+signed kernels here). At
+        TestBatchMsmDP's shapes, so `vanilla` and the signed modes meet its
+        two programs; unsigned `glv` needs one of its own."""
         from spectre_tpu.plonk import backend as B
         from spectre_tpu.native import host
 
-        n, batch = 32, 3
-        pts = [bn.g1_curve.mul(bn.G1_GEN, 3 * k + 2) for k in range(n)]
-        pts64 = host.points_to_limbs(pts)
-        scs = [[(b * 131071 + k * 7919 + 5) % bn.R for k in range(n)]
-               for b in range(batch)]
-        sc64s = []
-        for sc in scs:
-            sc64 = np.zeros((n, 4), np.uint64)
-            for i, s in enumerate(sc):
-                for j in range(4):
-                    sc64[i, j] = (s >> (64 * j)) & 0xFFFFFFFFFFFFFFFF
-            sc64s.append(sc64)
-        bk = B.TpuBackend()
-        for mode in ("glv", "glv+signed", "fixed"):
-            monkeypatch.setenv("SPECTRE_MSM_MODE", mode)
-            got = bk.msm_many(pts64, sc64s)
-            for sc, g in zip(scs, got):
-                want = bn.g1_curve.msm(pts, sc)
-                assert g == (int(want[0]), int(want[1])), mode
+        monkeypatch.setenv("SPECTRE_MESH_SHAPE", "2x2")
+        monkeypatch.setenv("SPECTRE_MSM_MODE", mode)
+        monkeypatch.setenv("SPECTRE_MSM_WINDOW", str(C))
+        pts = _points(N)
+        scs = [_scalars(N) for _ in range(TestBatchMsmDP.BATCH)]
+        got = B.TpuBackend().msm_many(host.points_to_limbs(pts),
+                                      [host.ints_to_limbs(sc) for sc in scs])
+        for b, sc in enumerate(scs):
+            assert got[b] == _affine(bn.g1_curve.msm(pts, sc)), b
 
 
+@needs8
+@run_slow
 class TestMeshProve:
     """A COMPLETE prove rides the mesh (sharded MSM + sharded NTT through
     the TpuBackend gates) and is byte-identical to the host prove — the

@@ -424,44 +424,6 @@ class TestBackendByteEquality:
             assert p == base, \
                 f"SPECTRE_MSM_MODE={mode} diverged from vanilla proof bytes"
 
-    @pytest.mark.skipif(not os.environ.get("SPECTRE_BYTEEQ_FULL"),
-                        reason="this box's XLA CPU LLVM segfaults under "
-                               "repeated prove compile churn; opt in with "
-                               "SPECTRE_BYTEEQ_FULL=1 (real-device tier)")
-    def test_msm_impl_proof_bytes_identical(self, srs, monkeypatch):
-        """ISSUE 17 acceptance gate, impl axis: SPECTRE_MSM_IMPL=pallas
-        must produce BYTE-IDENTICAL proofs to xla through the device
-        backend for every MSM mode, with zero unsupported-mode fallbacks
-        in the glv/glv+signed/fixed runs. Same full-prove tier as the mode
-        gate above (the commitment-level pallas sweep rides the slow tier
-        in TestMsmModeCommitments)."""
-        from spectre_tpu.ops import msm as MSM
-        cfg = CircuitConfig(k=K, num_advice=1, num_lookup_advice=1,
-                            num_fixed=1, lookup_bits=4)
-        advice, lookup, fixed, selectors, copies, out = _tiny_circuit(cfg)
-        asg = Assignment(cfg, advice, lookup, fixed, selectors, [[out]], copies)
-        bk = B.get_backend("tpu")
-        events = []
-        orig = MSM._record_event
-        monkeypatch.setattr(
-            MSM, "_record_event",
-            lambda name, **kw: (events.append((name, kw)),
-                                orig(name, **kw)))
-        for mode in ("vanilla", "glv", "glv+signed", "fixed"):
-            monkeypatch.setenv("SPECTRE_MSM_MODE", mode)
-            monkeypatch.setenv("SPECTRE_MSM_IMPL", "xla")
-            pk = keygen(srs, cfg, fixed, selectors, copies, bk)
-            base = prove(pk, srs, asg, bk, blinding_rng=self._seeded_rng(11))
-            events.clear()
-            monkeypatch.setenv("SPECTRE_MSM_IMPL", "pallas")
-            p = prove(pk, srs, asg, bk, blinding_rng=self._seeded_rng(11))
-            assert p == base, \
-                f"mode={mode}: pallas proof bytes diverge from xla"
-            if mode != "vanilla":
-                bad = [e for e in events
-                       if e[0] == "msm_pallas_unsupported_mode"]
-                assert not bad, f"mode={mode} degraded to XLA: {bad}"
-
     def test_seeded_blinding_is_deterministic_and_fresh_is_not(self, tiny):
         pk, srs, asg, out = tiny.pk, tiny.srs, tiny.asg, tiny.out
         p1 = prove(pk, srs, asg, blinding_rng=self._seeded_rng(1))

@@ -198,7 +198,7 @@ class TestShardedQuotientIdentity:
 @needs8
 @run_slow
 class TestShardedQuotientK11:
-    """The bench-shape arm (k=11, n_ext = 2^13 — above the default size
+    """The k=11 arm (n_ext = 2^13 — above the default size
     gate, so this also exercises the production gate path untouched)."""
 
     def test_mesh_byte_identity_k11(self, monkeypatch):
