@@ -15,7 +15,8 @@ from dataclasses import dataclass
 import numpy as np
 
 from ..fields import bn254
-from ..observability.tracing import span
+from ..native import host
+from ..observability.tracing import annotate, span
 from . import backend as B
 from .domain import Domain
 from .srs import SRS
@@ -193,42 +194,56 @@ def _pad(coeffs, n):
 
 def shplonk_accumulate(srs: SRS, entries: list[OpenEntry], transcript):
     """Verifier scalar/MSM work WITHOUT the pairing: returns the deferred
-    check (lhs, rhs) with e(lhs, [1]_2) == e(rhs... — concretely the pair
-    (w2, f_acc + u*w2) satisfying e(f_acc + u*w2, [1]_2) == e(w2, [tau]_2).
+    check (w2, F + u*W2) satisfying e(F + u*W2, [1]_2) == e(W2, [tau]_2),
+    affine points with `Fq` coordinates (None = the identity).
     One definition serves shplonk_verify AND the aggregation layer's native
-    accumulator oracle (`plonk/in_circuit.py`)."""
-    g1 = bn254.g1_curve
+    accumulator oracle (`plonk/in_circuit.py`).
+
+    The combination is ONE multi-scalar multiplication in the native host
+    library (span `verify/accumulate`, its `points` the pairs that went in).
+    It never goes through `B.get_backend()`: this is the check that guards a
+    served proof against corruption on the device, and a verifier that
+    committed through `TpuBackend` would have the chip vouch for itself.
+    Points read from the proof come through `transcript.read_point`, which
+    has checked them to be on the curve before they reach the MSM."""
     v = transcript.challenge()
     w1 = transcript.read_point()
     u = transcript.challenge()
     w2 = transcript.read_point()
 
-    all_points = []
-    for e in entries:
-        for p in e.points:
-            if p not in all_points:
-                all_points.append(p)
+    with span("verify/accumulate"):
+        all_points = []
+        for e in entries:
+            for p in e.points:
+                if p not in all_points:
+                    all_points.append(p)
 
-    # F = sum v^k Z_rest(u) C_k  -  [sum v^k Z_rest(u) r_k(u)] G  -  Z_T(u) W1
-    f_acc = None
-    e_scalar = 0
-    vk = 1
-    for e in entries:
-        z_rest = _z_eval([p for p in all_points if p not in e.points], u)
-        r_coeffs = _interp(e.points, e.evals)
-        r_u = 0
-        for c in reversed(r_coeffs):
-            r_u = (r_u * u + c) % R
-        w = vk * z_rest % R
-        f_acc = g1.add(f_acc, g1.mul(e.commitment, w))
-        e_scalar = (e_scalar + w * r_u) % R
-        vk = vk * v % R
-    z_t_u = _z_eval(all_points, u)
-    f_acc = g1.add(f_acc, g1.neg(g1.mul(bn254.G1_GEN, e_scalar)))
-    f_acc = g1.add(f_acc, g1.neg(g1.mul(w1, z_t_u)))
+        # F + u W2, with
+        # F = sum v^k Z_rest(u) C_k  -  [sum v^k Z_rest(u) r_k(u)] G  -  Z_T(u) W1
+        points, scalars = [], []
+        e_scalar = 0
+        vk = 1
+        for e in entries:
+            z_rest = _z_eval([p for p in all_points if p not in e.points], u)
+            r_coeffs = _interp(e.points, e.evals)
+            r_u = 0
+            for c in reversed(r_coeffs):
+                r_u = (r_u * u + c) % R
+            w = vk * z_rest % R
+            points.append(e.commitment)
+            scalars.append(w)
+            e_scalar = (e_scalar + w * r_u) % R
+            vk = vk * v % R
+        points += [bn254.G1_GEN, w1, w2]
+        scalars += [-e_scalar % R, -_z_eval(all_points, u) % R, u % R]
+        annotate(points=len(points))
+        one_side = host.g1_msm(host.points_to_limbs(points),
+                               host.ints_to_limbs(scalars))
+    if one_side is not None:
+        one_side = (bn254.Fq(one_side[0]), bn254.Fq(one_side[1]))
 
     # deferred: e(F + u W2, [1]_2) == e(W2, [tau]_2)
-    return w2, g1.add(f_acc, g1.mul(w2, u))
+    return w2, one_side
 
 
 def shplonk_verify(srs: SRS, entries: list[OpenEntry], transcript) -> bool:

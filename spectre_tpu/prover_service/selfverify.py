@@ -3,14 +3,19 @@
 A proof that fails on-chain verification is worse than no proof — the
 client burns gas and trust on bytes the service swore were good. Proving
 is minutes of accelerator-heavy MSM/NTT arithmetic (exactly where silent
-data corruption creeps in); *verification* is milliseconds of host-side
-pairing checks. This module spends those milliseconds on every fresh
-proof before the job queue marks it ``done``:
+data corruption creeps in); *verification* is host work only, well under
+a second at committee k=14: the transcript replay and the identity at x in
+Python, every opened commitment combined in ONE multi-scalar multiplication
+of the native host library (never the configured backend: the device does
+not vouch for itself), and the pairing, which is most of it. This module
+spends that on every fresh proof before the job queue marks it ``done``:
 
 * ``verified_prove(state, kind, args)`` wraps ``ProverState.prove_*``:
   the fresh proof bytes pass through fault site ``proof.bytes`` (kind
   ``corrupt`` bit-flips them — the deterministic stand-in for SDC), then
-  get verified host-side under a ``prove/self_verify`` span. A verify
+  get verified host-side under a ``prove/self_verify`` span (its children
+  ``verify/replay``, ``verify/identity``, ``verify/accumulate`` and
+  ``verify/pairing`` are ``plonk/verifier.py``'s stages). A verify
   failure is classified as suspected silent data corruption: the suspect
   bytes are quarantined (``results/quarantine/``), the prove is retried
   ONCE on the CPU backend (mirroring ``prove_with_fallback``'s degrade

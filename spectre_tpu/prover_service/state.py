@@ -224,7 +224,7 @@ class ProverState:
 
     def verify_proof(self, kind: str, proof: bytes, instances: list) -> bool:
         """Host-side check of a fresh proof against the matching verifying
-        key — the milliseconds verify-before-serve spends so an SDC'd
+        key — the half second verify-before-serve spends so an SDC'd
         prove never leaves the box (selfverify.verified_prove). `kind` is
         "step" or "committee"; `instances` is the flat public-input list
         the prove returned."""

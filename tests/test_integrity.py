@@ -140,6 +140,25 @@ class TestVerifiedProve:
         assert HEALTH.get("proofs_verified") == v0 + 1
         assert st.prove_backends == [None]
 
+    def test_verifier_spans_under_self_verify(self, toy):
+        """What is left of the host verifier has a name: its four stages
+        are children of `prove/self_verify`, and `verify/accumulate` says
+        how many (commitment, scalar) pairs its one MSM took."""
+        from spectre_tpu.observability import tracing
+        from spectre_tpu.prover_service import selfverify as SV
+        with tracing.trace("self-verify-spans") as tr:
+            SV.verified_prove(_ToyState(toy), "step", None)
+        phase, = [s for s in tr.root.children
+                  if s.name == "prove/self_verify"]
+        assert [s.name for s in phase.children] == [
+            "verify/replay", "verify/identity", "verify/accumulate",
+            "verify/pairing"]
+        assert all(s.seconds() is not None for s in phase.children)
+        pk = toy[0]
+        opened = len({key for key, _ in pk.vk.query_plan()})
+        # every opened polynomial, then G, W1 and W2
+        assert phase.children[2].meta == {"points": opened + 3}
+
     def test_bitflip_caught_cpu_retry_byte_identical(self, toy,
                                                      clean_cpu_proof):
         """THE acceptance pin: an SDC'd device prove is caught, retried

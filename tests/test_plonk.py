@@ -1,5 +1,6 @@
 """Proving-system tests: KZG/SHPLONK, transcripts, full prove/verify."""
 
+import dataclasses
 import os
 import secrets
 
@@ -120,6 +121,149 @@ class TestSHPLONK:
         tr = Blake2bTranscript(tw.finalize())
         f = (tr.read_scalar(),)
         assert not kzg.shplonk_verify(srs, [kzg.OpenEntry(None, C1, (x,), f)], tr)
+
+
+def _accumulate_oracle(srs, entries, transcript):
+    """`kzg.shplonk_accumulate` as it stood before it became one native MSM:
+    one double-and-add of `CurveGroup` an opened polynomial. Kept here as
+    the oracle of the MSM path."""
+    g1 = bn.g1_curve
+    v = transcript.challenge()
+    w1 = transcript.read_point()
+    u = transcript.challenge()
+    w2 = transcript.read_point()
+    all_points = []
+    for e in entries:
+        for p in e.points:
+            if p not in all_points:
+                all_points.append(p)
+    f_acc = None
+    e_scalar = 0
+    vk = 1
+    for e in entries:
+        z_rest = kzg._z_eval([p for p in all_points if p not in e.points], u)
+        r_coeffs = kzg._interp(e.points, e.evals)
+        r_u = 0
+        for c in reversed(r_coeffs):
+            r_u = (r_u * u + c) % bn.R
+        w = vk * z_rest % bn.R
+        f_acc = g1.add(f_acc, g1.mul(e.commitment, w))
+        e_scalar = (e_scalar + w * r_u) % bn.R
+        vk = vk * v % bn.R
+    z_t_u = kzg._z_eval(all_points, u)
+    f_acc = g1.add(f_acc, g1.neg(g1.mul(bn.G1_GEN, e_scalar)))
+    f_acc = g1.add(f_acc, g1.neg(g1.mul(w1, z_t_u)))
+    return w2, g1.add(f_acc, g1.mul(w2, u))
+
+
+class _FourValues:
+    """The transcript as `shplonk_accumulate` sees it: two challenges and
+    two points, handed out in the order they are asked for."""
+
+    def __init__(self, v, w1, u, w2):
+        self._challenges, self._points = [v, u], [w1, w2]
+
+    def challenge(self):
+        return self._challenges.pop(0)
+
+    def read_point(self):
+        return self._points.pop(0)
+
+
+@pytest.fixture(scope="module")
+def accumulate_inputs(tiny, tiny_cpu_proof):
+    """What the verifier hands `shplonk_accumulate` for a real proof of the
+    tiny gate + lookup + copy circuit: (entries, v, w1, u, w2)."""
+    seen = {}
+    real = kzg.shplonk_accumulate
+
+    class Tap:
+        def __init__(self, tr):
+            self.tr, self.values = tr, []
+
+        def challenge(self):
+            self.values.append(self.tr.challenge())
+            return self.values[-1]
+
+        def read_point(self):
+            self.values.append(self.tr.read_point())
+            return self.values[-1]
+
+    def spy(srs, entries, tr):
+        tap = Tap(tr)
+        out = real(srs, entries, tap)
+        seen["entries"], seen["values"] = list(entries), tap.values
+        return out
+
+    mp = pytest.MonkeyPatch()
+    try:
+        mp.setattr(kzg, "shplonk_accumulate", spy)
+        assert verify(tiny.pk.vk, tiny.srs, tiny.instances, tiny_cpu_proof)
+    finally:
+        mp.undo()
+    return (seen["entries"], *seen["values"])
+
+
+def _accumulate_case(case, inputs):
+    """(entries, v, w1, u, w2) of one edge of the native MSM. With v = 1 two
+    entries opened at the same points carry the same weight, so their
+    commitments meet in one bucket of every window."""
+    entries, v, w1, u, w2 = inputs
+    first = entries[0]
+    minus_first = dataclasses.replace(
+        first, commitment=bn.g1_curve.neg(first.commitment))
+    if case == "real_proof":
+        return entries, v, w1, u, w2
+    if case == "none_commitment":       # an all-zero fixed or selector column
+        return [first, dataclasses.replace(entries[1], commitment=None),
+                *entries[2:]], v, w1, u, w2
+    if case == "same_commitment_two_keys":      # the bucket doubles
+        return [first, *entries], 1, w1, u, w2
+    if case == "p_and_minus_p":                 # the bucket empties again
+        return [minus_first, *entries], 1, w1, u, w2
+    if case == "zero_weight":           # v = 0: every weight but the first
+        return entries, 0, w1, u, w2
+    if case == "wide":          # 64 pairs and over: the kernel's window is 8
+        return [dataclasses.replace(e, commitment=bn.g1_curve.mul(bn.G1_GEN, 3 + i))
+                for i, e in enumerate(entries * (70 // len(entries) + 1))], \
+            v, w1, u, w2
+    if case == "all_cancelling":        # P - P, r - r, no W1, no W2: identity
+        return [first, dataclasses.replace(
+            minus_first, evals=tuple(-x % bn.R for x in first.evals))], \
+            1, None, u, None
+    raise ValueError(case)
+
+
+class TestAccumulateNativeMsm:
+    """`shplonk_accumulate`'s one native MSM against the Python loop it
+    replaced, on what a real proof hands it and on each edge the native
+    kernel has to carry."""
+
+    @pytest.mark.parametrize("case", [
+        "real_proof", "none_commitment", "same_commitment_two_keys",
+        "p_and_minus_p", "zero_weight", "wide", "all_cancelling"])
+    def test_equals_python_loop(self, srs, accumulate_inputs, case):
+        entries, *values = _accumulate_case(case, accumulate_inputs)
+        want = _accumulate_oracle(srs, entries, _FourValues(*values))
+        got = kzg.shplonk_accumulate(srs, entries, _FourValues(*values))
+        assert got == want
+        tau_side, one_side = got
+        assert (one_side is None) == (case == "all_cancelling")
+        if one_side is not None:
+            assert all(isinstance(c, bn.Fq) for c in one_side)
+            assert bn.g1_curve.is_on_curve(one_side)
+
+    def test_verifier_never_asks_for_the_backend(self, tiny, tiny_cpu_proof,
+                                                 monkeypatch):
+        """The verifier guards the served proof against the device: it may
+        not commit through whatever backend the service is configured with."""
+        def refuse(*a, **kw):
+            raise RuntimeError("the verifier asked for the backend")
+
+        monkeypatch.setattr(B, "get_backend", refuse)
+        assert verify(tiny.pk.vk, tiny.srs, tiny.instances, tiny_cpu_proof)
+        assert not verify(tiny.pk.vk, tiny.srs, [[tiny.out + 1]],
+                          tiny_cpu_proof)
 
 
 class TestProveVerify:
