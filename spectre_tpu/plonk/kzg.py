@@ -89,38 +89,53 @@ def _z_eval(points, x: int) -> int:
     return out
 
 
-def _domain_linear_factors(domain: Domain, points, bk) -> np.ndarray:
-    """[n,4] evals of Z_S(omega^i) = prod (omega^i - s)."""
-    omegas = bk.powers(domain.omega, domain.n)
+def _domain_linear_factors(omegas: np.ndarray, points, bk) -> np.ndarray:
+    """[n,4] evals of Z_S(omega^i) = prod (omega^i - s), from the domain's
+    points `omegas` = omega^i."""
     acc = None
     for s in points:
-        term = bk.sub(omegas, B.to_arr([s] * domain.n))
+        term = bk.add_scalar(omegas, -s % R)
         acc = term if acc is None else bk.mul(acc, term)
     return acc
 
 
-def _eval_small_poly_on_domain(domain: Domain, coeffs: list[int], bk) -> np.ndarray:
-    """Evaluate a degree<=3 poly on the whole domain, vectorized."""
-    omegas = bk.powers(domain.omega, domain.n)
-    acc = B.to_arr([coeffs[-1]] * domain.n)
+def _eval_small_poly_on_domain(omegas: np.ndarray, coeffs: list[int], bk) -> np.ndarray:
+    """Evaluate a poly of a few coefficients (as many as its set has points:
+    one to five) at every omega^i of `omegas`, by Horner over the column."""
+    acc = B.const_arr(coeffs[-1], omegas.shape[0])
     for c in reversed(coeffs[:-1]):
-        acc = bk.add(bk.mul(acc, omegas), B.to_arr([c] * domain.n))
+        acc = bk.add_scalar(bk.mul(acc, omegas), c)
     return acc
+
+
+def _horner(coeffs: list[int], x: int) -> int:
+    out = 0
+    for c in reversed(coeffs):
+        out = (out * x + c) % R
+    return out
 
 
 def shplonk_open(srs: SRS, domain: Domain, entries: list[OpenEntry], transcript, bk=None):
     """Prover: BDFG20 two-commitment multiopen. Evals must already be absorbed
     into the transcript by the caller; this writes W1, W2.
 
-    Most of it is host arithmetic between a few backend calls; its three
-    loops are spans of their own (`multiopen/h_poly`, with each entry's
-    low-degree remainder on the domain as `multiopen/h_poly/remainder`;
-    `multiopen/linearisation`; `multiopen/w2_division`), with the backend's
-    calls as their children."""
+    h = sum_k v^k (p_k - r_k) / Z_{S_k} is linear in the p_k, and the entries
+    share a few point sets S (eight in a committee prove of 231 entries), so
+    the entries are first combined a set, P_S = sum_{k in S} v^k p_k in
+    coefficient form and R_S = sum v^k r_k as a handful of ints (span
+    `multiopen/h_poly/combine`), and everything that touches the domain
+    happens once a SET: one NTT, one remainder put on the domain
+    (`multiopen/h_poly/remainder`), one division by Z_S. The linearisation
+    L = sum_S Z_{T - S}(u) (P_S - R_S(u)) - Z_T(u) h is taken from the same
+    per-set evaluations and from h's, which are still at hand
+    (`multiopen/linearisation`: no transform), and divided by X - u on the
+    domain (`multiopen/w2_division`). Exact field arithmetic throughout, so
+    W1 and W2 are those of the entry-at-a-time form (kept as the oracle in
+    tests/test_plonk.py). `multiopen/h_poly` carries `entries` and `sets`;
+    the backend's calls are children of these spans."""
     bk = bk or B.get_backend()
     v = transcript.challenge()
 
-    # group by point set (identical sets share one Z_S)
     n = domain.n
     all_points = []
     for e in entries:
@@ -129,55 +144,50 @@ def shplonk_open(srs: SRS, domain: Domain, entries: list[OpenEntry], transcript,
                 all_points.append(p)
 
     with span("multiopen/h_poly", entries=len(entries)):
+        with span("multiopen/h_poly/combine"):
+            p_sets: dict = {}   # point set -> P_S, [n,4] coefficients
+            r_sets: dict = {}   # point set -> R_S, as many ints as points
+            zero = B.zeros(n)
+            vk = 1
+            for e in entries:
+                p_sets[e.points] = bk.axpy(_pad(e.coeffs, n), vk,
+                                           p_sets.get(e.points, zero))
+                r_sets[e.points] = [
+                    (a + c * vk) % R for a, c in zip(
+                        r_sets.get(e.points, [0] * len(e.points)),
+                        _interp(e.points, e.evals))]
+                vk = vk * v % R
+        annotate(sets=len(p_sets))
+
+        omegas = bk.powers(domain.omega, n)
         h_evals = B.zeros(n)
-        vk = 1
-        lagrange_cache = {}
-        zinv_cache = {}
-        for e in entries:
-            key = e.points
-            if key not in zinv_cache:
-                zinv_cache[key] = bk.inv(
-                    _domain_linear_factors(domain, e.points, bk))
-            if e.coeffs.shape[0] < n:
-                padded = np.zeros((n, 4), dtype=np.uint64)
-                padded[:e.coeffs.shape[0]] = e.coeffs
-            else:
-                padded = e.coeffs
-            p_evals = domain.coeff_to_lagrange(padded, bk)
+        p_evals = {}
+        for points, p_s in p_sets.items():
+            zinv = bk.inv(_domain_linear_factors(omegas, points, bk))
+            p_evals[points] = domain.coeff_to_lagrange(p_s, bk)
             with span("multiopen/h_poly/remainder"):
-                r_coeffs = _interp(e.points, e.evals)
-                r_evals = _eval_small_poly_on_domain(domain, r_coeffs, bk)
-            term = bk.mul(bk.sub(p_evals, r_evals), zinv_cache[key])
-            h_evals = bk.add(h_evals, bk.scale(term, vk))
-            lagrange_cache[id(e)] = (p_evals, r_coeffs)
-            vk = vk * v % R
+                r_evals = _eval_small_poly_on_domain(omegas, r_sets[points], bk)
+            h_evals = bk.add(h_evals, bk.mul(
+                bk.sub(p_evals[points], r_evals), zinv))
 
         h_coeffs = domain.lagrange_to_coeff(h_evals, bk)
     w1 = commit(srs, h_coeffs, bk)
     transcript.write_point(w1)
     u = transcript.challenge()
 
-    # L(X) = sum v^k Z_{T \ S_k}(u) (p_k(X) - r_k(u)) - Z_T(u) h(X)
+    # L(X) = sum_S Z_{T - S}(u) (P_S(X) - R_S(u)) - Z_T(u) h(X), on the domain
     with span("multiopen/linearisation"):
-        l_evals = B.zeros(n)
-        vk = 1
-        for e in entries:
-            p_evals, r_coeffs = lagrange_cache[id(e)]
-            z_rest = _z_eval([p for p in all_points if p not in e.points], u)
-            r_u = 0
-            for c in reversed(r_coeffs):
-                r_u = (r_u * u + c) % R
-            term = bk.sub(p_evals, B.to_arr([r_u] * n))
-            l_evals = bk.add(l_evals, bk.scale(term, vk * z_rest % R))
-            vk = vk * v % R
-        z_t_u = _z_eval(all_points, u)
-        l_evals = bk.sub(l_evals, bk.scale(domain.coeff_to_lagrange(
-            _pad(h_coeffs, n), bk), z_t_u))
+        l_evals = bk.scale(h_evals, -_z_eval(all_points, u) % R)
+        const = 0
+        for points, p_s in p_evals.items():
+            z_rest = _z_eval([p for p in all_points if p not in points], u)
+            l_evals = bk.axpy(p_s, z_rest, l_evals)
+            const = (const + z_rest * _horner(r_sets[points], u)) % R
+        l_evals = bk.add_scalar(l_evals, -const % R)
 
     # W2 = commit(L / (X - u)) via pointwise division on the domain
     with span("multiopen/w2_division"):
-        omegas = bk.powers(domain.omega, n)
-        denom_inv = bk.inv(bk.sub(omegas, B.to_arr([u] * n)))
+        denom_inv = bk.inv(bk.add_scalar(omegas, -u % R))
         w2_evals = bk.mul(l_evals, denom_inv)
         w2_coeffs = domain.lagrange_to_coeff(w2_evals, bk)
     w2 = commit(srs, w2_coeffs, bk)
@@ -225,10 +235,7 @@ def shplonk_accumulate(srs: SRS, entries: list[OpenEntry], transcript):
         vk = 1
         for e in entries:
             z_rest = _z_eval([p for p in all_points if p not in e.points], u)
-            r_coeffs = _interp(e.points, e.evals)
-            r_u = 0
-            for c in reversed(r_coeffs):
-                r_u = (r_u * u + c) % R
+            r_u = _horner(_interp(e.points, e.evals), u)
             w = vk * z_rest % R
             points.append(e.commitment)
             scalars.append(w)

@@ -269,7 +269,8 @@ class TestDeviceBoundarySpans:
             <= PROVE_PHASES
         assert {"job/blind", "commit/marshal", "grand_products/perm_chunk",
                 "grand_products/lookup", "evals/horner", "multiopen/h_poly",
-                "multiopen/h_poly/remainder", "multiopen/linearisation",
+                "multiopen/h_poly/combine", "multiopen/h_poly/remainder",
+                "multiopen/linearisation",
                 "multiopen/w2_division"} <= names
         # and in the source: `prove/...` is opened by phase(), never span()
         root = os.path.dirname(spectre_tpu.__file__)

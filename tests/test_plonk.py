@@ -123,6 +123,165 @@ class TestSHPLONK:
         assert not kzg.shplonk_verify(srs, [kzg.OpenEntry(None, C1, (x,), f)], tr)
 
 
+def _open_oracle(srs, domain, entries, transcript):
+    """`kzg.shplonk_open` as it stood before it combined the entries a point
+    set: every entry transformed, its remainder put on the domain and divided
+    by its Z_S one at a time, then a second walk over the entries for L.
+    Kept here as the oracle of the grouped form, on `CpuBackend`."""
+    bk = B.get_backend()
+    n = domain.n
+    omegas = bk.powers(domain.omega, n)
+    v = transcript.challenge()
+    all_points = []
+    for e in entries:
+        for p in e.points:
+            if p not in all_points:
+                all_points.append(p)
+
+    h_evals = B.zeros(n)
+    vk = 1
+    cache = []
+    for e in entries:
+        z_s = None
+        for s in e.points:
+            term = bk.sub(omegas, B.to_arr([s] * n))
+            z_s = term if z_s is None else bk.mul(z_s, term)
+        padded = np.zeros((n, 4), dtype=np.uint64)
+        padded[:e.coeffs.shape[0]] = e.coeffs
+        p_evals = domain.coeff_to_lagrange(padded, bk)
+        r_coeffs = kzg._interp(e.points, e.evals)
+        r_evals = B.to_arr([r_coeffs[-1]] * n)
+        for c in reversed(r_coeffs[:-1]):
+            r_evals = bk.add(bk.mul(r_evals, omegas), B.to_arr([c] * n))
+        term = bk.mul(bk.sub(p_evals, r_evals), bk.inv(z_s))
+        h_evals = bk.add(h_evals, bk.scale(term, vk))
+        cache.append((p_evals, r_coeffs))
+        vk = vk * v % bn.R
+    h_coeffs = domain.lagrange_to_coeff(h_evals, bk)
+    w1 = kzg.commit(srs, h_coeffs, bk)
+    transcript.write_point(w1)
+    u = transcript.challenge()
+
+    l_evals = B.zeros(n)
+    vk = 1
+    for e, (p_evals, r_coeffs) in zip(entries, cache):
+        z_rest = kzg._z_eval([p for p in all_points if p not in e.points], u)
+        r_u = 0
+        for c in reversed(r_coeffs):
+            r_u = (r_u * u + c) % bn.R
+        term = bk.sub(p_evals, B.to_arr([r_u] * n))
+        l_evals = bk.add(l_evals, bk.scale(term, vk * z_rest % bn.R))
+        vk = vk * v % bn.R
+    l_evals = bk.sub(l_evals, bk.scale(
+        domain.coeff_to_lagrange(h_coeffs, bk), kzg._z_eval(all_points, u)))
+    denom_inv = bk.inv(bk.sub(omegas, B.to_arr([u] * n)))
+    w2_coeffs = domain.lagrange_to_coeff(bk.mul(l_evals, denom_inv), bk)
+    w2 = kzg.commit(srs, w2_coeffs, bk)
+    transcript.write_point(w2)
+
+
+class _Forced:
+    """A Blake2b transcript whose first challenges are the ones given (v,
+    then u; None: the transcript's own), everything else its own."""
+
+    def __init__(self, *forced):
+        self.tr, self.forced, self.points = Blake2bTranscript(), list(forced), []
+
+    def challenge(self):
+        own = self.tr.challenge()
+        given = self.forced.pop(0) if self.forced else None
+        return own if given is None else given
+
+    def write_point(self, pt):
+        self.points.append(pt)
+        self.tr.write_point(pt)
+
+
+# the rotation families of `keygen.query_plan`, as a committee prove's 231
+# entries share them (LAST and `back` stand for whatever rows they name)
+_ROTATION_FAMILIES = (
+    (0, 1, 2, 3), (0,), (0, -1), (0, 1), (0, 1, -5),
+    (0, -2, -7, -15, -16), (0, -1, -2, -3, -4), (0, -9))
+
+
+def _open_case(case, dom):
+    """(entries, v, u) of one case of the grouped open; None leaves a
+    challenge to the transcript. Polynomials and x are drawn anew a case."""
+    n = dom.n
+    rng = secrets.SystemRandom()
+    x = rng.randrange(bn.R)
+
+    def poly(rows=n):
+        return B.to_arr([rng.randrange(bn.R) for _ in range(rows)])
+
+    def entry(coeffs, rots):
+        pts = tuple(x * pow(dom.omega, r, bn.R) % bn.R for r in rots)
+        return kzg.OpenEntry(coeffs, None, pts, tuple(
+            host.fp_horner(host.FR, coeffs, p) for p in pts))
+
+    if case == "one_set":
+        return [entry(poly(), (0, 1)) for _ in range(3)], None, None
+    if case == "eight_families_mixed":      # every family twice, interleaved
+        fams = _ROTATION_FAMILIES + _ROTATION_FAMILIES[::-1]
+        return [entry(poly(), f) for f in fams], None, None
+    if case == "five_point_set":
+        return [entry(poly(), _ROTATION_FAMILIES[5])], None, None
+    if case == "shorter_than_n":            # a chunk of h; a constant; n rows
+        return [entry(poly(n // 2), (0,)), entry(poly(1), (0, -1)),
+                entry(poly(), (0,))], None, None
+    if case == "equal_polynomials":         # the same column under two keys
+        p = poly()
+        return [entry(p, (0, 1)), entry(p, (0, 1)), entry(p, (0,))], None, None
+    if case == "set_first_and_last":
+        return [entry(poly(), (0, 1)), entry(poly(), (0,)),
+                entry(poly(), (0, -1)), entry(poly(), (0, 1))], None, None
+    mixed = [entry(poly(), f) for f in _ROTATION_FAMILIES[:4] * 2]
+    if case == "v_zero":        # every weight but the first vanishes
+        return mixed, 0, None
+    if case == "v_one":         # equal weights: a set's entries just add
+        return mixed, 1, None
+    if case == "u_zero":
+        return mixed, None, 0
+    if case == "u_one":         # u = omega^0: X - u vanishes on the domain
+        return mixed, None, 1
+    raise ValueError(case)
+
+
+class TestOpenGroupedBySet:
+    """`shplonk_open`, which combines the entries a point set before it
+    touches the domain, against the entry-at-a-time form it replaced: W1, W2
+    and the transcript's state, byte for byte on `CpuBackend`."""
+
+    @pytest.mark.parametrize("case", [
+        "one_set", "eight_families_mixed", "five_point_set", "shorter_than_n",
+        "equal_polynomials", "set_first_and_last", "v_zero", "v_one",
+        "u_zero", "u_one"])
+    def test_equals_entry_at_a_time(self, srs, case):
+        from spectre_tpu.observability import tracing
+
+        dom = Domain(K)
+        entries, v, u = _open_case(case, dom)
+        want, got = _Forced(v, u), _Forced(v, u)
+        _open_oracle(srs, dom, entries, want)
+        with tracing.trace("open") as tr:
+            kzg.shplonk_open(srs, dom, entries, got)
+        assert got.points == want.points and len(got.points) == 2
+        assert got.tr.finalize() == want.tr.finalize()
+        assert got.tr.challenge() == want.tr.challenge()
+        # the mechanism: the domain is touched once a set, not once an entry
+        spans, todo = [], [tr.root]
+        while todo:
+            spans.append(todo.pop())
+            todo += spans[-1].children
+        h_poly, = [s for s in spans if s.name == "multiopen/h_poly"]
+        n_sets = len({e.points for e in entries})
+        assert h_poly.meta["entries"] == len(entries)
+        assert h_poly.meta["sets"] == n_sets
+        assert sum(s.name == "multiopen/h_poly/remainder"
+                   for s in spans) == n_sets
+        assert sum(s.name == "multiopen/h_poly/combine" for s in spans) == 1
+
+
 def _accumulate_oracle(srs, entries, transcript):
     """`kzg.shplonk_accumulate` as it stood before it became one native MSM:
     one double-and-add of `CurveGroup` an opened polynomial. Kept here as
