@@ -270,8 +270,9 @@ def _eval_eqn(eqn, ei, env, eqns, outvar_set, lint: _Lint, check: bool,
     if prim == "sub":
         # signed a-b stays within max(|a|,|b|) magnitude (negative results
         # are representable, no wrap); unsigned wrap-to-borrow is a
-        # deliberate idiom (_sub_limbs) — conservatively full-width there,
-        # recovered by downstream masks
+        # deliberate idiom (field_ops._sub_limbs: `(a - b - borrow) & MASK`
+        # on uint32 limbs, the borrows themselves resolved beside it) —
+        # conservatively full-width there, recovered by downstream masks
         try:
             if np.issubdtype(np.dtype(eqn.outvars[0].aval.dtype),
                              np.signedinteger):

@@ -103,10 +103,15 @@ def padd(p, q):
     """Complete projective add, a=0, b=3 (RCB alg. 7). p, q: [..., 3, 16].
 
     The 12 field multiplies are batched into TWO stacked mont_mul calls (the
-    formula has two dependency layers of muls); adds/subs are likewise stacked.
-    This matters: every field op lowers to a lax.scan over limb rounds, and
-    XLA compile time scales with scan count, so 2 big scans beat 12 small ones
-    — runtime also improves (wider batches per kernel)."""
+    formula has two dependency layers of muls); adds/subs are likewise stacked
+    (11 calls). This matters: a mont_mul's CIOS rounds are a 16-step lax.scan,
+    every step a sequence of device programs of its own, and XLA compile time
+    scales with scan count, so 2 big scans beat 12 small ones — runtime also
+    improves (wider batches per kernel). The two are the only loops of an
+    addition: add / sub and the multiplications' tails resolve their carries
+    in one pass (field_ops._resolve; tests/test_msm_modes.py pins the count,
+    which was 39 scans = 624 sequential steps while they went limb by
+    limb)."""
     ctx = _fq()
     add = lambda a, b: F.add(ctx, a, b)       # noqa: E731
     sub = lambda a, b: F.sub(ctx, a, b)       # noqa: E731

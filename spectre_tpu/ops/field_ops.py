@@ -2,8 +2,10 @@
 
 The device-side equivalent of the reference's `halo2curves` field arithmetic
 (SURVEY.md §2b N1), designed for the TPU VPU: all values are [..., 16] uint32
-tensors of 16-bit limbs; multiplication is 16 unrolled CIOS rounds, each a
-fully vectorized multiply-accumulate over the batch; no 64-bit integers
+tensors of 16-bit limbs; multiplication is 16 CIOS rounds (one `lax.scan`,
+the only loop of this module's arithmetic), each a fully vectorized
+multiply-accumulate over the batch; carries and borrows are resolved in one
+pass over the limb axis (`_resolve`), not limb by limb; no 64-bit integers
 anywhere. Montgomery radix R = 2^256 (matches the native C++ lib, so host <->
 device form conversion is pure layout change).
 
@@ -78,68 +80,97 @@ def fq_ctx() -> FieldCtx:
 # core arithmetic (all shapes [..., 16] uint32)
 # ---------------------------------------------------------------------------
 
+def _resolve(generate, propagate):
+    """Carry (or borrow) INTO each limb, and out of the top one, of a chain
+    whose limb i makes a carry whatever comes in (`generate[..., i]`) or
+    passes on the one that comes in (`propagate[..., i]`; never both): the
+    recurrence out_i = generate_i | (propagate_i & out_{i-1}), which is a
+    binary addition's own. So the bits of a value are gathered into one
+    word each, G and P, and ONE integer addition ripples every carry
+    through its run of propagating limbs: with X = G | P and Y = G, X & Y
+    generates where G does and X ^ Y propagates where P does, and the sum's
+    bit i is P_i ^ in_i. No loop over the limbs: a reduction over the limb
+    axis, three word operations, a broadcast back. [..., k] bools, k <= 31
+    -> ([..., k] uint32 of 0/1, [...] uint32 of 0/1)."""
+    k = generate.shape[-1]
+    shifts = np.arange(k, dtype=np.uint32)
+    weights = np.uint32(1) << shifts
+    zero = np.uint32(0)
+    g = jnp.sum(jnp.where(generate, weights, zero), axis=-1, dtype=jnp.uint32)
+    p = jnp.sum(jnp.where(propagate, weights, zero), axis=-1,
+                dtype=jnp.uint32)
+    into = ((g | p) + g) ^ p        # bit i: into limb i; bit k: out of the top
+    return (into[..., None] >> shifts) & np.uint32(1), into >> np.uint32(k)
+
+
+def _carry_bits(s):
+    """`_carry_propagate` for limbs <= 0x1FFFE (a sum of two normalized
+    limbs): each limb makes at most one carry, and one that overflowed is
+    at most 0xFFFE below, so it cannot pass one on as well."""
+    low = s & MASK
+    into, out = _resolve(s > MASK, low == MASK)
+    return (low + into) & MASK, out
+
+
 def _carry_propagate(t):
     """Full carry propagation of a [..., k] uint32 accumulator tensor, little-
-    endian 16-bit limbs. Returns same-shape tensor with entries < 2^16 except
-    possibly the top. lax.scan keeps the traced graph to O(1) ops regardless
-    of limb count (unrolled carry chains dominate XLA compile time otherwise)."""
-    tT = jnp.moveaxis(t, -1, 0)
-
-    def step(carry, ti):
-        cur = ti + carry
-        return cur >> 16, cur & MASK
-
-    carry, outs = jax.lax.scan(step, jnp.zeros_like(tT[0]), tT)
-    return jnp.moveaxis(outs, 0, -1), carry
+    endian 16-bit limbs (any uint32: the CIOS rounds hand over limbs up to
+    ~2^24). Returns the same-shape tensor with entries < 2^16 and the carry
+    out of the top limb. One pass, no loop (PERF.md section 5, PR 36: a
+    16-step `lax.scan` here was 16 x ~7 device programs): the high halves
+    move up one limb first, after which every limb is <= 0x1FFFE and
+    `_carry_bits` resolves what is left."""
+    high = t >> 16
+    moved = jnp.concatenate(
+        [jnp.zeros_like(high[..., :1]), high[..., :-1]], axis=-1)
+    out, carry = _carry_bits((t & MASK) + moved)
+    return out, high[..., -1] + carry
 
 
 def _sub_limbs(a, b):
-    """a - b with borrow chain; returns (diff limbs, final borrow 0/1)."""
-    shape = jnp.broadcast_shapes(a.shape, b.shape)
-    aT = jnp.moveaxis(jnp.broadcast_to(a, shape), -1, 0)
-    bT = jnp.moveaxis(jnp.broadcast_to(b, shape), -1, 0)
-
-    def step(borrow, ab):
-        ai, bi = ab
-        cur = ai - bi - borrow  # uint32 wraps
-        return (cur >> 16) & np.uint32(1), cur & MASK  # wrap iff borrow
-
-    borrow, outs = jax.lax.scan(step, jnp.zeros_like(aT[0]), (aT, bT))
-    return jnp.moveaxis(outs, 0, -1), borrow
+    """a - b over normalized limbs; returns (diff limbs, final borrow 0/1).
+    A limb borrows whatever comes in where a < b and passes a borrow on
+    where a == b (`_resolve`); the limb itself is a - b - borrow in uint32,
+    which wraps where it borrows, and the mask keeps the low 16 bits."""
+    a, b = jnp.asarray(a), jnp.asarray(b)
+    into, out = _resolve(a < b, a == b)
+    return (a - b - into) & MASK, out
 
 
 def _cond_sub_p(ctx: FieldCtx, a):
     """a if a < p else a - p (a < 2p, limbs normalized)."""
-    diff, borrow = _sub_limbs(a, jnp.broadcast_to(ctx.p_limbs, a.shape))
+    diff, borrow = _sub_limbs(a, ctx.p_limbs)
     return jnp.where((borrow == 0)[..., None], diff, a)
 
 
 def add(ctx: FieldCtx, a, b):
-    t = a + b
-    t, _ = _carry_propagate(t)
+    t, _ = _carry_bits(a + b)
     return _cond_sub_p(ctx, t)
 
 
 def sub(ctx: FieldCtx, a, b):
     # a + (p - b): both < p so p - b has no borrow issues
-    pb, _ = _sub_limbs(jnp.broadcast_to(ctx.p_limbs, b.shape), b)
+    pb, _ = _sub_limbs(ctx.p_limbs, b)
     return add(ctx, a, pb)
 
 
 def neg(ctx: FieldCtx, a):
-    pb, _ = _sub_limbs(jnp.broadcast_to(ctx.p_limbs, a.shape), a)
+    pb, _ = _sub_limbs(ctx.p_limbs, a)
     # p - 0 = p must normalize to 0
     is_zero = jnp.all(a == 0, axis=-1, keepdims=True)
     return jnp.where(is_zero, jnp.zeros_like(a), _cond_sub_p(ctx, pb))
 
 
 def _mont_mul_cios(ctx: FieldCtx, a, b):
-    """Montgomery product a*b*R^{-1} mod p: 16 CIOS rounds as a lax.scan.
+    """Montgomery product a*b*R^{-1} mod p: 16 CIOS rounds as a lax.scan,
+    then one carry pass and one conditional subtraction of p, neither a
+    loop.
 
     Each round is a fully vectorized multiply-accumulate over the batch; the
     scan keeps the traced graph small (an unrolled version is ~300 HLO ops per
     multiply, which made circuit-sized graphs take minutes to compile). Written
-    scatter-free: shifted adds via concatenate."""
+    scatter-free: shifted adds via concatenate. The rounds leave limbs up
+    to ~2^24 unresolved, which is what `_carry_propagate` takes."""
     shape = jnp.broadcast_shapes(a.shape, b.shape)
     a = jnp.broadcast_to(a, shape)
     bT = jnp.moveaxis(jnp.broadcast_to(b, shape), -1, 0)  # [16, ...]

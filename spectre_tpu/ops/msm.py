@@ -231,9 +231,9 @@ def _aggregate_buckets(bucket_sums, c: int):
         ec.select_point(masks[None], src, ec.inf_point((1, 1, 1))), 2)
     # acc = sum_j 2^j bit_sums[:, j] by high-to-low double-and-add
     # (a scan, not c unrolled steps: the same chain of additions as one loop
-    # body where the unrolled form is 2c copies of every field operation's
-    # loops to lower, compile and load; PERF.md section 5 has what the
-    # TPU compiler makes of each)
+    # body where the unrolled form is 2c copies of an addition, its two
+    # CIOS loops and some hundred fused programs each, to lower, compile
+    # and load; PERF.md section 5 has what the TPU compiler makes of each)
     def step(acc, bit_sum):
         acc = ec.padd(acc, acc)
         return ec.padd(acc, bit_sum), None
@@ -568,15 +568,20 @@ def msm_fixed_run(table, scalars, neg, c: int, nbits: int):
 def default_window(n: int, signed: bool = False) -> int:
     """Pippenger window size for n points (the EXPANDED count under GLV).
 
-    The unsigned entry for 2^12 <= n < 2^18 was chosen on the chip (TPU v5
-    lite, PR 32, chip call 1: `msm_windows` with the split-digit aggregate,
-    the median of three repeats): at n = 2^14, c = 7 / 8 / 9 / 10 / 11 read
-    0.345 / 0.309 / 0.293 / 0.280 / 0.309 s; at 2^15, c = 8 / 9 / 10 read
-    0.427 / 0.398 / 0.375; at 2^16, c = 8 / 9 / 10 / 11 read 0.685 / 0.636 /
-    0.594 / 0.608. The count of additions alone says 8 at 2^14; what it
-    leaves out is that a window costs ~9 ms there whatever its buckets
-    (its sort, gathers, scatters and narrow levels), so fewer, wider
-    windows win until the emission tree's levels * 2^c outgrows that.
+    The unsigned entries for 2^12 <= n < 2^18 were chosen on the chip (TPU
+    v5 lite, `msm_windows` in a process of its own, the median of three
+    repeats), twice. PR 32, chip call 1, while every field operation was a
+    16-step scan: at n = 2^14, c = 7 / 8 / 9 / 10 / 11 read 0.345 / 0.309 /
+    0.293 / 0.280 / 0.309 s; at 2^15, c = 8 / 9 / 10 read 0.427 / 0.398 /
+    0.375; at 2^16, c = 8 / 9 / 10 / 11 read 0.685 / 0.636 / 0.594 / 0.608:
+    a window cost ~9 ms whatever its buckets (the carry chains' steps at
+    its ten narrow levels), so fewer, wider windows won and the whole class
+    took 10. PR 36, chip call 2, with the carries resolved in one pass: at
+    2^14, c = 8 / 9 / 10 read 0.0916 / 0.0924 / 0.0991 s; at 2^15, 0.1665 /
+    0.1615 / 0.1614; at 2^16, 0.3455 / 0.3299 / 0.3141. A window is now
+    ~0.14 us an insertion and ~0.09 us an addition of the emission tree
+    (14 x 2^c a window) on top of ~0.5 ms, which is the count of additions'
+    own answer: 8 under 2^15, 10 from there (at 2^15 itself 9 and 10 tie).
     Every other entry (signed, n >= 2^18, n < 2^12) dates from XLA:CPU
     sweeps and has not run on the chip. With signed digits the bucket array
     is 2^(c-1)+1, so the emission term that caps c relaxes by one bucket-
@@ -596,8 +601,10 @@ def default_window(n: int, signed: bool = False) -> int:
         return 5
     if n >= 1 << 18:
         return 13
-    if n >= 1 << 12:
+    if n >= 1 << 15:
         return 10
+    if n >= 1 << 12:
+        return 8
     if n >= 1 << 7:
         return 7
     return 4

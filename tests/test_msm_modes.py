@@ -292,13 +292,15 @@ class TestFixedTableCache:
 
 class TestDefaultWindowTuning:
     def test_pinned_unsigned(self):
-        # 10 for 2^12 <= n < 2^18 is the chip's choice (PR 32, chip call 1:
-        # `msm_windows` at 2^14, c = 7..11, read 0.345 / 0.309 / 0.293 /
-        # 0.280 / 0.309 s; 10 also won at 2^15 and 2^16): the sweep kept
-        # the value the XLA:CPU table had
+        # 2^12 <= n < 2^18 is the chip's choice, twice. PR 32 (every field
+        # operation a 16-step scan, a window ~9 ms whatever its buckets):
+        # 10 won at 2^14, 2^15 and 2^16. PR 36 (carries resolved in one
+        # pass, chip call 2: `msm_windows` at 2^14, c = 8 / 9 / 10, read
+        # 0.0916 / 0.0924 / 0.0991 s; at 2^15 0.1665 / 0.1615 / 0.1614; at
+        # 2^16 0.3455 / 0.3299 / 0.3141): 8 under 2^15, 10 from there
         assert [MSM.default_window(n) for n in
-                (1 << 6, 1 << 7, 1 << 12, 1 << 16, 1 << 18)] == \
-            [4, 7, 10, 10, 13]
+                (1 << 6, 1 << 7, 1 << 12, 1 << 14, 1 << 15, 1 << 16,
+                 1 << 18)] == [4, 7, 8, 8, 10, 10, 13]
 
     def test_pinned_signed(self):
         # signed digits halve the bucket array -> each size class affords
@@ -329,8 +331,8 @@ class TestWindowOverride:
         assert MSM.window_override() is None
         monkeypatch.setenv("SPECTRE_MSM_WINDOW", "")
         assert MSM.window_override() is None
-        # the table's value: the winner of PR 32's sweep on the chip (call 1)
-        assert MSM.default_window(1 << 12) == 10
+        # the table's value: the winner of PR 36's sweep on the chip (call 2)
+        assert MSM.default_window(1 << 12) == 8
 
     @pytest.mark.parametrize("bad", ["0", "14", "-3"])
     def test_out_of_range_rejected(self, bad, monkeypatch):
@@ -398,8 +400,9 @@ class TestMsmTableBudgetDegrade:
 
 
 # `ec.padd` call sites of the served window program (2^14 points, the
-# table's c = 10): 14 + 4 + 5 + 5 + 2
-WINDOW_PADD_SITES = 30
+# table's c = 8): 14 + 4 + 4 + 4 + 2 (30 while the table said 10: the
+# aggregate's two trees are c // 2 and c - c // 2 levels)
+WINDOW_PADD_SITES = 28
 
 
 def _padd_call_sites(monkeypatch, n: int, c: int) -> list:
@@ -421,13 +424,32 @@ def _padd_call_sites(monkeypatch, n: int, c: int) -> list:
     return sites
 
 
+# `lax.scan`s in one `ec.padd`'s trace: the CIOS rounds of its two stacked
+# `mont_mul`s. It was 39 until PR 36, the other 37 the 16-step carry and
+# borrow chains of its 11 stacked `add` / `sub` calls and of the two
+# multiplications' tails (592 of an addition's 624 sequential steps).
+PADD_SCANS = 2
+
+
+def _count_scans(jaxpr) -> int:
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "scan"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _count_scans(sub)
+    return n
+
+
 class TestWindowProgramSize:
-    """What `setup_s` pays for: every `ec.padd` call site of the window
-    program is 39 loops to lower, compile and load (PERF.md section 5), and
-    the served commit's program, `msm_windows` at 2^14 points and the
-    table's window, is most of a process's set-up. An edit that adds call
-    sites to it (an unrolled chain, a tree split in two, a recursion of the
-    aggregate) fails here by name."""
+    """What `setup_s` and `prove_s` pay for: every `ec.padd` call site of
+    the window program is two loops (the CIOS rounds) and some hundred
+    fused programs to lower, compile and load (PERF.md section 5), each
+    loop iteration a sequence of device programs of its own when it runs,
+    and the served commit's program, `msm_windows` at 2^14 points and the
+    table's window, is most of a process's set-up and four fifths of a
+    prove. An edit that adds call sites to it (an unrolled chain, a tree
+    split in two, a recursion of the aggregate) or a loop to an addition
+    (a carry chain written limb by limb) fails here by name."""
 
     N = 1 << 14
 
@@ -444,6 +466,11 @@ class TestWindowProgramSize:
         assert sites[14:18] == [(k, 1 << c) for k in (7, 4, 2, 1)]
         assert {s[0] for s in sites[18:]} == {nwin}
         assert len(sites) == WINDOW_PADD_SITES
+
+    def test_scans_in_one_point_addition(self):
+        pt = jax.ShapeDtypeStruct((8, 3, 16), jnp.uint32)
+        closed = jax.make_jaxpr(lambda p, q: ec.padd(p, q))(pt, pt)
+        assert _count_scans(closed.jaxpr) == PADD_SCANS
 
 
 class TestKernelShapesPinned:

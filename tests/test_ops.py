@@ -20,6 +20,10 @@ def rand_fr(n):
     return [secrets.randbelow(bn.R) for _ in range(n)]
 
 
+def _field(field):
+    return (F.fr_ctx(), bn.R) if field == "fr" else (F.fq_ctx(), bn.P)
+
+
 class TestLimbs:
     def test_roundtrip(self):
         vals = [0, 1, bn.R - 1, 2**255 - 1, 12345]
@@ -71,11 +75,190 @@ class TestFieldOps:
         and p - 0 = p has to fold back to the canonical representative
         (equal values mod p is what `test_mul_add_sub_neg` decodes; equal
         limbs was checked only on the Pallas mirror, which is gone)."""
-        ctx, p = (F.fr_ctx(), bn.R) if field == "fr" else (F.fq_ctx(), bn.P)
+        ctx, p = _field(field)
         a = [0, 1, p - 1] + [secrets.randbelow(p) for _ in range(5)]
         am = jnp.asarray(ctx.encode(a))
         got = jax.jit(lambda x, y: F.sub(ctx, x, y))(am, jnp.zeros_like(am))
         assert np.array_equal(np.asarray(got), np.asarray(am))
+
+
+# The 16-step scan forms `field_ops` held until PR 36 (one limb a loop
+# iteration), kept here as the oracle of the one-pass forms that took their
+# place: equal limbs AND equal carry / borrow out, not equal values mod p.
+
+def _carry_propagate_scan(t):
+    tT = jnp.moveaxis(t, -1, 0)
+
+    def step(carry, ti):
+        cur = ti + carry
+        return cur >> 16, cur & F.MASK
+
+    carry, outs = jax.lax.scan(step, jnp.zeros_like(tT[0]), tT)
+    return jnp.moveaxis(outs, 0, -1), carry
+
+
+def _sub_limbs_scan(a, b):
+    shape = jnp.broadcast_shapes(a.shape, b.shape)
+    aT = jnp.moveaxis(jnp.broadcast_to(a, shape), -1, 0)
+    bT = jnp.moveaxis(jnp.broadcast_to(b, shape), -1, 0)
+
+    def step(borrow, ab):
+        ai, bi = ab
+        cur = ai - bi - borrow  # uint32 wraps
+        return (cur >> 16) & np.uint32(1), cur & F.MASK  # wrap iff borrow
+
+    borrow, outs = jax.lax.scan(step, jnp.zeros_like(aT[0]), (aT, bT))
+    return jnp.moveaxis(outs, 0, -1), borrow
+
+
+def _cond_sub_p_scan(ctx, a):
+    diff, borrow = _sub_limbs_scan(a, jnp.broadcast_to(ctx.p_limbs, a.shape))
+    return jnp.where((borrow == 0)[..., None], diff, a)
+
+
+def _add_scan(ctx, a, b):
+    return _cond_sub_p_scan(ctx, _carry_propagate_scan(a + b)[0])
+
+
+def _sub_scan(ctx, a, b):
+    pb, _ = _sub_limbs_scan(jnp.broadcast_to(ctx.p_limbs, b.shape), b)
+    return _add_scan(ctx, a, pb)
+
+
+def _neg_scan(ctx, a):
+    pb, _ = _sub_limbs_scan(jnp.broadcast_to(ctx.p_limbs, a.shape), a)
+    is_zero = jnp.all(a == 0, axis=-1, keepdims=True)
+    return jnp.where(is_zero, jnp.zeros_like(a), _cond_sub_p_scan(ctx, pb))
+
+
+def _limbs(vals):
+    return jnp.asarray(L.ints_to_limbs16(list(vals)))
+
+
+def _rand_ints(p, n, seed):
+    rng = np.random.default_rng(seed)
+    return [int.from_bytes(rng.bytes(40), "little") % p for _ in range(n)]
+
+
+def _rand_below(p, shape, seed):
+    return _limbs(_rand_ints(p, int(np.prod(shape)), seed)).reshape(
+        tuple(shape) + (16,))
+
+
+def _same(got, want):
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+
+
+# accumulators handed to `_carry_propagate`: name -> f(p) -> [..., 16] uint32
+ACCUMULATORS = {
+    # 0xFFFF... + 1: the carry made in limb 0 passes through all sixteen
+    "ripple_through_all_limbs": lambda p: _limbs([(1 << 256) - 1]).at[0, 0].add(1),
+    "p_minus_1_twice": lambda p: _limbs([p - 1]) * 2,
+    "zero_and_one": lambda p: _limbs([0, 1]),
+    # what the CIOS rounds hand over: limbs up to 2^24 - 1, every one of them
+    "limbs_at_2_24_minus_1": lambda p: jnp.full((3, 16), (1 << 24) - 1, jnp.uint32),
+    "limbs_below_2_24": lambda p: jnp.asarray(np.random.default_rng(24).integers(
+        0, 1 << 24, size=(64, 16), dtype=np.uint32)),
+    # a carry of more than one out of the top limb comes back as before
+    "top_carry_above_one": lambda p: jnp.zeros((2, 16), jnp.uint32).at[:, 15].set(
+        jnp.asarray([0x30000, 0x2FFFF], jnp.uint32)).at[1, 14].set(0x10000),
+    "limbs_at_2_31_minus_1": lambda p: jnp.full((2, 16), (1 << 31) - 1, jnp.uint32),
+    "stacked_6_n": lambda p: _rand_below(p, (6, 5), 1) + _rand_below(p, (6, 5), 2),
+}
+
+# operand pairs of `_sub_limbs`, `add`, `sub`: name -> f(p) -> (a, b) limbs
+PAIRS = {
+    "a_minus_a": lambda p: (_rand_below(p, (8,), 3),) * 2,
+    "zero_minus_one": lambda p: (_limbs([0]), _limbs([1])),
+    # the borrow made in limb 0 passes through a run of fourteen zero limbs
+    "borrow_through_zero_limbs": lambda p: (_limbs([1 << 240, 1 << 240]),
+                                           _limbs([1, 0xFFFF])),
+    "sum_is_p_exactly": lambda p: (_limbs([1, p - 1, p // 2]),
+                                   _limbs([p - 1, 1, p - p // 2])),
+    "p_minus_1_twice": lambda p: (_limbs([p - 1]), _limbs([p - 1])),
+    "minus_zero": lambda p: (_limbs([0, 1, p - 1]), _limbs([0, 0, 0])),
+    "edges_crossed": lambda p: tuple(
+        _limbs(v) for v in zip(*[(a, b) for a in _edges(p) for b in _edges(p)])),
+    "stacked_6_n_against_16": lambda p: (_rand_below(p, (6, 7), 4),
+                                         _rand_below(p, (), 5)),
+    "random_rows": lambda p: (_rand_below(p, (64,), 6), _rand_below(p, (64,), 7)),
+}
+
+
+# normalized values under 2p, what `_cond_sub_p` takes
+BELOW_2P = {
+    "below_p": lambda p: _limbs([0, 1, p - 1, p - (1 << 16)]),
+    "at_and_above_p": lambda p: _limbs([p, p + 1, 2 * p - 1, p + (1 << 240)]),
+    "stacked_6_n": lambda p: _limbs(
+        [x + y for x, y in zip(_rand_ints(p, 54, 8), _rand_ints(p, 54, 9))]
+    ).reshape(6, 9, 16),
+}
+
+
+def _edges(p):
+    return [0, 1, p - 1, p - 2, (1 << 240) - 1, 0xFFFF, p - (1 << 16),
+            (1 << 253) - 1]
+
+
+class TestCarryChains:
+    """`_carry_propagate`, `_sub_limbs`, `_cond_sub_p` and the three
+    operations built on them against their scan forms, limb for limb."""
+
+    @pytest.mark.parametrize("case", sorted(ACCUMULATORS))
+    @pytest.mark.parametrize("field", ["fr", "fq"])
+    def test_carry_propagate(self, field, case):
+        _, p = _field(field)
+        t = ACCUMULATORS[case](p)
+        _same(jax.jit(F._carry_propagate)(t), _carry_propagate_scan(t))
+
+    @pytest.mark.parametrize("case", sorted(PAIRS))
+    @pytest.mark.parametrize("field", ["fr", "fq"])
+    def test_sub_limbs(self, field, case):
+        _, p = _field(field)
+        a, b = PAIRS[case](p)
+        _same(jax.jit(F._sub_limbs)(a, b), _sub_limbs_scan(a, b))
+        _same(jax.jit(F._sub_limbs)(b, a), _sub_limbs_scan(b, a))
+
+    @pytest.mark.parametrize("case", sorted(BELOW_2P))
+    @pytest.mark.parametrize("field", ["fr", "fq"])
+    def test_cond_sub_p(self, field, case):
+        ctx, p = _field(field)
+        a = BELOW_2P[case](p)
+        _same(jax.jit(lambda x: F._cond_sub_p(ctx, x))(a),
+              _cond_sub_p_scan(ctx, a))
+
+    @pytest.mark.parametrize("case", sorted(PAIRS))
+    @pytest.mark.parametrize("field", ["fr", "fq"])
+    def test_add_sub_neg(self, field, case):
+        ctx, p = _field(field)
+        a, b = PAIRS[case](p)
+        total = jax.jit(lambda x, y: F.add(ctx, x, y))(a, b)
+        diff = jax.jit(lambda x, y: F.sub(ctx, x, y))(a, b)
+        minus = jax.jit(lambda x: F.neg(ctx, x))(b)
+        _same((total, diff, minus), (_add_scan(ctx, a, b),
+                                     _sub_scan(ctx, a, b), _neg_scan(ctx, b)))
+        ints = lambda x: L.limbs16_to_ints(np.asarray(x).reshape(-1, 16))  # noqa: E731
+        ai, bi = ints(jnp.broadcast_to(a, total.shape)), \
+            ints(jnp.broadcast_to(b, total.shape))
+        assert ints(total) == [(x + y) % p for x, y in zip(ai, bi)]
+        assert ints(diff) == [(x - y) % p for x, y in zip(ai, bi)]
+        assert ints(minus) == [(-y) % p for y in ints(b)]
+
+    @pytest.mark.parametrize("field", ["fr", "fq"])
+    def test_mont_mul_tail(self, field):
+        """The CIOS rounds' accumulator through the new carry pass and the
+        conditional subtraction: products of edge values and random rows
+        against Python ints."""
+        ctx, p = _field(field)
+        vals = _edges(p) + _rand_ints(p, 24, 10)
+        a = jnp.asarray(ctx.encode([x for x in vals for _ in vals]))
+        b = jnp.asarray(ctx.encode([y for _ in vals for y in vals]))
+        got = np.asarray(jax.jit(lambda x, y: F.mont_mul(ctx, x, y))(a, b))
+        assert ctx.decode(got) == [x * y % p for x in vals for y in vals]
+        assert (got < (1 << 16)).all()
 
 
 class TestNTT:
