@@ -1,6 +1,6 @@
 """The `breakdown` of a traced run. `device_ops`: the device programs of
 the shapes that were traced whole, by seconds a call x the calls in the
-window; a shape that was not traced (today the 2^14 MSM, most of the
+window; a shape that was not traced (today the 2^14 MSM, 57 % of the
 proof) is NOT in the list, since nothing stands in for device seconds.
 `idle_gaps`: the window's seconds outside every backend call of a kind that
 reaches the device, by the program's span the host was in. Span boundaries

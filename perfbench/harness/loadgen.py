@@ -5,6 +5,11 @@
                  its last is served (closed loop), over a connection that
                  genEvmProof_* holds until the proof is there (blocking)
     concurrency  the service's `--concurrency`
+    stagger_s    optional: client number k (from 0) sends its first request
+                 k x this many seconds after the first send, so that the
+                 jobs of a closed loop run out of phase, as a backlog's do
+                 once it has been going for a while, and do not all start
+                 in the same millisecond (0 where the key is absent)
 
 Requests are sent while less than `seconds` have passed since the first
 send; every request sent is waited for; the window ends when the last one
@@ -66,15 +71,18 @@ class Window:
             s.error = f"{type(exc).__name__}: {exc}"
         s.t_done = time.time()
 
-    def _client(self, client):
+    def _client(self, client, k: int):
+        delay = k * float(self.traffic.get("stagger_s", 0.0))
+        if delay:
+            time.sleep(max(0.0, self.t_first + delay - time.time()))
         while self._open():
             self._blocking(client, self._take())
 
     def run(self):
         self.t_first = time.time()
         threads = [threading.Thread(target=self._client,
-                                    args=(self.served.client(),))
-                   for _ in range(int(self.traffic.get("clients", 1)))]
+                                    args=(self.served.client(), k))
+                   for k in range(int(self.traffic.get("clients", 1)))]
         for t in threads:
             t.start()
         for t in threads:

@@ -176,6 +176,22 @@ def named_seconds(ctx, name: str):
     return per_proof(ctx, one)
 
 
+def no_inflight_s(ctx):
+    """Seconds of the window in which NO served job had a call in flight,
+    over the proofs served: `host_only_s` taken across the jobs of a
+    window, on the wall clock their spans share. A job's own seconds hold
+    its waits for a neighbour; the window's do not, and they divide as
+    `prove_s` does."""
+    w, srv = ctx["window"], readers.served(ctx)
+    flights = [(a, b) for s in srv for a, b, _ in in_flight(
+        [(e["name"], e["ts"] / 1e6 - w.t_first,
+          (e["ts"] + e["dur"]) / 1e6 - w.t_first)
+         for e in (s.spans or []) if e.get("ph") == "X"])]
+    if not flights:
+        return None
+    return (w.wall_s - union_s(clip(flights, 0.0, w.wall_s))) / len(srv)
+
+
 def transfer_mb(ctx):
     """Bytes a proof across the device boundary, both ways, from the
     manifest's `transfer_bytes` (the spans' `bytes`), in MB."""

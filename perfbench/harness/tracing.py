@@ -4,10 +4,10 @@ of the backend object, and the profiler round whole calls of it. A
 and nothing else of this file.
 
 The device tracer of this chip writes every XLA operation of every loop
-iteration (one 2^14 MSM: a 156 MB file and two minutes of `stop_trace`
-beside a prove, 40 s and more in a process that does nothing else), so a
-window cannot be traced, and neither can a call of half a second inside a
-run's wall limit. Device times repeat to a microsecond for the same program
+iteration (one 2^14 MSM of 0.5 s, as it was in PR 28: a 156 MB file and
+two minutes of `stop_trace` beside a prove, 40 s and more in a process that
+does nothing else), so a window cannot be traced, and neither can a call
+of tenths of a second inside a run's wall limit. Device times repeat to a microsecond for the same program
 and shape. So once the window has closed a traced run REPLAYS one whole
 call of each operation and shape, shortest first, as far as `MAX_REPLAY_S`
 reaches, under one profiler session, in a process that by then does nothing
@@ -101,9 +101,10 @@ class Call:
 # What one profiler session may hold, in host seconds of the calls replayed
 # under it as the window saw them: the tracer writes some 0.3 MB for every
 # millisecond the device runs and `stop_trace` a few MB a second, and a run
-# has 50 s to spare of its 360. A call longer than this is never replayed;
-# today that keeps out the 2^14 MSM (0.5 s, a 156 MB file). An MSM call
-# shorter than this is replayed like the rest.
+# has to end inside its 360 s. A call longer than this is never replayed;
+# today that keeps out the 2^14 MSM (a single `msm` 0.106 s and a run of 16
+# columns 1.49 s since PR 36; 0.5 s and a 156 MB file when this was set).
+# An MSM call shorter than this is replayed like the rest.
 MAX_REPLAY_S = 0.1
 TPU_TRACE_MODE = "TRACE_COMPUTE"    # the cheapest mode that has the device
 SESSION = "perfbench/replay"

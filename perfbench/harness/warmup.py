@@ -1,15 +1,26 @@
 """A shorter warm-up prove. Set-up has to put every program the window will
 run into this process's jit caches, and the only entry that reaches all of
-them is a whole served prove. Most of that prove is one program run again:
-138 column commits through the same two MSM kernels, 0.5 s each. For the
-length of the warm-up request only, a backend operation named in the
-configuration's `warmup.run_each_shape` runs for real that many times for
-each distinct shape of its arguments and answers every further call of
-that shape with its last answer. The warm-up proof then does not verify,
-and nothing reads it: it is a committee the window never sends, and the
-host verifier is off for it. The window runs the backend as the class has
-it; a shape this had kept from compiling would read `compiles_in_window`
-above 0 there and the run `correct: false`."""
+them is a whole served prove. For the length of the warm-up request only,
+a backend operation named in the configuration's `warmup.run_each_shape`
+runs for real that many times for each distinct shape of its arguments and
+answers every further call of that shape with its last answer. The warm-up
+proof then does not verify where a call was answered, and nothing reads
+it: it is a committee the window never sends, and the host verifier is off
+for it. The window runs the backend as the class has it; a shape this had
+kept from compiling would read `compiles_in_window` above 0 there and the
+run `correct: false`.
+
+What it shortens depends on the backend. Where `msm_many` loops `msm`
+(`CpuBackend`, and `TpuBackend` until PR 30: 138 column commits of 0.5 s
+through the same two kernels) a plan of `{"msm": 2}` answers all but two
+commits a shape. Since PR 30 `TpuBackend.msm_many` no longer calls `msm`:
+one device commits a run of 16 columns as a call of its own, so on the chip
+the plan reaches the prove's two single `msm` calls and answers none (the
+run's line reads `"msm": {"ran": 2, "answered_from_last": 0}`), and the
+warm-up is a whole prove, 164 commits of 0.0916 s since PR 36. A plan
+that names `msm_many` would answer most of those 15 s in every run's
+`setup_s`; that changes what set-up does and is an issue of its own
+(ROADMAP Queue 1 item 2a)."""
 
 from __future__ import annotations
 

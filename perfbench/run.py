@@ -74,8 +74,9 @@ def warm_up(opts, config, requests, served, client, methods):
     for it (tracing and lowering 60 programs allocates tens of millions of
     objects that hold no cycles), and so is the host verifier, which is
     plain Python with nothing to warm; and a backend operation the
-    configuration names under `warmup` runs each shape a few times, not
-    138 (harness/warmup.py). All three only shorten set-up, which every
+    configuration names under `warmup` runs each shape a few times and is
+    answered from its last run after (harness/warmup.py says what that
+    reaches on which backend). All three only shorten set-up, which every
     run of every later check pays in full."""
     warm = loadgen.Sent(index=-1,
                         request=requests.make(config, opts.seed, "warmup"))
@@ -190,7 +191,8 @@ def measure(opts, cell, dev, paths, requests, reference, served) -> int:
     # says in which phase
     for s in window.sent[:8]:
         if s.manifest:
-            log(f"request {s.index}: {s.t_done - s.t_send:.3f}s; phase "
+            log(f"request {s.index}: sent at {s.t_send - window.t_first:.3f}s, "
+                f"{s.t_done - s.t_send:.3f}s; phase "
                 f"seconds: {json.dumps(s.manifest['phase_seconds'])}")
 
     session = trace = None

@@ -15,8 +15,7 @@ import urllib.request
 ZERO_COUNTERS = (
     "prove_cpu_fallbacks_oom", "prove_cpu_fallbacks_compile",
     "proofs_sdc_retried", "proofs_verify_failed", "msm_fixed_degraded",
-    "msm_pallas_degraded", "quotient_sharded_degraded",
-    "self_check_failures",
+    "quotient_sharded_degraded", "self_check_failures",
 )
 
 

@@ -4,8 +4,11 @@ the chip (the benchmark's own runs do not run it):
 
     python3 perfbench/tests/control_on_chip.py --workload cu-minimal32.serial --seeds 11,12,13
 
-One service, one warm-up prove, then one served proof per seed through the
-window's own entry. Each served result is judged by the reference as a run
+One service, one warm-up prove, then for each seed a short window at the
+cell's own load through the window's own entry (`--seconds`, by default one
+more than the last client's stagger: each client of the mix sends once, so
+one proof a seed in `serial` and in `pair` two, the second 13 s after the
+first and beside it from there). Each served result is judged by the reference as a run
 judges it (has to pass), then once with each guarantee broken (has to be
 refused): a commitment, an evaluation and the opening altered, another
 request's header, another committee, another set-up, a truncated proof, and
@@ -57,10 +60,14 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", required=True)
+    ap.add_argument("--seconds", type=float)
     opts = ap.parse_args()
     seeds = [int(s) for s in opts.seeds.split(",")]
     cell = cells.load_cell(opts.workload)
     config, traffic = cell.config, cell.traffic
+    if opts.seconds is None:
+        opts.seconds = 1.0 + traffic.get("stagger_s", 0.0) \
+            * (traffic["clients"] - 1)
     device.require_platform(config["platform"], cell.chips)
     paths = workdir.prepare(config)
     requests = cells.load_plugin("requests", config["circuit"])
@@ -74,13 +81,14 @@ def main() -> int:
                      requests.make(config, seeds[0], "warmup")["params"])
         results = []
         for seed in seeds:
-            w = loadgen.Window(served, dict(traffic, clients=1),
+            w = loadgen.Window(served, traffic,
                                lambda i, s=seed: requests.make(config, s, i),
-                               (requests.METHOD, requests.SUBMIT_METHOD), 1.0)
+                               (requests.METHOD, requests.SUBMIT_METHOD),
+                               opts.seconds)
             w.run()
-            results.append((seed, w.sent[0]))
-            bench.log(f"seed {seed}: served in {w.wall_s:.1f}s "
-                      f"error={w.sent[0].error}")
+            results += [(f"{seed}/{s.index}", s) for s in w.sent]
+            bench.log(f"seed {seed}: {len(w.sent)} served in {w.wall_s:.1f}s "
+                      f"errors={[s.error for s in w.sent if s.error]}")
         ref = reference.Reference(config, served.verifying_key())
         for i, (seed, sent) in enumerate(results):
             if sent.error:

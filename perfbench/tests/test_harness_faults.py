@@ -7,11 +7,15 @@ fault this kind of cell can have, planted under the timed path:
   has seen it, so only the benchmark's reference can notice);
 - verify-before-serve skipped (the guarantee the configuration states).
 
-The faults of training cells (state unchanged, half a batch, the exchange
-between chips) have no counterpart in a one-chip prover. Slow: five served
-proves of about a minute each."""
+The sound run and the altered answer are driven through both traffic
+mixes: in `pair` the two jobs run side by side and ONE of them is altered,
+so the run has to tell the sound neighbour from the altered one. The
+faults of training cells (state unchanged, half a batch, the exchange
+between chips) have no counterpart in a one-chip prover. Slow: eleven
+served proves of about a minute each."""
 
 import io
+import itertools
 import json
 import os
 import sys
@@ -49,42 +53,59 @@ def bench_path(tmp_path_factory):
     return str(path)
 
 
-def one_run(bench_path, seed):
+# cell -> requests a window sends that closes a second after its last
+# client's first turn (`stagger_s` in the mix): one a client
+CELLS = {"cu-minimal32.serial": 1, "cu-minimal32.pair": 2}
+
+
+def seconds_for(workload) -> float:
+    with open(os.path.join(BENCH, "traffic",
+                           workload.rsplit(".", 1)[1] + ".json")) as f:
+        mix = json.load(f)
+    return 1.0 + mix.get("stagger_s", 0.0) * (mix["clients"] - 1)
+
+
+def one_run(bench_path, seed, workload="cu-minimal32.serial"):
     import run
     out = io.StringIO()
     with redirect_stdout(out):
-        rc = run.main(["--workload", "cu-minimal32.serial", "--seed",
-                       str(seed), "--seconds", "1", "--trace", "0"],
+        rc = run.main(["--workload", workload, "--seed",
+                       str(seed), "--seconds", str(seconds_for(workload)),
+                       "--trace", "0"],
                       bench_path=bench_path)
     assert rc == 0
     return json.loads(out.getvalue().strip().splitlines()[-1])
 
 
-def test_sound_run_is_correct(bench_path):
-    line = one_run(bench_path, 2**31 + 1)
+@pytest.mark.parametrize("workload", CELLS)
+def test_sound_run_is_correct(bench_path, workload):
+    line = one_run(bench_path, 2**31 + 1, workload)
     assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] == CELLS[workload]
     assert set(line["metrics"]) == {"prove_s", "setup_s"}
     assert list(line)[-1] == "checks"
 
 
-def test_altered_answer_is_not_correct(bench_path, monkeypatch):
+@pytest.mark.parametrize("workload", CELLS)
+def test_altered_answer_is_not_correct(bench_path, monkeypatch, workload):
     from spectre_tpu.prover_service import rpc
     sound = rpc.verified_prove
-    calls = []
+    served = itertools.count(1)            # `next` is safe from two jobs
 
     def altered(state, kind, args, heartbeat=None):
         proof, instances = sound(state, kind, args, heartbeat=heartbeat)
-        calls.append(kind)
-        if len(calls) < 2:                 # the warm-up is left sound
-            return proof, instances
+        if next(served) != 2:              # the warm-up is left sound, and
+            return proof, instances        # in `pair` the neighbour too
         bad = bytearray(proof)
         bad[-70] ^= 1                      # a byte of W1's y
         return bytes(bad), instances
 
     monkeypatch.setattr(rpc, "verified_prove", altered)
-    line = one_run(bench_path, 2**31 + 2)
+    line = one_run(bench_path, 2**31 + 2, workload)
     assert line["correct"] is False
     assert line["checks"]["proofs_rejected_by_reference"][0] == 1
+    assert line["failed"] == 1
+    assert line["attempted"] == CELLS[workload]
 
 
 def test_unverified_serve_is_not_correct(bench_path, monkeypatch):
