@@ -59,6 +59,11 @@ class HostLib:
             lib.g1_add_affine_batch.restype = None
             lib.g1_scalar_powers.argtypes = [u64p, u64p, ctypes.c_size_t, u64p]
             lib.g1_scalar_powers.restype = None
+            lib.g1_fixed_base_mul.argtypes = [u64p, u64p, ctypes.c_size_t, u64p]
+            lib.g1_fixed_base_mul.restype = None
+            lib.g1_fft.argtypes = [u64p, ctypes.c_size_t, u64p, u64p,
+                                   ctypes.c_int]
+            lib.g1_fft.restype = None
             lib.fp_horner.argtypes = [ctypes.c_int, u64p, u64p, u64p, ctypes.c_size_t]
             lib.fp_horner.restype = None
             lib.fp_sum.argtypes = [ctypes.c_int, u64p, u64p, ctypes.c_size_t]
@@ -123,6 +128,14 @@ def points_to_limbs(points) -> np.ndarray:
             flat.extend([int(pt[0]), int(pt[1])])
     xs = ints_to_limbs(flat)
     return xs.reshape(len(points), 8)
+
+
+def limbs_to_points(arr: np.ndarray) -> list:
+    """[n, 8] uint64 -> list of affine (x:int, y:int) or None for (0, 0):
+    `points_to_limbs`' inverse."""
+    flat = limbs_to_ints(np.ascontiguousarray(arr).reshape(-1, 4))
+    return [None if x == 0 and y == 0 else (x, y)
+            for x, y in zip(flat[0::2], flat[1::2])]
 
 
 # ---------------------------------------------------------------------------
@@ -213,6 +226,37 @@ def g1_scalar_powers(g, tau: int, n: int) -> np.ndarray:
     tl = ints_to_limbs([tau]).reshape(4)
     out = np.zeros((n, 8), dtype=np.uint64)
     lib.g1_scalar_powers(_u64p(gl), _u64p(tl), n, _u64p(out))
+    return out
+
+
+def g1_fixed_base_mul(g, scalars: np.ndarray) -> np.ndarray:
+    """[n, 8] limbs: scalars[i] * g for [n, 4] standard-form scalars, through
+    `g1_scalar_powers`' fixed-base table. g = affine (x, y) ints."""
+    lib = HostLib().lib
+    gl = ints_to_limbs([int(g[0]), int(g[1])]).reshape(8)
+    scalars = np.ascontiguousarray(scalars, dtype=np.uint64)
+    n = scalars.shape[0]
+    assert scalars.shape == (n, 4)
+    out = np.zeros((n, 8), dtype=np.uint64)
+    lib.g1_fixed_base_mul(_u64p(gl), _u64p(scalars), n, _u64p(out))
+    return out
+
+
+def g1_fft(points: np.ndarray, omega: int, scale: int | None = None,
+           nthreads: int | None = None) -> np.ndarray:
+    """FFT over the group: [n, 8] affine points P_j (n a power of two) ->
+    new [n, 8] array of sum_j omega^(i j) P_j, every point then multiplied
+    by `scale` if given. With omega^-1 and 1 / n: the inverse transform."""
+    lib = HostLib().lib
+    out = np.array(points, dtype=np.uint64, order="C")
+    n = out.shape[0]
+    logn = n.bit_length() - 1
+    assert out.shape == (n, 8) and 1 << logn == n
+    sc = None if scale is None else ints_to_limbs([scale])
+    if nthreads is None:
+        nthreads = min(8, os.cpu_count() or 1)
+    lib.g1_fft(_u64p(out), logn, _u64p(ints_to_limbs([omega])),
+               None if sc is None else _u64p(sc), nthreads)
     return out
 
 

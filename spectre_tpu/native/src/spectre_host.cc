@@ -685,26 +685,65 @@ void fp_sum(int field, const u64* a, u64* out, size_t n) {
 
 }  // extern "C"
 
-extern "C" {
+namespace {
 
-// SRS generation: out[i] = tau^i * G, affine standard form [n, 8] limbs.
-// Sequential chain P_{i+1} = tau * P_i with jacobian double-and-add.
-void g1_scalar_powers(const u64* g_xy, const u64* tau, size_t n, u64* out) {
-  // P_i = tau^i * g via FIXED-BASE windowed multiplication: the scalars
-  // tau^i are cheap field muls, and one shared table of g-multiples
-  // (16 windows x 2^16 entries) turns every point into <= 16 additions —
-  // the previous per-power double-and-add was O(256) EC ops per point,
-  // which made 2^22+ SRS generation dominate setup wall-clock.
-  spectre_init();
+// Batch-normalize jacobian points to affine standard form [n, 8] limbs:
+// one Montgomery batch inversion of z, skipping infinity points (z == 0
+// would otherwise poison the whole product); infinity -> (0, 0).
+void g1_batch_to_affine(const std::vector<G1>& jac, u64* out) {
   const FpCtx& C = g_fq;
-  const FpCtx& Cr = g_fr;
-  Fp gx, gy;
-  std::memcpy(gx.v, g_xy, 32);
-  std::memcpy(gy.v, g_xy + 4, 32);
+  const size_t n = jac.size();
+  std::vector<Fp> prefix(n);
+  Fp accp = C.one;
+  for (size_t i = 0; i < n; ++i) {
+    prefix[i] = accp;
+    if (!fp_is_zero(jac[i].z)) fp_mul(accp, accp, jac[i].z, C);
+  }
+  Fp inv_acc;
+  fp_inv(inv_acc, accp, C);
+  for (size_t i = n; i-- > 0;) {
+    if (fp_is_zero(jac[i].z)) {
+      std::memset(out + 8 * i, 0, 64);
+      continue;
+    }
+    Fp zinv, zinv2, zinv3, ax, ay;
+    fp_mul(zinv, inv_acc, prefix[i], C);
+    fp_mul(inv_acc, inv_acc, jac[i].z, C);
+    fp_sqr(zinv2, zinv, C);
+    fp_mul(zinv3, zinv2, zinv, C);
+    fp_mul(ax, jac[i].x, zinv2, C);
+    fp_mul(ay, jac[i].y, zinv3, C);
+    from_mont(ax, ax, C);
+    from_mont(ay, ay, C);
+    std::memcpy(out + 8 * i, ax.v, 32);
+    std::memcpy(out + 8 * i + 4, ay.v, 32);
+  }
+}
+
+bool g1_load_affine(G1& p, const u64* xy) {
+  Fp x, y;
+  std::memcpy(x.v, xy, 32);
+  std::memcpy(y.v, xy + 4, 32);
+  if (fp_is_zero(x) && fp_is_zero(y)) {
+    g1_set_inf(p);
+    return false;
+  }
+  to_mont(p.x, x, g_fq);
+  to_mont(p.y, y, g_fq);
+  p.z = g_fq.one;
+  return true;
+}
+
+// out[i] = scalars[i] * g for n standard-form scalars, by FIXED-BASE
+// windowed multiplication: one shared table of g-multiples (256 / W windows
+// x 2^W entries) turns every point into <= 256 / W additions, where a
+// double-and-add a scalar is O(256) EC ops per point (which made 2^22+ SRS
+// generation dominate setup wall-clock).
+void fixed_base_mul_many(const u64* g_xy, const std::vector<Fp>& scalars,
+                         u64* out) {
+  const size_t n = scalars.size();
   G1 base;
-  to_mont(base.x, gx, C);
-  to_mont(base.y, gy, C);
-  base.z = C.one;
+  g1_load_affine(base, g_xy);
 
   // Window width from n (W must divide 64 so digits never straddle limbs).
   // Total adds ~ (256/W) * (2^W + n): the pure-add break-evens are n=224
@@ -729,15 +768,9 @@ void g1_scalar_powers(const u64* g_xy, const u64* tau, size_t n, u64* out) {
     }
   }
 
-  // scalar powers tau^i in Montgomery Fr, emitted in standard form
-  Fp tau_m;
-  std::memcpy(tau_m.v, tau, 32);
-  to_mont(tau_m, tau_m, Cr);
-  Fp cur_s = Cr.one;                  // tau^0 (Montgomery)
   std::vector<G1> jac(n);
   for (size_t i = 0; i < n; ++i) {
-    Fp s;
-    from_mont(s, cur_s, Cr);          // standard-form scalar
+    const Fp& s = scalars[i];
     G1 acc;
     g1_set_inf(acc);
     for (int j = 0; j < NW; ++j) {
@@ -745,36 +778,119 @@ void g1_scalar_powers(const u64* g_xy, const u64* tau, size_t n, u64* out) {
       if (d) g1_add(acc, acc, table[(size_t)j * TSZ + d]);
     }
     jac[i] = acc;
+  }
+  g1_batch_to_affine(jac, out);
+}
+
+// p <- s * p for a standard-form scalar, 4-bit windows high to low
+void g1_mul_var(G1& p, const Fp& s) {
+  if (g1_is_inf(p)) return;
+  G1 tab[16];
+  g1_set_inf(tab[0]);
+  tab[1] = p;
+  for (int d = 2; d < 16; ++d) g1_add(tab[d], tab[d - 1], p);
+  G1 acc;
+  g1_set_inf(acc);
+  for (int j = 63; j >= 0; --j) {
+    for (int d = 0; d < 4; ++d) g1_dbl(acc, acc);
+    unsigned dig = (unsigned)((s.v[j / 16] >> (4 * (j % 16))) & 15);
+    if (dig) g1_add(acc, acc, tab[dig]);
+  }
+  p = acc;
+}
+
+}  // namespace
+
+extern "C" {
+
+// SRS generation: out[i] = tau^i * G, affine standard form [n, 8] limbs.
+void g1_scalar_powers(const u64* g_xy, const u64* tau, size_t n, u64* out) {
+  spectre_init();
+  const FpCtx& Cr = g_fr;
+  // scalar powers tau^i in Montgomery Fr, kept in standard form
+  Fp tau_m;
+  std::memcpy(tau_m.v, tau, 32);
+  to_mont(tau_m, tau_m, Cr);
+  Fp cur_s = Cr.one;                  // tau^0 (Montgomery)
+  std::vector<Fp> scalars(n);
+  for (size_t i = 0; i < n; ++i) {
+    from_mont(scalars[i], cur_s, Cr);
     fp_mul(cur_s, cur_s, tau_m, Cr);
   }
-  // batch-normalize to affine: montgomery batch inversion of z, skipping
-  // infinity points (z == 0 would otherwise poison the whole product)
-  std::vector<Fp> zs(n), prefix(n);
-  Fp accp = C.one;
+  fixed_base_mul_many(g_xy, scalars, out);
+}
+
+// out[i] = scalars[i] * G for scalars GIVEN ([n, 4] limbs, standard form):
+// g1_scalar_powers' table for a set-up that knows tau and wants another
+// base than the powers (the Lagrange base L_i(tau) G).
+void g1_fixed_base_mul(const u64* g_xy, const u64* scalars, size_t n,
+                       u64* out) {
+  spectre_init();
+  std::vector<Fp> sc(n);
+  std::memcpy(sc.data(), scalars, 32 * n);
+  fixed_base_mul_many(g_xy, sc, out);
+}
+
+// Radix-2 FFT over G1, in place on affine standard-form points [2^logn, 8]
+// (natural order in and out): out[i] = sum_j omega^(i j) P_j. The upstream's
+// `g_to_lagrange` (its `best_fft` over curve points): with omega^-1 and a
+// final scaling by 1/n, `scale_std`, it turns the powers tau^j G into the
+// Lagrange base L_i(tau) G without tau. Each butterfly multiplies a POINT by
+// a twiddle, 254 doublings: ~9 s at 2^14 on one thread, so the butterflies
+// of a stage are split over `nthreads`. scale_std may be null (no scaling).
+void g1_fft(u64* points, size_t logn, const u64* omega_std,
+            const u64* scale_std, int nthreads) {
+  spectre_init();
+  const FpCtx& Cr = g_fr;
+  const size_t n = (size_t)1 << logn;
+  std::vector<G1> a(n);
   for (size_t i = 0; i < n; ++i) {
-    zs[i] = jac[i].z;
-    prefix[i] = accp;
-    if (!fp_is_zero(zs[i])) fp_mul(accp, accp, zs[i], C);
+    size_t r = 0;
+    for (size_t b = 0; b < logn; ++b) r |= ((i >> b) & 1) << (logn - 1 - b);
+    g1_load_affine(a[r], points + 8 * i);
   }
-  Fp inv_acc;
-  fp_inv(inv_acc, accp, C);
-  for (size_t i = n; i-- > 0;) {
-    if (fp_is_zero(zs[i])) {
-      std::memset(out + 8 * i, 0, 64);  // infinity -> (0, 0)
-      continue;
+  Fp om;
+  std::memcpy(om.v, omega_std, 32);
+  to_mont(om, om, Cr);
+  // twiddles omega^j, j < n/2, standard form
+  std::vector<Fp> tw(n / 2 ? n / 2 : 1);
+  Fp cur = Cr.one;
+  for (size_t j = 0; j < n / 2; ++j) {
+    from_mont(tw[j], cur, Cr);
+    fp_mul(cur, cur, om, Cr);
+  }
+  auto in_threads = [&](size_t count, auto&& fn) {
+    int t_n = nthreads > 1 && count >= 64 ? nthreads : 1;
+    if (t_n == 1) {
+      for (size_t i = 0; i < count; ++i) fn(i);
+      return;
     }
-    Fp zinv, zinv2, zinv3, ax, ay;
-    fp_mul(zinv, inv_acc, prefix[i], C);
-    fp_mul(inv_acc, inv_acc, zs[i], C);
-    fp_sqr(zinv2, zinv, C);
-    fp_mul(zinv3, zinv2, zinv, C);
-    fp_mul(ax, jac[i].x, zinv2, C);
-    fp_mul(ay, jac[i].y, zinv3, C);
-    from_mont(ax, ax, C);
-    from_mont(ay, ay, C);
-    std::memcpy(out + 8 * i, ax.v, 32);
-    std::memcpy(out + 8 * i + 4, ay.v, 32);
+    std::vector<std::thread> pool;
+    for (int t = 0; t < t_n; ++t)
+      pool.emplace_back([&, t]() {
+        for (size_t i = t; i < count; i += t_n) fn(i);
+      });
+    for (auto& th : pool) th.join();
+  };
+  for (size_t s = 1; s <= logn; ++s) {
+    const size_t m = (size_t)1 << s, half = m >> 1, stride = n / m;
+    in_threads(n / 2, [&](size_t idx) {
+      const size_t k = (idx / half) * m, j = idx % half;
+      G1 t = a[k + j + half];
+      if (j) g1_mul_var(t, tw[j * stride]);
+      G1 u = a[k + j], nt = t;
+      const Fp zero = {{0, 0, 0, 0}};
+      fp_sub(nt.y, zero, t.y, g_fq);
+      g1_add(a[k + j], u, t);
+      g1_add(a[k + j + half], u, nt);
+    });
   }
+  if (scale_std) {
+    Fp sc;
+    std::memcpy(sc.v, scale_std, 32);
+    in_threads(n, [&](size_t i) { g1_mul_var(a[i], sc); });
+  }
+  g1_batch_to_affine(a, points);
 }
 
 // pointwise ops used by the prover's quotient evaluation (standard form)

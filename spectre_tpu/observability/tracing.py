@@ -276,15 +276,20 @@ def summary(tr: Trace | None) -> dict:
     run of the one-device MSM path, plonk/backend.py `_msm_chunks`): the
     columns committed (`real`) and the identity columns that filled the
     runs up to their width (`padded`); `msm_window`, the Pippenger windows
-    `c` those runs carried (one, on a prove of one size of commitment)."""
+    `c` those runs carried (one, on a prove of one size of commitment);
+    `msm_window_passes`, their `active` summed: the window passes the
+    columns ran, ceil(254 / c) a column of full-width scalars and as many
+    as its largest value reaches for a column committed as values."""
     seconds: dict[str, float] = {}
     counts: dict[str, int] = {}
     moved = {"h2d": 0, "d2h": 0}
     columns = {"real": 0, "padded": 0}
     windows = set()
+    passes = 0
     way = {ENCODE: "h2d", WAIT: "d2h"}
 
     def walk(s: Span):
+        nonlocal passes
         for c in s.children:
             counts[c.name] = counts.get(c.name, 0) + 1
             if c.t1 is not None:
@@ -297,6 +302,7 @@ def summary(tr: Trace | None) -> dict:
                 columns["real"] += int(c.meta["batch"])
                 columns["padded"] += int(c.meta["width"] - c.meta["batch"])
                 windows.add(int(c.meta["c"]))
+                passes += int(c.meta["active"])
             walk(c)
 
     if tr is not None:
@@ -306,7 +312,8 @@ def summary(tr: Trace | None) -> dict:
             "span_counts": dict(sorted(counts.items())),
             "transfer_bytes": moved,
             "msm_columns": columns,
-            "msm_window": sorted(windows)}
+            "msm_window": sorted(windows),
+            "msm_window_passes": passes}
 
 
 def phase_seconds(tr: Trace) -> dict[str, float]:

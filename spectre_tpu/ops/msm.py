@@ -6,7 +6,11 @@ the wrong shape for a vector machine — so this is a ground-up redesign around
 three TPU constraints: static shapes, no random-access writes, no data-
 dependent control flow.
 
-Per window (processed under `lax` control flow so the graph stays small):
+Per window (processed under `lax` control flow so the graph stays small;
+`msm_windows` runs as many windows as the caller says the column's scalars
+need, `active`, a traced count, so ONE program serves a column of single
+bits and a column of full field elements; the windows it does not run are
+the identity, which is what a window of zero digits sums to):
   1. digit extraction from limb scalars (branchless bit windowing)
   2. stable sort of point indices by bucket digit
   3. segmented halving reduction over the sorted array: at each of ~log2(n)
@@ -54,7 +58,10 @@ optimizations (`SPECTRE_MSM_MODE`, see `msm_mode()`):
 All modes produce the identical group element (tests/test_msm_modes.py
 holds each to the host curve, tests/test_device_prove.py the default inside
 byte-equal proofs); they differ only in work shape. Only `vanilla` has run
-on the chip (PERF.md section 7).
+on the chip (PERF.md section 7), and only it reads how wide a column's
+scalars are: the three modes above and the mesh kernels (parallel/) run
+every window of their scalar width against any base, the SRS's Lagrange
+base included (values committed as they are: correct, and no faster there).
 """
 
 from __future__ import annotations
@@ -243,15 +250,28 @@ def _aggregate_buckets(bucket_sums, c: int):
     return acc
 
 
-def _msm_windows_impl(points, scalars, c: int, nbits: int):
-    nwin = (nbits + c - 1) // c
+def window_count(nbits: int, c: int) -> int:
+    """Windows of c bits that hold a scalar of nbits bits."""
+    return (nbits + c - 1) // c
+
+
+def _msm_windows_impl(points, scalars, c: int, nbits: int, active=None):
+    """`active` (None: all): a traced int32, the low windows to run. The
+    others stay the identity, so the caller owes that every scalar is below
+    2^(c * active); the loop's trip count is data, not shape."""
+    nwin = window_count(nbits, c)
     nbuckets = 1 << c
 
     def one_window(w):
         d = F.limb_digits(scalars, w, c)
         return _segmented_bucket_sums(points, d, nbuckets)
 
-    bucket_sums = jax.lax.map(one_window, jnp.arange(nwin))  # [nwin, nb, 3, 16]
+    if active is None:
+        bucket_sums = jax.lax.map(one_window, jnp.arange(nwin))
+    else:
+        bucket_sums = jax.lax.fori_loop(
+            0, active, lambda w, buf: buf.at[w].set(one_window(w)),
+            ec.inf_point((nwin, nbuckets)))      # [nwin, nb, 3, 16]
     return _aggregate_buckets(bucket_sums, c)
 
 
@@ -264,13 +284,32 @@ TRACE_JIT_ROOTS = ("msm_windows", "msm_windows_bits", "msm_windows_signed",
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
-def msm_windows(points, scalars, c: int):
+def msm_windows(points, scalars, c: int, active=None):
     """Per-window partial MSM sums: [nwin, 3, 16].
 
     points: [n, 3, 16] projective Montgomery; scalars: [n, 16] standard-form
     16-bit limbs. Separated from the final combine so the window axis can be
-    sharded across devices (parallel.sharded_msm all-reduces these)."""
-    return _msm_windows_impl(points, scalars, c, 254)
+    sharded across devices (parallel.sharded_msm all-reduces these).
+
+    active: an int32 scalar, TRACED (`np.int32`, so that every count is the
+    same program): only the low `active` windows are run, the others are the
+    identity; every scalar must be below 2^(c * active)
+    (`windows_needed` reads the count off a column). The one-device commit
+    path and `msm` always pass it. None runs every window with a static
+    trip count: the form the mesh kernels trace inside their own programs
+    (parallel/), which take any base at full width."""
+    return _msm_windows_impl(points, scalars, c, 254, active)
+
+
+def windows_needed(scalars_u64: np.ndarray, c: int) -> np.int32:
+    """The windows of c bits the largest scalar of a host column reaches:
+    [n, 4] u64 standard limbs -> `msm_windows`' `active`. 0 for a column of
+    zeros, 1 for a column of bits or of bytes at c = 8, ceil(254 / c) for
+    one that holds a full field element."""
+    bits = max((64 * j + int(limb).bit_length()
+                for j, limb in enumerate(scalars_u64.max(axis=0)) if limb),
+               default=0)
+    return np.int32(window_count(bits, c))
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3))
@@ -635,7 +674,9 @@ def msm(points, scalars, c: int | None = None, mode: str | None = None,
     if mode == "vanilla":
         if c is None:
             c = default_window(n)
-        return combine_windows(msm_windows(points, scalars, c), c)
+        # every window, through the program the commit path runs
+        full = np.int32(window_count(254, c))
+        return combine_windows(msm_windows(points, scalars, c, full), c)
 
     from . import glv
     nbits = glv.glv_bits()

@@ -177,13 +177,15 @@ class CpuBackend:
         return out
 
     # -- MSM: points [m, 8] u64 affine standard, scalars [m, 4] --
-    def msm(self, points, scalars, base_key=None):
-        # base_key names a fixed base for the device table cache; the
-        # native Pippenger has no precompute path, so it is ignored here
+    def msm(self, points, scalars, base_key=None, basis="powers"):
+        # base_key names a fixed base for the device table cache and `basis`
+        # what the base is ("powers" of tau, or "lagrange") for
+        # the device backend's spans; the native Pippenger has no precompute
+        # path and no spans, so both are ignored here
         m = min(points.shape[0], scalars.shape[0])
         return host.g1_msm(points[:m], scalars[:m])
 
-    def msm_many(self, points, scalars_list, base_key=None):
+    def msm_many(self, points, scalars_list, base_key=None, basis="powers"):
         """Commit several scalar vectors against the same base points."""
         return [self.msm(points, sc, base_key=base_key)
                 for sc in scalars_list]
@@ -205,7 +207,8 @@ class TpuBackend(CpuBackend):
     `wait` closes on the read that blocked anyway; no stage adds a
     synchronisation. On one device a list of commits is one
     `backend/msm_many` span a run of MSM.CHUNK_WIDTH columns
-    (`_msm_chunks`), with `batch` and `width` on it. The NTT kinds cross
+    (`_msm_chunks`), with `batch`, `width`, the window `c`, `active` (the
+    windows its columns ran) and `basis` on it. The NTT kinds cross
     the boundary twice a call (the transform's result comes to the host
     and goes straight back for `from_mont`): their spans show both
     crossings."""
@@ -278,7 +281,11 @@ class TpuBackend(CpuBackend):
     # via SPECTRE_SHARD_MSM_MIN_LOGN)
     SHARD_MSM_MIN_LOGN = 20
 
-    def msm(self, points, scalars, base_key=None):
+    def msm(self, points, scalars, base_key=None, basis="powers"):
+        """basis: what `points` are, "powers" of tau or "lagrange", a label
+        on the call's span. Only the one-device default path reads
+        the scalars' width (`_msm_chunks`); the mesh and the other MSM modes
+        commit against either base with every window."""
         import jax
         import jax.numpy as jnp
 
@@ -286,11 +293,13 @@ class TpuBackend(CpuBackend):
 
         m = min(points.shape[0], scalars.shape[0])
         if self._use_mesh(m, self._shard_min_logn):
-            return self._msm_sharded(points, scalars, m, base_key=base_key)
+            return self._msm_sharded(points, scalars, m, base_key=base_key,
+                                     basis=basis)
         if self._one_chip_default():
             # a chunk of one: the programs a list's runs have loaded already
-            return self._msm_chunks("backend/msm", points, [scalars])[0]
-        with span("backend/msm", n=m):
+            return self._msm_chunks("backend/msm", points, [scalars],
+                                    basis)[0]
+        with span("backend/msm", n=m, basis=basis):
             with span("backend/msm/encode"):
                 pts = self._base_points(points, m)
                 sc16 = jnp.asarray(L16.u64limbs_to_u16limbs(scalars[:m]))
@@ -331,10 +340,11 @@ class TpuBackend(CpuBackend):
         self._mesh_base_cache[key] = (points, placed)
         return placed
 
-    def _msm_sharded(self, points, scalars, m: int, base_key=None):
+    def _msm_sharded(self, points, scalars, m: int, base_key=None,
+                     basis="powers"):
         """One MSM sharded over the ShardingPlan's ("data", "win") mesh.
         Points are padded with infinity (zero scalars) so the data axis
-        divides evenly.
+        divides evenly. Every window runs, whatever the scalars hold.
 
         GLV modes ride the mesh too: the host scalar-prep stage (Babai
         decomposition) runs per call, but the endomorphism-expanded base
@@ -366,7 +376,7 @@ class TpuBackend(CpuBackend):
                 proj = np.asarray(res)
             return ec.decode_points(proj[None], call=call)[0]
 
-        with span(call, n=m):
+        with span(call, n=m, basis=basis):
             with span(call + "/encode"):
                 sc16 = L16.u64limbs_to_u16limbs(scalars[:m])
                 nbits, signed = 254, False
@@ -428,20 +438,22 @@ class TpuBackend(CpuBackend):
                                      signed=signed, neg=ngd, plan=plan)
             return read(res)
 
-    def msm_many(self, points, scalars_list, base_key=None):
-        """Commit several scalar vectors against one cached device base.
+    def msm_many(self, points, scalars_list, base_key=None, basis="powers"):
+        """Commit several scalar vectors against one cached device base
+        (`basis`: as in `msm`).
 
         With >1 local device the batch axis is sharded over a 1-D mesh
         (SURVEY §2c(b): inter-proof/column DP). On one device, in the
         default mode, the columns go through `_msm_chunks`, 16 to a device
         run and a read (PERF.md section 5 has the chip's readings of it
-        against a loop of `msm`); the other modes, which have not run on
-        the chip, loop `msm`. GLV modes thread the scalar-prep stage
-        through the DP path: half-scalars and sign masks are stacked per
-        batch row against ONE replicated endomorphism-expanded base
-        (`fixed` uses the glv+signed kernels here — replicating a
-        per-window table across the mesh would multiply its memory by the
-        device count)."""
+        against a loop of `msm`), each with the windows its scalars need;
+        the other modes, which have not run on the chip, loop `msm`, and
+        they and the mesh run every window. GLV modes thread the
+        scalar-prep stage through the DP path: half-scalars and sign masks
+        are stacked per batch row against ONE replicated
+        endomorphism-expanded base (`fixed` uses the glv+signed kernels
+        here — replicating a per-window table across the mesh would
+        multiply its memory by the device count)."""
         import jax
         import jax.numpy as jnp
 
@@ -461,7 +473,7 @@ class TpuBackend(CpuBackend):
             mmax = min(points.shape[0],
                        max(s.shape[0] for s in scalars_list))
             mode = MSM.msm_mode()
-            with span(call, batch=batch, n=mmax):
+            with span(call, batch=batch, n=mmax, basis=basis):
                 with span(call + "/encode"):
                     pts = self._base_points(points, mmax)
                     if mode == "vanilla":
@@ -497,8 +509,9 @@ class TpuBackend(CpuBackend):
                     proj = np.asarray(res)
                 return list(ec.decode_points(proj, call=call))
         if self._one_chip_default():
-            return self._msm_chunks("backend/msm_many", points, scalars_list)
-        return [self.msm(points, s, base_key=base_key)
+            return self._msm_chunks("backend/msm_many", points, scalars_list,
+                                    basis)
+        return [self.msm(points, s, base_key=base_key, basis=basis)
                 for s in scalars_list]
 
     @staticmethod
@@ -509,7 +522,8 @@ class TpuBackend(CpuBackend):
         from ..parallel.plan import current_plan
         return current_plan().n_devices == 1 and MSM.msm_mode() == "vanilla"
 
-    def _msm_chunks(self, call: str, points, scalars_list) -> list:
+    def _msm_chunks(self, call: str, points, scalars_list,
+                    basis: str = "powers") -> list:
         """Commit `scalars_list` against one resident base, MSM.CHUNK_WIDTH
         columns a device run: each column's window phase (`msm_windows`,
         the one window-phase program of its n), then for the whole run the
@@ -520,11 +534,22 @@ class TpuBackend(CpuBackend):
         chains of dependent steps that cost less at width 16 than at width
         1 (PERF.md section 5 has the readings).
 
+        A column's window phase runs the windows its largest scalar
+        reaches and no more (`MSM.windows_needed`, read here from the host
+        column; the count goes to the program as data, so every width is
+        the one program and a run may mix them): 1 of 32 at c = 8 for a
+        column of bits committed as values against the Lagrange base, all
+        of them for coefficients, whose scalars are full field elements.
+        Nothing is declared and nothing can be exceeded: the count is the
+        column's own.
+
         ONE width: a run of fewer columns is filled with identity window
         sums, whose points are read with the rest and dropped; a longer
         list is split; a shorter scalar vector is zero-extended. One span
-        named `call` a run, with `batch` (its columns), `width` and the
-        window `c` its columns were committed with on it."""
+        named `call` a run, with `batch` (its columns), `width`, the
+        window `c` its columns were committed with, `active` (the windows
+        they ran, summed) and `basis` (what the base is: "powers" or
+        "lagrange") on it."""
         import jax.numpy as jnp
 
         from ..ops import ec, limbs as L16, msm as MSM
@@ -535,19 +560,23 @@ class TpuBackend(CpuBackend):
         out = []
         for at in range(0, len(scalars_list), width):
             chunk = scalars_list[at:at + width]
-            with span(call, n=n, batch=len(chunk), width=width, c=c):
+            with span(call, n=n, batch=len(chunk), width=width, c=c,
+                      basis=basis):
                 with span(call + "/encode"):
                     pts = self._base_points(points, n)
-                    cols, sent = [], 0
+                    cols, active, sent = [], [], 0
                     for col in chunk:
+                        active.append(MSM.windows_needed(col[:n], c))
                         sc = L16.u64limbs_to_u16limbs(col[:n])
                         if sc.shape[0] < n:
                             sc = np.pad(sc, ((0, n - sc.shape[0]), (0, 0)))
                         cols.append(jnp.asarray(sc))
                         sent += sc.nbytes
                     annotate(bytes=sent)
+                annotate(active=int(sum(active)))
                 with span(call + "/dispatch"):
-                    wins = tuple(MSM.msm_windows(pts, sc, c) for sc in cols)
+                    wins = tuple(MSM.msm_windows(pts, sc, c, a)
+                                 for sc, a in zip(cols, active))
                     affine = ec.affine_points(MSM.combine_windows_batch(
                         MSM.pad_window_sums(wins, width), c))
                 out += ec.read_points(affine, call, keep=len(chunk))

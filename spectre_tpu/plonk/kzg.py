@@ -27,7 +27,9 @@ R = bn254.R
 def commit(srs: SRS, coeffs: np.ndarray, bk=None):
     """Commit to coefficient-form poly: MSM over tau powers. The SRS digest
     rides along as the fixed-base table key (SPECTRE_MSM_MODE=fixed reuses
-    one precomputed window table per SRS across every commitment)."""
+    one precomputed window table per SRS across every commitment). A
+    coefficient is a full-width scalar whatever the column held: what the
+    prover has as values it commits with `commit_lagrange_many`."""
     bk = bk or B.get_backend()
     assert coeffs.shape[0] <= srs.n, "poly larger than SRS"
     return bk.msm(srs.g1_powers, coeffs, base_key=srs.digest())
@@ -42,10 +44,47 @@ def commit_many(srs: SRS, coeffs_list: list, bk=None) -> list:
     return bk.msm_many(srs.g1_powers, coeffs_list, base_key=srs.digest())
 
 
-def commit_lagrange(srs: SRS, domain: Domain, evals: np.ndarray, bk=None):
-    """Commit to lagrange-form poly (iNTT then power-basis MSM)."""
+def commit_lagrange(srs: SRS, evals: np.ndarray, bk=None, usable=None):
+    """`commit_lagrange_many` of one column."""
+    return commit_lagrange_many(srs, [evals], bk, usable)[0]
+
+
+def commit_lagrange_many(srs: SRS, evals_list: list, bk=None,
+                         usable: int | None = None) -> list:
+    """Commit to polynomials given by their VALUES on the domain ([n, 4]
+    each, n the domain's size): one MSM of the values against the Lagrange
+    base L_i(tau) G (halo2's `commit_lagrange`), the point the coefficients
+    commit to against the powers, with no transform on the way. The scalars
+    are then the cells themselves, and the one-device backend runs only the
+    windows a column's largest cell reaches (`TpuBackend._msm_chunks`): one
+    for a column of bits where a coefficient pays all of them.
+
+    usable: the rows from `usable` on are blinding rows, full-width scalars
+    in every column. They are kept from the backend (zeroed in what it gets,
+    so a column of bits stays one) and committed here, n - usable points a
+    column in the native host library while the backend's call runs, then
+    added to its point."""
     bk = bk or B.get_backend()
-    return commit(srs, domain.lagrange_to_coeff(evals, bk), bk)
+    n = evals_list[0].shape[0]
+    assert all(e.shape[0] == n for e in evals_list) and n <= srs.n \
+        and n & (n - 1) == 0, "values are a whole domain's"
+    srs = srs.truncate(n.bit_length() - 1)
+    base, key = srs.g1_lagrange, srs.lagrange_digest()
+    if usable is None or usable >= n:
+        return bk.msm_many(base, evals_list, base_key=key, basis="lagrange")
+    heads = []
+    for e in evals_list:
+        head = e.copy()
+        head[usable:] = 0
+        heads.append(head)
+    from concurrent.futures import ThreadPoolExecutor
+    with ThreadPoolExecutor(max_workers=1) as ex:
+        tails = ex.submit(lambda: [host.g1_msm(base[usable:], e[usable:])
+                                   for e in evals_list])
+        points = bk.msm_many(base, heads, base_key=key, basis="lagrange")
+    summed = host.g1_add_affine_batch(host.points_to_limbs(points),
+                                      host.points_to_limbs(tails.result()))
+    return host.limbs_to_points(summed)
 
 
 @dataclass

@@ -133,18 +133,9 @@ def prove(pk: ProvingKey, srs: SRS, assignment: Assignment,
     polys: dict = {}      # key -> coefficient form
     values: dict = {}     # key -> int list (lagrange values)
 
-    def commit_col(key, vals, arr=None):
-        values[key] = vals
-        if arr is None:
-            arr = B.to_arr(vals)
-        coeffs = dom.lagrange_to_coeff(arr, bk)
-        polys[key] = coeffs
-        pt = kzg.commit(srs, coeffs, bk)
-        tr.write_point(pt)
-
     COMMIT_CHUNK = 16   # bounds resident coefficient arrays (k=20: 512MB)
 
-    def commit_cols_batched(item_list):
+    def commit_cols_batched(item_list, as_values=False):
         """Pipelined + batched commits (SURVEY §2c axes (b)+(c)): host limb
         marshalling of the NEXT chunk overlaps the backend NTT+MSM of the
         current one on worker threads (ctypes/JAX release the GIL), each
@@ -152,7 +143,15 @@ def prove(pk: ProvingKey, srs: SRS, assignment: Assignment,
         (ISSUE 4: a single [B, n, 16] device kernel instead of B per-column
         dispatches), and each chunk's MSMs go through one `commit_many`
         call (device base cached; batch axis sharded on a mesh). Transcript
-        order is unchanged — points are absorbed strictly in sequence."""
+        order is unchanged — points are absorbed strictly in sequence.
+
+        as_values: the chunk's VALUES are committed against the Lagrange
+        base (`kzg.commit_lagrange_many`, the blinding rows from `u` on
+        apart), ahead of the transform, which then only feeds `polys`: the
+        same points, and a column of bits pays one window of the MSM where
+        its coefficients pay all of them. For the witness's own columns,
+        whose cells are mostly narrow; a grand product's are full width
+        and stay on the coefficient path."""
         from concurrent.futures import ThreadPoolExecutor
 
         if not item_list:
@@ -169,11 +168,15 @@ def prove(pk: ProvingKey, srs: SRS, assignment: Assignment,
                 with span("commit/marshal"):
                     arrs = [futs.pop(base + off).result()
                             for off in range(len(chunk))]
+                if as_values:
+                    points = kzg.commit_lagrange_many(srs, arrs, bk, usable=u)
                 coeffs = dom.lagrange_to_coeff_many(arrs, bk)
                 for (key, vals), c in zip(chunk, coeffs):
                     values[key] = vals
                     polys[key] = c
-                for pt in kzg.commit_many(srs, coeffs, bk):
+                if not as_values:
+                    points = kzg.commit_many(srs, coeffs, bk)
+                for pt in points:
                     tr.write_point(pt)
 
     with phase("prove/commit_advice"):
@@ -181,7 +184,7 @@ def prove(pk: ProvingKey, srs: SRS, assignment: Assignment,
                  + [(("ladv", j), v) for j, v in enumerate(ladv_vals)]
                  + [(("shb", j), v) for j, v in enumerate(shb_vals)]
                  + [(("shw", j), v) for j, v in enumerate(shw_vals)])
-        commit_cols_batched(items)
+        commit_cols_batched(items, as_values=True)
 
     # --- 2. lookup permuted columns ---
     with phase("prove/lookup_permute"):
@@ -190,7 +193,7 @@ def prove(pk: ProvingKey, srs: SRS, assignment: Assignment,
             pa, pt_col = permute_lookup(cfg, ladv_vals[j], pk.table_values[j])
             lk_items.append(((("pA", j)), pa))
             lk_items.append(((("pT", j)), pt_col))
-        commit_cols_batched(lk_items)
+        commit_cols_batched(lk_items, as_values=True)
 
     beta = tr.challenge()
     gamma = tr.challenge()

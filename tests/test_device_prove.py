@@ -56,6 +56,75 @@ class TestDefaultMsm:
         assert kernel_programs()["vanilla"] - programs_before <= 1
 
 
+class TestActiveWindows:
+    """ISSUE 38: `msm_windows` runs the low `active` windows, a count that
+    is DATA (`np.int32`), so every case here is the one program above, the
+    served one. A column whose largest scalar is under, or AT, the bound
+    2^(c * active) - 1 gives the host curve's point; `windows_needed` reads
+    that count off the host column."""
+
+    C = MSM_WINDOWS["vanilla"]
+    FULL = -(-254 // MSM_WINDOWS["vanilla"])
+
+    @pytest.fixture(scope="class", autouse=True)
+    def programs_before(self):
+        return kernel_programs()["vanilla"]
+
+    def _check(self, scalars, active):
+        import jax.numpy as jnp
+
+        from spectre_tpu.ops import ec, limbs as L16, msm as MSM
+
+        pts = list(msm_case("random")[0])
+        want = bn.g1_curve.msm(pts, scalars)
+        want = None if want is None else (int(want[0]), int(want[1]))
+        assert MSM.windows_needed(B.to_arr(scalars), self.C) == active
+        wins = MSM.msm_windows(
+            ec.encode_points(pts), jnp.asarray(L16.ints_to_limbs16(scalars)),
+            self.C, np.int32(active))
+        assert wins.shape[0] == self.FULL
+        # the windows that did not run are the identity (z = 0)
+        assert not np.asarray(wins[active:, 2]).any()
+        assert ec.decode_points(MSM.combine_windows(wins, self.C)[None])[0] \
+            == want
+        return wins
+
+    @pytest.mark.parametrize("active,where", [
+        (0, "under"), (1, "under"), (1, "at"), (2, "under"), (2, "at"),
+        (FULL, "under"), (FULL, "at")])
+    def test_matches_oracle(self, active, where):
+        import random
+        rng = random.Random(38 + active)
+        top = min(1 << (self.C * active), bn.R) - 1     # the bound itself
+        low = 1 << (self.C * (active - 1)) if active else 0
+        scalars = [rng.randrange(low, top + 1) if top else 0
+                   for _ in range(MSM_N)]
+        scalars[:2] = [0, low]
+        if where == "at":
+            scalars[5] = top
+        self._check(scalars, active)
+
+    def test_all_windows_bit_equal_to_the_static_loop(self):
+        """The full-width call against the form every window ran in until
+        ISSUE 38 (`active` None: a counted loop over all of them, what the
+        mesh kernels still trace inside their own programs): the same
+        limbs, not just the same point. The one other program of this
+        (n, c) in tier-1: some tens of seconds with a cold compile cache."""
+        import jax.numpy as jnp
+
+        from spectre_tpu.ops import msm as MSM
+
+        pp, sc = encode_msm(*msm_case("random"))
+        assert jnp.array_equal(
+            MSM.msm_windows(pp, sc, self.C),
+            MSM.msm_windows(pp, sc, self.C, np.int32(self.FULL)))
+
+    def test_one_program_for_every_count(self, programs_before):
+        # the counts above and the default MSM's share one (compiled here
+        # where this class runs alone); the static loop's is the other
+        assert kernel_programs()["vanilla"] - programs_before <= 2
+
+
 class TestChunkCombine:
     def test_matches_single(self):
         """The one-chip commit path's shape: a window phase a column, then
@@ -65,7 +134,8 @@ class TestChunkCombine:
 
         m, width, c = 3, 8, MSM_WINDOWS["vanilla"]
         operands = [encode_msm(*msm_case("random")) for _ in range(m)]
-        wins = tuple(MSM.msm_windows(pp, sc, c) for pp, sc in operands)
+        full = np.int32(MSM.window_count(254, c))
+        wins = tuple(MSM.msm_windows(pp, sc, c, full) for pp, sc in operands)
         want = [ec.decode_points(MSM.combine_windows(w, c)[None])[0]
                 for w in wins]
         padded = MSM.pad_window_sums(wins, width)
@@ -214,6 +284,11 @@ class TestDeviceBoundarySpans:
             # in the manifest's sums
             assert call.meta["c"] == MSM_WINDOWS["vanilla"]
             assert got["msm_window"] == [call.meta["c"]]
+            # the windows its columns ran: 1..n are 7 bits wide, two
+            # windows of 4 a column; and what the base was said to be
+            assert call.meta["active"] == 2 * batch \
+                == got["msm_window_passes"]
+            assert call.meta["basis"] == "powers"
             assert moved == {"h2d": 64 * n * batch,
                              "d2h": MSM_WIDTH * 3 * 64}
             assert got["msm_columns"] == {"real": batch,
@@ -221,7 +296,8 @@ class TestDeviceBoundarySpans:
         elif op == "msm":
             assert moved == {"h2d": 64 * n, "d2h": 3 * 64}
             assert got["msm_columns"] == {"real": 0, "padded": 0}
-            assert got["msm_window"] == []
+            assert got["msm_window"] == [] and got["msm_window_passes"] == 0
+            assert call.meta["basis"] == "powers"
         elif op == "msm_many":
             assert call.meta["batch"] == 2 and moved["d2h"] == 2 * 6 * 64
         else:
@@ -311,12 +387,30 @@ class TestDeviceBoundarySpans:
         assert got["msm_window"] == man["msm_window"] \
             == [MSM_WINDOWS["vanilla"]]
         real = 0
+        runs_seen = []
         for s in _walk(t.trace.root):
             if s.name in ("backend/msm", "backend/msm_many"):
                 assert s.meta["width"] == MSM_WIDTH
                 assert 1 <= s.meta["batch"] <= MSM_WIDTH
                 real += s.meta["batch"]
+                runs_seen.append((s.meta["basis"], s.meta["batch"],
+                                  s.meta["active"]))
         assert real == commits
+        # ISSUE 38: the witness's columns are committed as VALUES against
+        # the Lagrange base with the windows their cells reach (the tiny
+        # circuit's advice holds 28 at most, two windows of 4; its lookup
+        # advice, the permuted column and the table stay under 16, one),
+        # everything after them as coefficients with all of them; the
+        # blinding rows never reach the device
+        full = -(-254 // MSM_WINDOWS["vanilla"])
+        assert runs_seen == [
+            ("lagrange", 2, 2 + 1), ("lagrange", 2, 1 + 1),
+            ("powers", cfg.num_perm_chunks + cfg.num_lookup_advice,
+             full * (cfg.num_perm_chunks + cfg.num_lookup_advice)),
+            ("powers", NUM_H_CHUNKS, full * NUM_H_CHUNKS),
+            ("powers", 1, full), ("powers", 1, full)]
+        assert got["msm_window_passes"] == man["msm_window_passes"] \
+            == sum(r[2] for r in runs_seen) < full * commits
         for op in ("msm", "msm_many"):
             c = counts[f"backend/{op}"]
             assert [counts[f"backend/{op}/{w}"] for w in CHUNK_MSM_STAGES] \
@@ -525,7 +619,8 @@ class TestOneChipBatchedCommit:
         calls = []
         monkeypatch.setattr(
             one_chip, "msm",
-            lambda points, sc, base_key=None: calls.append(sc) or len(calls))
+            lambda points, sc, base_key=None, basis="powers":
+            calls.append(sc) or len(calls))
         with tracing.trace("not-batched") as tr:
             assert one_chip.msm_many(base, columns[:2]) == [1, 2]
         assert [c is col for c, col in zip(calls, columns)] == [True, True]

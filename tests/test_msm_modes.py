@@ -405,11 +405,12 @@ class TestMsmTableBudgetDegrade:
 WINDOW_PADD_SITES = 28
 
 
-def _padd_call_sites(monkeypatch, n: int, c: int) -> list:
+def _padd_call_sites(monkeypatch, n: int, c: int, counted=False) -> list:
     """The shapes `ec.padd` is traced with, call site by call site, in
     `msm_windows`' program at (n, c): a loop's body is traced once, so this
     is what the program holds to lower, not what it runs. Traced only, on
-    shapes: nothing is compiled."""
+    shapes: nothing is compiled. `counted`: the served form, whose count of
+    windows to run is an argument (ISSUE 38); else the static loop."""
     sites = []
     padd = ec.padd
 
@@ -420,7 +421,8 @@ def _padd_call_sites(monkeypatch, n: int, c: int) -> list:
     monkeypatch.setattr(ec, "padd", counting)
     jax.make_jaxpr(MSM.msm_windows.__wrapped__, static_argnums=2)(
         jax.ShapeDtypeStruct((n, 3, 16), jnp.uint32),
-        jax.ShapeDtypeStruct((n, 16), jnp.uint32), c)
+        jax.ShapeDtypeStruct((n, 16), jnp.uint32), c,
+        *([jax.ShapeDtypeStruct((), jnp.int32)] if counted else []))
     return sites
 
 
@@ -453,10 +455,13 @@ class TestWindowProgramSize:
 
     N = 1 << 14
 
-    def test_padd_call_sites_of_the_served_program(self, monkeypatch):
+    @pytest.mark.parametrize("counted", [True, False],
+                             ids=["served", "static"])
+    def test_padd_call_sites_of_the_served_program(self, monkeypatch,
+                                                   counted):
         monkeypatch.delenv("SPECTRE_MSM_WINDOW", raising=False)
         c = MSM.default_window(self.N)
-        sites = _padd_call_sites(monkeypatch, self.N, c)
+        sites = _padd_call_sites(monkeypatch, self.N, c, counted)
         nwin = (254 + c - 1) // c
         # the segmented halving's 14 levels, the emission tree over 15
         # levels' slots (7, 4, 2, 1), then the aggregate, at nwin windows
@@ -471,6 +476,23 @@ class TestWindowProgramSize:
         pt = jax.ShapeDtypeStruct((8, 3, 16), jnp.uint32)
         closed = jax.make_jaxpr(lambda p, q: ec.padd(p, q))(pt, pt)
         assert _count_scans(closed.jaxpr) == PADD_SCANS
+
+
+class TestWindowsNeeded:
+    """`MSM.windows_needed`: the windows of c bits the largest scalar of a
+    host column reaches (numpy only)."""
+
+    @pytest.mark.parametrize("top,c,want", [
+        (0, 8, 0), (1, 8, 1), (255, 8, 1), (256, 8, 2), ((1 << 32) - 1, 8, 4),
+        (1 << 32, 8, 5), ((1 << 64) - 1, 8, 8), (1 << 64, 8, 9),
+        ((1 << 128) - 1, 10, 13), (bn.R - 1, 8, 32), (bn.R - 1, 10, 26),
+        (bn.R - 1, 4, 64), (15, 4, 1), (16, 4, 2)])
+    def test_counts(self, top, c, want):
+        from spectre_tpu.native import host
+        col = host.ints_to_limbs([top >> 3, top, 0, top >> 1])
+        got = MSM.windows_needed(col, c)
+        assert got == want and got.dtype == np.int32
+        assert want == MSM.window_count(top.bit_length(), c)
 
 
 class TestKernelShapesPinned:
