@@ -1001,6 +1001,8 @@ def lint_limbs_host() -> list:
     """KL-WIDTH probe for the host-side limb converters (numpy, untraceable):
     drive them with extreme inputs and check the declared 16-bit invariant
     plus exact round-trips. A widened limb or dropped mask shows up here."""
+    import jax.numpy as jnp
+
     from ..fields import bn254
     from ..ops import limbs as L
 
@@ -1019,6 +1021,11 @@ def lint_limbs_host() -> list:
     if not np.array_equal(L.u16limbs_to_u64limbs(u16), ones64):
         bad("u64-roundtrip", "u64<->u16 limb round-trip loses bits at the "
             "all-ones extreme")
+    # the wire format's device half has to give the host split's limbs
+    if not np.array_equal(np.asarray(L.split_limbs16(
+            jnp.asarray(L.pack_u64limbs(ones64)))), u16):
+        bad("split-device", "split_limbs16 of the packed rows differs from "
+            "u64limbs_to_u16limbs at the all-ones extreme")
     vals = [0, 1, bn254.R - 1, 2**256 - 1]
     limbs = L.ints_to_limbs16(vals)
     if int(limbs.max()) > L.LIMB_MASK:

@@ -1,12 +1,26 @@
-"""Host-side conversion between Python ints and 16-bit limb tensors.
+"""Conversion between Python ints, the native lib's 4x64 limbs and the
+device's 16-bit limb tensors.
 
 Device convention: a 256-bit value is [..., 16] uint32, little-endian 16-bit
-limbs (each entry < 2^16). This is the wire format between the host (Python
-ints / the native C++ lib's 4x64 limbs) and device kernels.
+limbs (each entry < 2^16); every kernel computes on that form.
+
+Wire format, host to device, of the quotient's columns and the NTT kinds'
+inputs: the [..., 4] uint64 rows the native C++ lib holds, viewed as
+[..., 8] uint32 (`pack_u64limbs`: no copy, 32 bytes a field element, x64
+stays off) and unpadded; the device splits each word into its two 16-bit
+limbs and appends the zero rows a longer domain needs (`split_limbs16`, a
+small program of its own). The MSM scalars and everything that comes DOWN
+still cross as [..., 16] uint32, 64 bytes a field element, split and joined
+on the host (`u64limbs_to_u16limbs`, `u16limbs_to_u64limbs`).
 """
 
 from __future__ import annotations
 
+import functools
+import sys
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 NLIMBS = 16
@@ -57,4 +71,30 @@ def u16limbs_to_u64limbs(arr: np.ndarray) -> np.ndarray:
         for k in range(4):
             acc |= (arr[:, 4 * j + k] & np.uint64(0xFFFF)) << np.uint64(16 * k)
         out[:, j] = acc
+    return out
+
+
+def pack_u64limbs(arr: np.ndarray) -> np.ndarray:
+    """[..., 4] uint64 (native lib format) -> the same bytes as [..., 8]
+    uint32, a view where `arr` is contiguous: what `split_limbs16` takes."""
+    if sys.byteorder != "little":
+        raise NotImplementedError("the packed wire format is the host's "
+                                  "little-endian uint64 rows")
+    return np.ascontiguousarray(arr, dtype=np.uint64).view(np.uint32)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def split_limbs16(packed, n_out: int | None = None):
+    """Device half of the wire format: [..., n, 8] uint32 (`pack_u64limbs`)
+    -> [..., n_out or n, 16] uint32 16-bit limbs, limb for limb what
+    `u64limbs_to_u16limbs` gives for the rows zero-padded to `n_out`. One
+    small program a shape, `split_limbs16` in a device trace; elementwise
+    along every leading axis, so a batch-sharded stack stays where it is."""
+    lo = packed & jnp.uint32(LIMB_MASK)
+    hi = packed >> jnp.uint32(LIMB_BITS)
+    out = jnp.stack([lo, hi], axis=-1).reshape(packed.shape[:-1] + (NLIMBS,))
+    if n_out is not None:
+        pad = [(0, 0)] * out.ndim
+        pad[-2] = (0, n_out - out.shape[-2])
+        out = jnp.pad(out, pad)
     return out

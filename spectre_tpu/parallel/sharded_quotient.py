@@ -10,8 +10,10 @@ tree into one program blows up LLVM codegen — see quotient_device's design
 note). Layers:
 
   * LDE prefetch (`_lde_runner`): the chunked `coset_lde_std` batch is
-    sharded over the BATCH axis — each device runs the same fused
-    single-device `_fwd_kernel` body on its own columns (embarrassingly
+    sharded over the BATCH axis — the packed [B, n, 8] stack goes up
+    batch-sharded, each device splits its own columns' limbs and pads
+    their rows to 4n (`ops/limbs.py:split_limbs16`) and runs the same
+    fused single-device `_fwd_kernel` body on them (embarrassingly
     parallel, byte-identical by construction) — then ONE all_to_all
     resharding turns the batch-sharded [B, 4n, 16] stack into row-sharded
     [4n, 16] columns for the pointwise phase.
@@ -54,7 +56,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..fields import bn254
 from ..observability import compilelog
 from ..observability.tracing import span
-from ..ops import field_ops as F, ntt as NTT
+from ..ops import field_ops as F, limbs as L16, ntt as NTT
 from .plan import ShardingPlan
 
 R = bn254.R
@@ -454,21 +456,25 @@ class MeshQuotientEngine:
         d = self.plan.n_devices
         return max(d, (base // d) * d)
 
-    def lde(self, std16: np.ndarray):
-        """[B, m, 16] standard-form stack -> list of B row-sharded
-        Montgomery [m, 16] evaluations (pads the batch up to a device-count
-        multiple; duplicate tail columns are computed and dropped)."""
-        b = std16.shape[0]
+    def lde(self, packed: np.ndarray):
+        """[B, n, 8] packed standard-form stack (ops/limbs.py: the wire
+        format) -> list of B row-sharded Montgomery [m, 16] evaluations
+        (pads the batch up to a device-count multiple; duplicate tail
+        columns are computed and dropped). The stack goes up batch-sharded
+        as it is; each device splits the limbs of its own columns and pads
+        their rows to m (`split_limbs16`: nothing crosses the batch axis,
+        no collective) before the LDE program."""
+        b = packed.shape[0]
         d = self.plan.n_devices
         bp = max(d, ((b + d - 1) // d) * d)
         if bp != b:
-            std16 = np.concatenate(
-                [std16, np.repeat(std16[:1], bp - b, axis=0)], axis=0)
+            packed = np.concatenate(
+                [packed, np.repeat(packed[:1], bp - b, axis=0)], axis=0)
         run = _lde_runner(self.plan, bp, self._logm, self.dom.omega_ext,
                           self._g())
         sh = NamedSharding(self.plan.batch_mesh,
                            P(self.plan.batch_axis, None, None))
-        stack = jax.device_put(jnp.asarray(std16), sh)
+        stack = L16.split_limbs16(jax.device_put(packed, sh), self.m)
         with compilelog.entry_point("parallel.sharded_quotient.lde"):
             out = _fence(run(stack))
         return [out[i] for i in range(b)]

@@ -603,9 +603,9 @@ class TpuBackend(CpuBackend):
             return self._ntt_sharded(coeffs, omega)
         call = "backend/ntt"
         with span(call, n=coeffs.shape[0]):
-            std16 = _ship_std16(coeffs, call)
+            packed = _ship_packed(coeffs, call)
             with span(call + "/dispatch"):
-                out = NTT.ntt(_mont_fns()["to"](std16), omega)
+                out = NTT.ntt(_to_mont_packed(packed), omega)
             return _fetch_u64_std(out, call)
 
     def intt(self, evals, omega: int):
@@ -627,9 +627,9 @@ class TpuBackend(CpuBackend):
                     out = _helpers()["mul_s"](res, jnp.asarray(ninv))
                 return _fetch_u64_std(out, call)
         with span(call, n=evals.shape[0]):
-            std16 = _ship_std16(evals, call)
+            packed = _ship_packed(evals, call)
             with span(call + "/dispatch"):
-                out = NTT.intt(_mont_fns()["to"](std16), omega)
+                out = NTT.intt(_to_mont_packed(packed), omega)
             return _fetch_u64_std(out, call)
 
     def _ntt_sharded(self, arr_u64, omega: int, mont_out: bool = False):
@@ -641,9 +641,9 @@ class TpuBackend(CpuBackend):
         plan = current_plan()
         call = "backend/ntt_sharded"
         with span(call, n=arr_u64.shape[0]):
-            std16 = _ship_std16(arr_u64, call)
+            packed = _ship_packed(arr_u64, call)
             with span(call + "/dispatch"):
-                res = sharded_ntt(_mont_fns()["to"](std16), omega, plan.mesh,
+                res = sharded_ntt(_to_mont_packed(packed), omega, plan.mesh,
                                   plan=plan)
             if mont_out:
                 return res
@@ -671,10 +671,10 @@ class TpuBackend(CpuBackend):
         b, n = len(arrs), arrs[0].shape[0]
         call = "backend/intt_many" if inverse else "backend/ntt_many"
         with span(call, batch=b, n=n):
-            std16 = _ship_std16(
+            packed = _ship_packed(
                 lambda: self._pad_batch(np.stack(arrs)).reshape(-1, 4), call)
             with span(call + "/dispatch"):
-                mont = _mont_fns()["to"](std16).reshape(-1, n, 16)
+                mont = _to_mont_packed(packed).reshape(-1, n, 16)
                 fn = NTT.intt_many if inverse else NTT.ntt_many
                 out = fn(mont, omega)
             return _fetch_u64_std(
@@ -700,12 +700,14 @@ class TpuBackend(CpuBackend):
 
     def coset_lde_many(self, coeffs_list, omega: int, g: int, n_out: int,
                        powers=None) -> list:
-        """Batched FUSED coset-LDE: pad to n_out, then one compiled kernel
-        per stack — the std→mont conversion and the g^i coset scale both
-        fold into stage 0 of the batched NTT (ops/ntt.py:coset_lde_std),
-        so the whole extension is a single device program with no separate
-        scale pass and no intermediate Montgomery array."""
-        from ..ops import ntt as NTT
+        """Batched FUSED coset-LDE: the coefficients go up as they are, the
+        device splits their limbs and pads the rows to n_out, then one
+        compiled kernel per stack — the std→mont conversion and the g^i
+        coset scale both fold into stage 0 of the batched NTT
+        (ops/ntt.py:coset_lde_std), so the extension itself is a single
+        device program with no separate scale pass and no intermediate
+        Montgomery array."""
+        from ..ops import limbs as L16, ntt as NTT
 
         if not coeffs_list:
             return []
@@ -715,16 +717,12 @@ class TpuBackend(CpuBackend):
                                           powers=powers)
         b = len(coeffs_list)
         call = "backend/coset_lde_many"
-        def padded_stack():
-            stack = np.zeros((b, n_out, 4), dtype=np.uint64)
-            for i, cf in enumerate(coeffs_list):
-                stack[i, :cf.shape[0]] = cf
-            return self._pad_batch(stack)
-
         with span(call, batch=b, n=n_out):
-            std16 = _ship_std16(padded_stack, call)
+            packed = _ship_packed(
+                lambda: self._pad_batch(stack_rows(coeffs_list)), call)
             with span(call + "/dispatch"):
-                out = NTT.coset_lde_std(std16, omega, g)
+                out = NTT.coset_lde_std(L16.split_limbs16(packed, n_out),
+                                        omega, g)
             return _fetch_u64_std(
                 out, call, lambda std: list(std.reshape(-1, n_out, 4)[:b]))
 
@@ -764,10 +762,22 @@ def _mont_fns():
     return _mont_jits
 
 
-def _ship_std16(arr, call: str):
+def stack_rows(cols, n: int | None = None) -> np.ndarray:
+    """[<=n, 4] u64 columns -> one [B, n, 4] stack (n: the longest column
+    unless given); a shorter column's tail is zeroed, nothing else is."""
+    n = max(c.shape[0] for c in cols) if n is None else n
+    stack = np.empty((len(cols), n, 4), dtype=np.uint64)
+    for i, c in enumerate(cols):
+        stack[i, :c.shape[0]] = c
+        stack[i, c.shape[0]:] = 0
+    return stack
+
+
+def _ship_packed(arr, call: str):
     """Stage `encode` of `call`: [..., 4] u64 standard (or a function that
-    stacks and pads it first) -> device [..., 16] u32 standard limbs; the
-    caller's `dispatch` takes it from there."""
+    stacks it first) -> the same rows on the device, [..., 8] u32, 32 bytes
+    a field element (ops/limbs.py has the wire format). The caller's
+    `dispatch` splits the limbs as its first device step."""
     import jax.numpy as jnp
 
     from ..ops import limbs as L16
@@ -775,10 +785,17 @@ def _ship_std16(arr, call: str):
     with span(call + "/encode"):
         if callable(arr):
             arr = arr()
-        std16 = jnp.asarray(L16.u64limbs_to_u16limbs(
-            arr.reshape(-1, 4)).reshape(arr.shape[:-1] + (16,)))
-        annotate(bytes=std16.nbytes)
-    return std16
+        packed = jnp.asarray(L16.pack_u64limbs(arr))
+        annotate(bytes=packed.nbytes)
+    return packed
+
+
+def _to_mont_packed(packed):
+    """Packed standard rows -> [..., 16] u32 Montgomery limbs: the limb
+    split, then the `to_mont_fr` every NTT kind has always run."""
+    from ..ops import limbs as L16
+
+    return _mont_fns()["to"](L16.split_limbs16(packed))
 
 
 def _fetch_u64_std(out, call: str, unstack=None):

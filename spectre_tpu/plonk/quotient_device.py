@@ -35,8 +35,11 @@ the device win lives (each op is HBM-bandwidth-bound either way), and
 compile cost stays bounded per primitive shape.
 
 Spans (observability/tracing.py): `quotient/extend` holds the coefficient
-fetch, the packing (`quotient/extend/encode`, with the bytes the engine's
-`lde` then ships) and the LDE dispatches (`quotient/extend/dispatch`);
+fetch, the stacking (`quotient/extend/encode`: a chunk's columns as their n
+rows of 32 bytes, with the bytes the engine's `lde` then ships; the limb
+split and the zero rows from n to 4n are the engine's first device step,
+`ops/limbs.py:split_limbs16`) and the LDE dispatches
+(`quotient/extend/dispatch`);
 `quotient/expressions` the expression tree's dispatches; `quotient/wait`
 the one blocking read, in the engine's `inverse_std`; `quotient/decode` the
 limb join. The device is busy with this queue from the first
@@ -226,17 +229,19 @@ class _LocalEngine:
     def chunk(self, base: int) -> int:
         return base
 
-    def lde(self, std16: np.ndarray):
-        """Batched fused coset-LDE of a [B, m, 16] standard-form stack: ONE
-        compiled kernel (std→mont + g^i scale fused into stage 0;
-        SPECTRE_NTT_MODE selects radix2/fourstep)."""
+    def lde(self, packed: np.ndarray):
+        """Batched fused coset-LDE of a [B, n, 8] packed standard-form
+        stack (ops/limbs.py: the wire format): the device splits the limbs
+        and pads the rows to m, then ONE compiled kernel (std→mont + g^i
+        scale fused into stage 0; SPECTRE_NTT_MODE selects
+        radix2/fourstep)."""
         import jax.numpy as jnp
 
-        from ..ops import ntt as NTT
+        from ..ops import limbs as L16, ntt as NTT
 
-        out = NTT.coset_lde_std(jnp.asarray(std16), self.dom.omega_ext,
-                                COSET_GEN)
-        return [out[i] for i in range(std16.shape[0])]
+        std16 = L16.split_limbs16(jnp.asarray(packed), self.m)
+        out = NTT.coset_lde_std(std16, self.dom.omega_ext, COSET_GEN)
+        return [out[i] for i in range(packed.shape[0])]
 
     def device_col(self, arr16):
         return arr16
@@ -330,8 +335,13 @@ def _quotient_impl(cfg: CircuitConfig, dom: Domain, fetch_coeffs,
 
     h = _helpers()
     to_mont16 = h["to_mont"]
-    mont_of = lambda ints: to_mont16(
-        jnp.asarray(L16.u64limbs_to_u16limbs(B.to_arr(ints))))
+
+    def mont_of_rows(arr_u64):
+        # up as the packed rows, like the columns: the device splits them
+        return to_mont16(L16.split_limbs16(
+            jnp.asarray(L16.pack_u64limbs(arr_u64))))
+
+    mont_of = lambda ints: mont_of_rows(B.to_arr(ints))
 
     def mont_scalar(s):
         v = int(s) % R
@@ -368,17 +378,12 @@ def _quotient_impl(cfg: CircuitConfig, dom: Domain, fetch_coeffs,
         _static_cache[ck] = st
 
     def ext_of_many(arrs_u64):
-        """Pack a coefficient-array list into ONE standard-form [B, m, 16]
-        stack and extend it through the engine's batched LDE."""
-        b = len(arrs_u64)
-        with span("quotient/extend/encode", bytes=b * m * 64):
-            stack = np.zeros((b, m, 4), dtype=np.uint64)
-            for i, cf in enumerate(arrs_u64):
-                stack[i, :cf.shape[0]] = cf
-            std16 = L16.u64limbs_to_u16limbs(stack.reshape(-1, 4)).reshape(
-                b, m, 16)
+        """Stack a coefficient-array list as ONE packed standard-form
+        [B, n, 8] array and extend it through the engine's batched LDE."""
+        with span("quotient/extend/encode", bytes=len(arrs_u64) * n * 32):
+            packed = L16.pack_u64limbs(B.stack_rows(arrs_u64, n))
         with span("quotient/extend/dispatch"):
-            return engine.lde(std16)
+            return engine.lde(packed)
 
     def ext_of_coeffs(arr_u64):
         return ext_of_many([arr_u64])[0]
@@ -430,8 +435,8 @@ def _quotient_impl(cfg: CircuitConfig, dom: Domain, fetch_coeffs,
     else:
         vinv = st.get("vinv")
         if vinv is None:
-            vinv = st["vinv"] = to_mont16(jnp.asarray(
-                L16.u64limbs_to_u16limbs(dom.vanishing_inv_on_extended())))
+            vinv = st["vinv"] = mont_of_rows(
+                dom.vanishing_inv_on_extended())
         hacc = ctx.mul(acc, engine.device_col(vinv))
         std = engine.inverse_std(hacc, None)
     with span("quotient/decode"):

@@ -128,6 +128,20 @@ class TestOneChip:
                  dom.omega_ext, ("std", COSET_GEN), mode,
                  NTT._resolve_kernel(None, mode))
 
+    def test_packed_columns_split_and_padded_k14(self, one_chip):
+        """ISSUE 39: a chunk of the quotient's columns arrives as [B, n, 8]
+        packed rows; the device splits the limbs and pads to the extended
+        domain, the shape `test_batched_coset_lde_k14`'s program takes."""
+        from spectre_tpu.ops import limbs as L16
+        from spectre_tpu.plonk.domain import Domain
+        from spectre_tpu.plonk.quotient_device import _ext_chunk
+        dom = Domain(14)
+        b = _ext_chunk(dom.n_ext)
+        compiled = _compile(L16.split_limbs16,
+                            _shape((b, dom.n, 8), one_chip), dom.n_ext)
+        (out,) = jax.tree.leaves(compiled.out_info)
+        assert out.shape == (b, dom.n_ext, 16) and out.dtype == jnp.uint32
+
     def test_quotient_fold_runner_k14(self, one_chip):
         from spectre_tpu.plonk import quotient_device as QD
         from spectre_tpu.plonk.domain import Domain
@@ -151,6 +165,21 @@ class TestFourChipMesh:
             SN._ntt_runner(mesh_plan, "data", logn, Domain(logn).omega),
             _shape((rr, cc, 16), sh), _shape((rr, cc, 16), sh))
         assert "all-to-all" in compiled.as_text()
+
+    def test_packed_columns_split_where_they_lie_k14(self, mesh_plan):
+        """The mesh engine's stack goes up batch-sharded and packed; the
+        split and the padding cross no device boundary."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from spectre_tpu.ops import limbs as L16
+        from spectre_tpu.plonk.domain import Domain
+        dom = Domain(14)
+        sh = NamedSharding(mesh_plan.batch_mesh, P("batch", None, None))
+        hlo = _compile(L16.split_limbs16, _shape((8, dom.n, 8), sh),
+                       dom.n_ext).as_text()
+        for word in ("all-gather", "all-to-all", "all-reduce",
+                     "collective-permute"):
+            assert word not in hlo, word
 
     def test_sharded_quotient_roll_k14(self, mesh_plan):
         """Rotation of a row-sharded extended column: the ppermute halo

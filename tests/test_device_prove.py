@@ -270,9 +270,11 @@ class TestDeviceBoundarySpans:
         assert [s.name for s in stages] == [f"backend/{op}/{w}" for w in want]
         _check_in_flight(call, stages)
         # what is shipped and what comes back, from the shapes: 64 bytes a
-        # field element as 16 u32 limbs; a batch of 3 is padded to 4; a
-        # run of the one-chip MSM path ships its columns and reads the
-        # affine points (x, y, z) of its whole width
+        # field element as 16 u32 limbs, 32 as the packed rows an NTT
+        # kind's input goes up as (its Montgomery limbs go up once more,
+        # for from_mont); a batch of 3 is padded to 4; a run of the
+        # one-chip MSM path ships its columns and reads the affine points
+        # (x, y, z) of its whole width
         rows = n * (4 if op == "intt_many" else 1)
         got = tracing.summary(tr)
         moved = got["transfer_bytes"]
@@ -301,7 +303,7 @@ class TestDeviceBoundarySpans:
         elif op == "msm_many":
             assert call.meta["batch"] == 2 and moved["d2h"] == 2 * 6 * 64
         else:
-            assert moved == {"h2d": 2 * 64 * rows, "d2h": 2 * 64 * rows}
+            assert moved == {"h2d": (32 + 64) * rows, "d2h": 2 * 64 * rows}
 
     def test_every_call_of_a_prove_has_its_stages(self, tiny_tpu_prove):
         calls = [s for s in _walk(tiny_tpu_prove.trace.root)
@@ -423,10 +425,14 @@ class TestDeviceBoundarySpans:
         assert counts["job/blind"] == counts["quotient/wait"] == 1
         assert counts["grand_products/perm_chunk"] == cfg.num_perm_chunks
         assert counts["grand_products/lookup"] == cfg.num_lookup_advice
-        # bytes: 64 a field element either way; an NTT kind ships its rows
-        # up twice and down twice; a batch is padded to a power of two;
-        # the quotient ships 3 synthetic rows, then whole chunks, and reads
-        # the extended domain once
+        # bytes: 64 a field element as limbs (the MSM scalars up, and
+        # everything down), 32 as the packed rows a column goes up as since
+        # ISSUE 39 (an NTT kind's first crossing, the quotient's stacks);
+        # an NTT kind ships its rows up twice (packed, then its Montgomery
+        # limbs back for from_mont) and down twice; a batch is padded to a
+        # power of two; the quotient ships 3 synthetic rows, then whole
+        # chunks, n rows a column (the device pads them to the extended
+        # domain), and reads the extended domain once
         n, m = cfg.n, t.pk.vk.domain.n_ext
         rows = n * (counts["backend/ntt"] + counts["backend/intt"])
         for s in _walk(t.trace.root):
@@ -434,7 +440,7 @@ class TestDeviceBoundarySpans:
                 rows += n * (1 << (s.meta["batch"] - 1).bit_length())
         lde_rows = 3 + _ext_chunk(m) * (counts["quotient/extend/encode"] - 1)
         assert moved == {
-            "h2d": 64 * (n * commits + 2 * rows + m * lde_rows),
+            "h2d": 64 * n * commits + (32 + 64) * rows + 32 * n * lde_rows,
             "d2h": 64 * (3 * MSM_WIDTH * runs + 2 * rows + m)}
 
     def test_span_meta_is_allocated_on_first_use(self):

@@ -37,6 +37,39 @@ class TestLimbs:
         assert L.limbs16_to_ints(u16) == vals
         assert np.array_equal(L.u16limbs_to_u64limbs(u16), u64)
 
+    # ISSUE 39: a column crosses as its rows of 32 bytes and the device
+    # splits the limbs and pads the rows; the host split of the host-padded
+    # stack is the oracle. ONE input shape ([2, 8, 8]) for every case
+    WIRE_ROWS = {
+        "random": lambda: rand_fr(16),
+        "zero": lambda: [0] * 16,
+        "r_minus_1": lambda: [bn.R - 1] * 16,
+        "all_ones_words": lambda: [2**256 - 1] * 16,
+        # a column shorter than n: zero-filled up to n by `stack_rows`
+        "short_column": lambda: rand_fr(8) + rand_fr(5),
+    }
+
+    @pytest.mark.parametrize("n_out", [None, 32], ids=["unpadded", "padded"])
+    @pytest.mark.parametrize("rows", sorted(WIRE_ROWS))
+    def test_device_split_equals_the_host_split(self, rows, n_out):
+        from spectre_tpu.native.host import ints_to_limbs
+        from spectre_tpu.plonk.backend import stack_rows
+        vals = self.WIRE_ROWS[rows]()
+        cols = [ints_to_limbs(vals[:8]), ints_to_limbs(vals[8:])]
+        stack = stack_rows(cols, 8)
+        assert stack.shape == (2, 8, 4)
+        assert not stack[1, len(vals) - 8:].any()
+        packed = L.pack_u64limbs(stack)
+        assert packed.shape == (2, 8, 8) and packed.dtype == np.uint32
+        assert np.shares_memory(packed, stack)
+        padded = np.zeros((2, n_out or 8, 4), dtype=np.uint64)
+        padded[:, :8] = stack
+        want = L.u64limbs_to_u16limbs(padded.reshape(-1, 4)).reshape(
+            2, n_out or 8, 16)
+        got = L.split_limbs16(jnp.asarray(packed), n_out)
+        assert got.dtype == jnp.uint32
+        assert np.array_equal(np.asarray(got), want)
+
 
 class TestFieldOps:
     def test_mul_add_sub_neg(self):

@@ -196,6 +196,35 @@ class TestShardedQuotientIdentity:
 
 
 @needs8
+def test_mesh_lde_splits_the_packed_stack_where_it_lies(monkeypatch):
+    """ISSUE 39: the mesh engine's stack goes up packed and batch-sharded;
+    each device splits its own columns' limbs and pads their rows, so the
+    split program holds no collective and its output has the stack's
+    sharding (what `_lde_runner` takes)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from spectre_tpu.ops import limbs as L16
+    from spectre_tpu.parallel.plan import current_plan
+
+    monkeypatch.setenv("SPECTRE_MESH_SHAPE", "4x2")
+    plan = current_plan()
+    sh = NamedSharding(plan.batch_mesh, P(plan.batch_axis, None, None))
+    rows = np.random.default_rng(39).integers(
+        0, 2**64, (8, 16, 4), dtype=np.uint64)
+    packed = jax.device_put(L16.pack_u64limbs(rows), sh)
+    hlo = L16.split_limbs16.lower(packed, 64).compile().as_text()
+    for word in ("all-gather", "all-to-all", "all-reduce",
+                 "collective-permute"):
+        assert word not in hlo, word
+    got = L16.split_limbs16(packed, 64)
+    assert got.sharding.is_equivalent_to(sh, 3)
+    want = np.zeros((8, 64, 16), dtype=np.uint32)
+    want[:, :16] = L16.u64limbs_to_u16limbs(
+        rows.reshape(-1, 4)).reshape(8, 16, 16)
+    assert np.array_equal(np.asarray(got), want)
+
+
+@needs8
 @run_slow
 class TestShardedQuotientK11:
     """The k=11 arm (n_ext = 2^13 — above the default size
