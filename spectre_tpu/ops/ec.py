@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..observability.tracing import span
+from ..observability.tracing import on_device, span
 from . import field_ops as F
 
 
@@ -89,6 +89,7 @@ def decode_points(arr, call: str = "ec/decode_points") -> list:
     conversion, then `read_points`' `wait` and `decode`."""
     with span(call + "/dispatch"):
         affine = affine_points(arr)
+        on_device(affine)
     return read_points(affine, call)
 
 

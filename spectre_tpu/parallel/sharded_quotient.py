@@ -55,7 +55,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..fields import bn254
 from ..observability import compilelog
-from ..observability.tracing import span
+from ..observability.tracing import on_device, span
 from ..ops import field_ops as F, limbs as L16, ntt as NTT
 from .plan import ShardingPlan
 
@@ -335,6 +335,7 @@ def _inv_apply(plan: ShardingPlan, acc, logm: int, omega: int, g,
     with span("quotient/inverse/dispatch"), \
             compilelog.entry_point("parallel.sharded_quotient.inverse"):
         out = run(a, scb, twb, outb)                 # [cc, rr, 16]
+        on_device(out)
     with span("quotient/inverse/wait", bytes=out.nbytes):
         out = np.asarray(out)
     return out.transpose(1, 0, 2).reshape(1 << logm, 16)

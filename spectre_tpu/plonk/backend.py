@@ -15,7 +15,7 @@ import numpy as np
 
 from ..fields import bn254
 from ..native import host
-from ..observability.tracing import annotate, span
+from ..observability.tracing import annotate, on_device, on_upload, span
 
 R = bn254.R
 
@@ -205,8 +205,10 @@ class TpuBackend(CpuBackend):
     call (`backend/msm`, `backend/ntt`, ...) and inside it the stages
     `encode`, `dispatch`, `wait`, `decode` (observability/tracing.py). A
     `wait` closes on the read that blocked anyway; no stage adds a
-    synchronisation. On one device a list of commits is one
-    `backend/msm_many` span a run of MSM.CHUNK_WIDTH columns
+    synchronisation; every `dispatch` ends in `on_device`, the device
+    timeline's mark of what it enqueued, and every `encode` that puts host
+    data on the device in `on_upload`. On one device a list of commits
+    is one `backend/msm_many` span a run of MSM.CHUNK_WIDTH columns
     (`_msm_chunks`), with `batch`, `width`, the window `c`, `active` (the
     windows its columns ran) and `basis` on it. The NTT kinds cross
     the boundary twice a call (the transform's result comes to the host
@@ -303,9 +305,11 @@ class TpuBackend(CpuBackend):
             with span("backend/msm/encode"):
                 pts = self._base_points(points, m)
                 sc16 = jnp.asarray(L16.u64limbs_to_u16limbs(scalars[:m]))
+                on_upload(sc16, "backend/msm")
                 annotate(bytes=sc16.nbytes)
             with span("backend/msm/dispatch"):
                 res = MSM.msm(pts, sc16, base_key=base_key)
+                on_device(res)
             return ec.decode_points(res[None], call="backend/msm")[0]
 
     def _mesh_base(self, points, m: int, plan, expand: bool):
@@ -410,6 +414,7 @@ class TpuBackend(CpuBackend):
                         ngd = plan.place(jnp.asarray(ng), plan.sign_spec)
                         res = SM.sharded_msm_fixed(tab, sd, ngd, c, plan,
                                                    nbits)
+                        on_device(res)
                     return read(res)
                 # per-device table slice over budget: glv+signed fallback
                 # below
@@ -436,6 +441,7 @@ class TpuBackend(CpuBackend):
                     if signed else None
                 res = SM.sharded_msm(base, sd, c, plan.mesh, nbits=nbits,
                                      signed=signed, neg=ngd, plan=plan)
+                on_device(res)
             return read(res)
 
     def msm_many(self, points, scalars_list, base_key=None, basis="powers"):
@@ -503,6 +509,7 @@ class TpuBackend(CpuBackend):
                     if kw:
                         pts = MSM._expand_endo(pts)
                     res = batch_msm_dp(pts, sc, mesh=bmesh, **kw)  # [B,3,16]
+                    on_device(res)
                 # the mesh result comes to the host whole and goes back up
                 # for `_affine_mont`: a second crossing, in decode_points
                 with span(call + "/wait", bytes=res.nbytes):
@@ -572,6 +579,7 @@ class TpuBackend(CpuBackend):
                             sc = np.pad(sc, ((0, n - sc.shape[0]), (0, 0)))
                         cols.append(jnp.asarray(sc))
                         sent += sc.nbytes
+                    on_upload(cols, call)
                     annotate(bytes=sent)
                 annotate(active=int(sum(active)))
                 with span(call + "/dispatch"):
@@ -579,6 +587,7 @@ class TpuBackend(CpuBackend):
                                  for sc, a in zip(cols, active))
                     affine = ec.affine_points(MSM.combine_windows_batch(
                         MSM.pad_window_sums(wins, width), c))
+                    on_device(affine)
                 out += ec.read_points(affine, call, keep=len(chunk))
         return out
 
@@ -606,6 +615,7 @@ class TpuBackend(CpuBackend):
             packed = _ship_packed(coeffs, call)
             with span(call + "/dispatch"):
                 out = NTT.ntt(_to_mont_packed(packed), omega)
+                on_device(out)
             return _fetch_u64_std(out, call)
 
     def intt(self, evals, omega: int):
@@ -625,11 +635,13 @@ class TpuBackend(CpuBackend):
                     # lax.scan that recompiles on EVERY call
                     from .quotient_device import _helpers
                     out = _helpers()["mul_s"](res, jnp.asarray(ninv))
+                    on_device(out)
                 return _fetch_u64_std(out, call)
         with span(call, n=evals.shape[0]):
             packed = _ship_packed(evals, call)
             with span(call + "/dispatch"):
                 out = NTT.intt(_to_mont_packed(packed), omega)
+                on_device(out)
             return _fetch_u64_std(out, call)
 
     def _ntt_sharded(self, arr_u64, omega: int, mont_out: bool = False):
@@ -645,6 +657,7 @@ class TpuBackend(CpuBackend):
             with span(call + "/dispatch"):
                 res = sharded_ntt(_to_mont_packed(packed), omega, plan.mesh,
                                   plan=plan)
+                on_device(res)
             if mont_out:
                 return res
             return _fetch_u64_std(res, call)
@@ -677,6 +690,7 @@ class TpuBackend(CpuBackend):
                 mont = _to_mont_packed(packed).reshape(-1, n, 16)
                 fn = NTT.intt_many if inverse else NTT.ntt_many
                 out = fn(mont, omega)
+                on_device(out)
             return _fetch_u64_std(
                 out, call, lambda std: list(std.reshape(-1, n, 4)[:b]))
 
@@ -723,6 +737,7 @@ class TpuBackend(CpuBackend):
             with span(call + "/dispatch"):
                 out = NTT.coset_lde_std(L16.split_limbs16(packed, n_out),
                                         omega, g)
+                on_device(out)
             return _fetch_u64_std(
                 out, call, lambda std: list(std.reshape(-1, n_out, 4)[:b]))
 
@@ -786,6 +801,7 @@ def _ship_packed(arr, call: str):
         if callable(arr):
             arr = arr()
         packed = jnp.asarray(L16.pack_u64limbs(arr))
+        on_upload(packed, call)
         annotate(bytes=packed.nbytes)
     return packed
 
@@ -812,8 +828,10 @@ def _fetch_u64_std(out, call: str, unstack=None):
         mont = np.asarray(out).reshape(-1, 16)
     with span(call + "/encode", bytes=mont.nbytes):
         back = jnp.asarray(mont)
+        on_upload(back, call)
     with span(call + "/dispatch"):
         std16 = _mont_fns()["from"](back)
+        on_device(std16)
     with span(call + "/wait", bytes=std16.nbytes):
         std16 = np.asarray(std16)
     with span(call + "/decode"):

@@ -42,9 +42,12 @@ split and the zero rows from n to 4n are the engine's first device step,
 (`quotient/extend/dispatch`);
 `quotient/expressions` the expression tree's dispatches; `quotient/wait`
 the one blocking read, in the engine's `inverse_std`; `quotient/decode` the
-limb join. The device is busy with this queue from the first
-`quotient/extend/dispatch` to the end of `quotient/wait`: that stretch is
-the device working, not the host.
+limb join. From the first `quotient/extend/dispatch` to the end of
+`quotient/wait` the device has work queued; when it works in that stretch
+the spans cannot say, the device timeline (`tracing.on_device`) can: a
+mark each LDE chunk in `quotient/extend/dispatch`, one after the last fold
+of the expressions (kind `quotient/expressions`, from that span's start)
+and one for the inverse (`quotient/inverse`).
 
 Parity: the device path produces EXACTLY the host path's u64 coefficient
 arrays, compared in-situ during real proves
@@ -55,11 +58,12 @@ mesh-vs-host in tests/test_quotient_sharded.py).
 from __future__ import annotations
 
 import os
+import time
 
 import numpy as np
 
 from ..fields import bn254
-from ..observability.tracing import span
+from ..observability.tracing import on_device, span
 from ..ops.msm import _TableLRU, _record_event
 from .constraint_system import CircuitConfig
 from .domain import COSET_GEN, Domain
@@ -252,11 +256,13 @@ class _LocalEngine:
     def inverse_std(self, acc, vinv_vals) -> np.ndarray:
         from ..ops import ntt as NTT
 
+        since = time.perf_counter()
         if vinv_vals is None:
             std = NTT.coset_intt_std(acc, self.dom.omega_ext, COSET_GEN)
         else:
             std = NTT.coset_intt_std_vinv(acc, self.dom.omega_ext,
                                           COSET_GEN, vinv_vals)
+        on_device(std, kind="quotient/inverse", since=since)
         # the quotient's one blocking read: the whole queue drains here
         with span("quotient/wait", bytes=std.nbytes):
             return np.asarray(std)
@@ -383,7 +389,9 @@ def _quotient_impl(cfg: CircuitConfig, dom: Domain, fetch_coeffs,
         with span("quotient/extend/encode", bytes=len(arrs_u64) * n * 32):
             packed = L16.pack_u64limbs(B.stack_rows(arrs_u64, n))
         with span("quotient/extend/dispatch"):
-            return engine.lde(packed)
+            outs = engine.lde(packed)
+            on_device(outs)
+            return outs
 
     def ext_of_coeffs(arr_u64):
         return ext_of_many([arr_u64])[0]
@@ -426,6 +434,9 @@ def _quotient_impl(cfg: CircuitConfig, dom: Domain, fetch_coeffs,
         if acc is None:
             raise ValueError("config yields no constraint expressions — "
                              "nothing to fold into a quotient")
+        # the device timeline's one mark of the folds, from this span's
+        # start (the expressions have no dispatch span of their own)
+        on_device(acc, kind="quotient/expressions")
     # h = acc / Z_H on the coset, then the fused inverse path: ONE kernel —
     # the 1/Z_H stage-0 pre-scale, the iNTT, and the combined
     # g^{-i}·n^{-1}·(mont→std) output table all ride a single transform.

@@ -16,6 +16,7 @@ lowered and compiled once for all of these and not once a file.
   program every prove above commits with.
 """
 
+import collections
 import re
 import types
 
@@ -205,6 +206,29 @@ def _stages(call):
     return [c for c in call.children if c.name.startswith(call.name + "/")]
 
 
+def _check_marks(tr) -> tuple:
+    """The device timeline (ISSUE 40): one mark a dispatch span, of that
+    span's call; an upload mark only inside an `encode` of its call;
+    besides them only the quotient's folds and its inverse, which have no
+    dispatch span. Returns (folds, inverses)."""
+    def calls(stage):
+        return collections.Counter(
+            s.name.rsplit("/", 1)[0] for s in _walk(tr.root)
+            if s.name.endswith("/" + stage))
+
+    kinds = [kind for kind, *_ in tr.device]
+    up = "/" + tracing.UPLOAD
+    uploads = collections.Counter(k[:-len(up)] for k in kinds
+                                  if k.endswith(up))
+    marked = collections.Counter(k for k in kinds if not k.endswith(up))
+    encoded = calls(tracing.ENCODE)
+    assert all(uploads[c] <= encoded[c] for c in uploads), (uploads, encoded)
+    folds = marked.pop("quotient/expressions", 0)
+    inverses = marked.pop("quotient/inverse", 0)
+    assert marked == calls(tracing.DISPATCH)
+    return folds, inverses
+
+
 def _check_in_flight(call, stages):
     """Every wait is preceded by a dispatch since the last wait, and each
     stretch from that dispatch to the wait's end lies inside the call."""
@@ -266,6 +290,7 @@ class TestDeviceBoundarySpans:
         (call,) = tr.root.children
         assert call.name == f"backend/{op}"
         assert call.meta["n"] == n
+        assert _check_marks(tr) == (0, 0)
         stages = _stages(call)
         assert [s.name for s in stages] == [f"backend/{op}/{w}" for w in want]
         _check_in_flight(call, stages)
@@ -316,6 +341,21 @@ class TestDeviceBoundarySpans:
             stages = _stages(call)
             assert [s.name.rsplit("/", 1)[-1] for s in stages] == want
             _check_in_flight(call, stages)
+
+    def test_every_dispatch_of_a_prove_marks_the_device_timeline(
+            self, tiny_tpu_prove):
+        tr = tiny_tpu_prove.trace
+        folds, inverses = _check_marks(tr)
+        assert folds == 1 and inverses == 1
+        dev = tracing.summary(tr)["device"]
+        assert dev["marks"] == len(tr.device) and dev["interleaved"] == 0
+        assert set(dev["by_kind"]) == {"msm", "ntt", "quotient", "upload"}
+        assert 0 < dev["busy_lower_s"] <= dev["busy_s"]
+        assert dev["busy_s"] + dev["idle_s"] == pytest.approx(
+            tr.root.t1 - tr.root.t0, abs=1e-5)
+        # stretches in stamp order, one device: none overlaps the next
+        for a, b in zip(tr.device, tr.device[1:]):
+            assert a[5] <= b[3] <= b[4] <= b[5]
 
     def test_quotient_is_one_queue_with_one_read(self, tiny_tpu_prove):
         (q,) = [s for s in _walk(tiny_tpu_prove.trace.root)
