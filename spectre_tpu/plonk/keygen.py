@@ -200,8 +200,12 @@ class ProvingKey:
         """Drop the prover's cached extended-domain forms of the fixed
         columns (populated lazily by `_quotient_host`, ~GBs at k=21). A
         service holding several pks calls this on the idle families so
-        peaks don't stack (`prover_service/state.py`)."""
+        peaks don't stack (`prover_service/state.py`); with them its
+        value columns' limb arrays (`prover._key_limbs`)."""
+        from .prover import release_key_columns
+
         self.__dict__.pop("_ext_fixed_cache", None)
+        release_key_columns(self)
 
 
 def keygen(srs: SRS, cfg: CircuitConfig, fixed_columns: list, selectors: list,

@@ -9,12 +9,14 @@ NTTs, pointwise quotient evaluation); transcript and control flow stay on host.
 from __future__ import annotations
 
 import secrets
+import threading
+import weakref
 
 import numpy as np
 
 from ..fields import bn254
 from ..native import host
-from ..observability.tracing import span
+from ..observability.tracing import annotate, span
 from ..utils.profiling import phase
 from . import backend as B, kzg
 from .constraint_system import (Assignment, NUM_H_CHUNKS, PERM_CHUNK,
@@ -72,21 +74,91 @@ class _ArrayCtx:
         return B.const_arr(s, self._dom.n_ext)
 
 
+# The proving key's value columns (fixed, sigma, table) as [n, 4] limb
+# arrays, marshalled once a key: id(pk) -> (a weak reference to the key,
+# {(kind, j): array}). Held here and not on the key, so a pickled key holds
+# what it always held; bounded as `quotient_device._static_cache` is.
+_key_limbs: dict = {}
+_key_limbs_lock = threading.Lock()          # two jobs may prove one key
+
+_ONE = host.ints_to_limbs([1])[0]          # the limb row of 1
+
+
+def _key_columns(pk) -> dict:
+    with _key_limbs_lock:
+        hit = _key_limbs.get(id(pk))
+        if hit is None or hit[0]() is not pk:
+            if len(_key_limbs) > 4:
+                _key_limbs.clear()
+            hit = _key_limbs[id(pk)] = (weakref.ref(pk), {})
+        return hit[1]
+
+
+def release_key_columns(pk):
+    """Drop the key's limb arrays (`ProvingKey.release_ext_cache`)."""
+    _key_limbs.pop(id(pk), None)
+
+
+def _running_product(bk, num, den, u: int):
+    """prefix[i] = prod_{r <= i} num[r] / den[r], every row from u on
+    counted as 1 (the blinding rows are inactive)."""
+    ratio = bk.mul(num, bk.inv(den))
+    ratio[u:] = _ONE
+    return bk.prefix_prod(ratio)
+
+
+def _blind_tail(z, u: int, rand):
+    """Rows u+1..n-1 of z from `rand`, row by row. Every constraint touching
+    z is inactive there (act excludes them, llast hits row u, ROT_LAST reads
+    row u), but z is opened at x and omega*x: deterministic tail rows would
+    leak witness information halo2 hides."""
+    n = z.shape[0]
+    if u + 1 < n:
+        z[u + 1:] = host.ints_to_limbs([rand() for _ in range(u + 1, n)])
+    return z
+
+
+def perm_grand_products(bk, u: int, columns: list, sigmas: list, omega_pows,
+                        beta: int, gamma: int, rand) -> list:
+    """The permutation's running products z, one a chunk of PERM_CHUNK
+    columns, each chunk starting where the last ended; for a satisfied
+    permutation the last ends at 1 (asserted). columns[g], sigmas[g]: the
+    g-th permutation column's values and its sigma, [n, 4] limb arrays;
+    every z is one too, its tail blinded from `rand` chunk by chunk."""
+    zs = []
+    prev_end = 1
+    for base in range(0, len(columns), PERM_CHUNK):
+        num = den = None
+        for g in range(base, min(base + PERM_CHUNK, len(columns))):
+            v = columns[g]
+            id_term = bk.add_scalar(
+                bk.axpy(omega_pows, beta * pow(DELTA, g, R) % R, v), gamma)
+            sig_term = bk.add_scalar(bk.axpy(sigmas[g], beta, v), gamma)
+            num = id_term if num is None else bk.mul(num, id_term)
+            den = sig_term if den is None else bk.mul(den, sig_term)
+        prefix = _running_product(bk, num, den, u)
+        z = np.empty_like(prefix)
+        z[0] = host.ints_to_limbs([prev_end])[0]
+        z[1:] = bk.scale(prefix[:-1], prev_end)
+        prev_end = prev_end * host.limbs_to_ints(prefix[u - 1:u])[0] % R
+        zs.append(_blind_tail(z, u, rand))
+    assert prev_end == 1, \
+        "permutation product != 1 (copy constraints unsatisfiable)"
+    return zs
+
+
 def lookup_grand_product(bk, n: int, u: int, a_v, pa_v, pt_v, t_v,
-                         beta: int, gamma: int) -> list:
-    """Running product z for one lookup column; telescopes to 1 at row u for
-    honest witnesses (asserted — the l_last boundary constraint enforces it
-    in-proof)."""
-    num = bk.mul(bk.add_scalar(B.to_arr(a_v), beta),
-                 bk.add_scalar(B.to_arr(t_v), gamma))
-    den = bk.mul(bk.add_scalar(B.to_arr(pa_v), beta),
-                 bk.add_scalar(B.to_arr(pt_v), gamma))
-    ratio = B.arr_to_ints(bk.mul(num, bk.inv(den)))
-    for i in range(u, n):
-        ratio[i] = 1
-    prefix = B.arr_to_ints(bk.prefix_prod(B.to_arr(ratio)))
-    z = [1] + prefix[:-1]
-    assert prefix[u - 1] == 1, "lookup product != 1"
+                         beta: int, gamma: int):
+    """Running product z for one lookup column, an [n, 4] limb array from
+    the columns' arrays; telescopes to 1 at row u for honest witnesses
+    (asserted — the l_last boundary constraint enforces it in-proof)."""
+    num = bk.mul(bk.add_scalar(a_v, beta), bk.add_scalar(t_v, gamma))
+    den = bk.mul(bk.add_scalar(pa_v, beta), bk.add_scalar(pt_v, gamma))
+    prefix = _running_product(bk, num, den, u)
+    assert np.array_equal(prefix[u - 1], _ONE), "lookup product != 1"
+    z = np.empty_like(prefix)
+    z[0] = _ONE
+    z[1:] = prefix[:-1]
     return z
 
 
@@ -127,11 +199,19 @@ def prove(pk: ProvingKey, srs: SRS, assignment: Assignment,
                     for j in range(cfg.num_sha_bit)]
         shw_vals = [blind(assignment.sha_word[j].tolist())
                     for j in range(cfg.num_sha_word)]
-        inst_vals = [assignment.instance_column(j)
+        inst_arrs = [B.to_arr(assignment.instance_column(j))
                      for j in range(cfg.num_instance)]
 
     polys: dict = {}      # key -> coefficient form
-    values: dict = {}     # key -> int list (lagrange values)
+    # key -> [n, 4] lagrange values, as the commit marshalled them, of the
+    # columns the grand products read
+    value_arrs: dict = {}
+    keep = set(perm_column_keys(cfg)) | {
+        (kind, j) for kind in ("pA", "pT")
+        for j in range(cfg.num_lookup_advice)}
+
+    def limbs(vals):
+        return vals if isinstance(vals, np.ndarray) else B.to_arr(vals)
 
     COMMIT_CHUNK = 16   # bounds resident coefficient arrays (k=20: 512MB)
 
@@ -151,28 +231,32 @@ def prove(pk: ProvingKey, srs: SRS, assignment: Assignment,
         same points, and a column of bits pays one window of the MSM where
         its coefficients pay all of them. For the witness's own columns,
         whose cells are mostly narrow; a grand product's are full width
-        and stay on the coefficient path."""
+        and stay on the coefficient path.
+
+        An item's values are an int list, marshalled here, or an [n, 4]
+        limb array, taken as it is."""
         from concurrent.futures import ThreadPoolExecutor
 
         if not item_list:
             return
         with ThreadPoolExecutor(max_workers=2) as ex:
-            futs = {i: ex.submit(B.to_arr, item_list[i][1])
+            futs = {i: ex.submit(limbs, item_list[i][1])
                     for i in range(min(COMMIT_CHUNK, len(item_list)))}
             for base in range(0, len(item_list), COMMIT_CHUNK):
                 chunk = item_list[base:base + COMMIT_CHUNK]
                 for j in range(base + COMMIT_CHUNK,
                                min(base + 2 * COMMIT_CHUNK, len(item_list))):
                     if j not in futs:
-                        futs[j] = ex.submit(B.to_arr, item_list[j][1])
+                        futs[j] = ex.submit(limbs, item_list[j][1])
                 with span("commit/marshal"):
                     arrs = [futs.pop(base + off).result()
                             for off in range(len(chunk))]
                 if as_values:
                     points = kzg.commit_lagrange_many(srs, arrs, bk, usable=u)
                 coeffs = dom.lagrange_to_coeff_many(arrs, bk)
-                for (key, vals), c in zip(chunk, coeffs):
-                    values[key] = vals
+                for (key, _), arr, c in zip(chunk, arrs, coeffs):
+                    if key in keep:
+                        value_arrs[key] = arr
                     polys[key] = c
                 if not as_values:
                     points = kzg.commit_many(srs, coeffs, bk)
@@ -199,78 +283,49 @@ def prove(pk: ProvingKey, srs: SRS, assignment: Assignment,
     gamma = tr.challenge()
 
     # --- 3. permutation grand products (chunk-linked) ---
+    # every column in [n, 4] limbs: the commit's arrays, the instance's
+    # marshalled above, the key's marshalled once a key
     with phase("prove/grand_products"):
         col_keys = perm_column_keys(cfg)
-        omega_pows = bk.powers(dom.omega, n)
+        key_arrs = _key_columns(pk)
 
-        def col_values(key):
-            kind, j = key
-            if kind == "adv":
-                return adv_vals[j]
-            if kind == "ladv":
-                return ladv_vals[j]
-            if kind == "fix":
-                return pk.fixed_values[j]
-            if kind == "shw":
-                return shw_vals[j]
-            if kind == "inst":
-                return inst_vals[j]
-            raise KeyError(key)
+        def key_column(key, vals):
+            arr = key_arrs.get(key)
+            if arr is None:
+                arr = key_arrs[key] = B.to_arr(vals)
+            return arr
 
-        prev_end = 1
-        nch = cfg.num_perm_chunks
-        gp_items = []    # pz + lz columns, committed in one batched call
-        for ch in range(nch):
-            with span("grand_products/perm_chunk"):
-                cols = list(enumerate(col_keys))[ch * PERM_CHUNK:
-                                                 (ch + 1) * PERM_CHUNK]
-                num = B.to_arr([1] * n)
-                den = B.to_arr([1] * n)
-                for gidx, key in cols:
-                    v_arr = B.to_arr(col_values(key))
-                    dj = pow(DELTA, gidx, R)
-                    id_term = bk.add_scalar(
-                        bk.add(v_arr, bk.scale(omega_pows, beta * dj % R)),
-                        gamma)
-                    sig_term = bk.add_scalar(
-                        bk.add(v_arr,
-                               bk.scale(B.to_arr(pk.sigma_values[gidx]),
-                                        beta)),
-                        gamma)
-                    num = bk.mul(num, id_term)
-                    den = bk.mul(den, sig_term)
-                ratio = bk.mul(num, bk.inv(den))
-                # deactivate blinding rows
-                ratio_ints = B.arr_to_ints(ratio)
-                for i in range(u, n):
-                    ratio_ints[i] = 1
-                prefix = bk.prefix_prod(B.to_arr(ratio_ints))
-                prefix_ints = B.arr_to_ints(prefix)
-                z = [prev_end] + [prev_end * p % R for p in prefix_ints[:-1]]
-                prev_end = prev_end * prefix_ints[u - 1] % R if u >= 1 \
-                    else prev_end
-                # Blind the tail: every constraint touching z is inactive on
-                # rows u+1..n-1 (act excludes them, llast hits row u, ROT_LAST
-                # reads row u), but z is opened at x and omega*x —
-                # deterministic tail rows would leak witness information
-                # halo2 hides. Randomize them.
-                for i in range(u + 1, n):
-                    z[i] = rand()
-            gp_items.append((("pz", ch), z))
-        assert prev_end == 1, \
-            "permutation product != 1 (copy constraints unsatisfiable)"
+        # ONE span for the chunks (the ledger's `idle_gaps` label);
+        # `from_ints`: the columns it marshalled from Python ints, the key's
+        # on its first prove
+        with span("grand_products/perm_chunk", columns=len(col_keys)):
+            cached = len(key_arrs)
+            columns = [
+                inst_arrs[j] if kind == "inst"
+                else key_column((kind, j), pk.fixed_values[j])
+                if kind == "fix" else value_arrs[(kind, j)]
+                for kind, j in col_keys]
+            sigmas = [key_column(("sig", g), pk.sigma_values[g])
+                      for g in range(len(col_keys))]
+            annotate(from_ints=len(key_arrs) - cached)
+            zs = perm_grand_products(bk, u, columns, sigmas,
+                                     bk.powers(dom.omega, n), beta, gamma,
+                                     rand)
+        gp_items = [(("pz", ch), z) for ch, z in enumerate(zs)]
 
         # --- 4. lookup grand products ---
         for j in range(cfg.num_lookup_advice):
             with span("grand_products/lookup"):
                 z = lookup_grand_product(
-                    bk, n, u, values[("ladv", j)], values[("pA", j)],
-                    values[("pT", j)], pk.table_values[j], beta, gamma)
-                for i in range(u + 1, n):    # blind tail rows (see pz above)
-                    z[i] = rand()
+                    bk, n, u, value_arrs[("ladv", j)], value_arrs[("pA", j)],
+                    value_arrs[("pT", j)],
+                    key_column(("tab", cfg.table_id(j)), pk.table_values[j]),
+                    beta, gamma)
+                _blind_tail(z, u, rand)
             gp_items.append((("lz", j), z))
         # no challenge between pz and lz commits: one batched call
         commit_cols_batched(gp_items)
+        value_arrs.clear()     # the quotient, the prove's peak, reads none
 
     y = tr.challenge()
 
@@ -278,8 +333,7 @@ def prove(pk: ProvingKey, srs: SRS, assignment: Assignment,
     # paths and nothing else create them, so hoist before the dispatch
     # (one batched iNTT over the instance-column stack)
     with phase("prove/instance_polys"):
-        for j, c in enumerate(dom.lagrange_to_coeff_many(
-                [B.to_arr(v) for v in inst_vals], bk)):
+        for j, c in enumerate(dom.lagrange_to_coeff_many(inst_arrs, bk)):
             polys[("inst", j)] = c
 
     def poly_for(key):

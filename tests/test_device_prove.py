@@ -12,11 +12,14 @@ lowered and compiled once for all of these and not once a file.
 - the second NTT mode's keygen and prove, bytes against the first;
 - the one-chip commit path's runs of columns (ISSUE 30), which are the same
   calls the spans look at;
+- a proving key pickled before the grand products read limbs, loaded and
+  proved twice: its bytes, and what the permutation's span counts;
 - first in the file, the default MSM mode's kernel cases: the window-phase
   program every prove above commits with.
 """
 
 import collections
+import os
 import re
 import types
 
@@ -463,7 +466,8 @@ class TestDeviceBoundarySpans:
             assert counts[f"backend/{op}/wait"] == 2 * c
             assert counts[f"backend/{op}/decode"] == c
         assert counts["job/blind"] == counts["quotient/wait"] == 1
-        assert counts["grand_products/perm_chunk"] == cfg.num_perm_chunks
+        # one span over all of the permutation's chunks, one a lookup
+        assert counts["grand_products/perm_chunk"] == 1
         assert counts["grand_products/lookup"] == cfg.num_lookup_advice
         # bytes: 64 a field element as limbs (the MSM scalars up, and
         # everything down), 32 as the packed rows a column goes up as since
@@ -558,6 +562,47 @@ class TestBackendByteEquality:
         assert verify(pk.vk, tiny.srs, tiny.instances, proof)
         assert proof == tiny_cpu_proof, \
             "backend proof bytes diverge (transcript/serialization drift)"
+
+
+class TestGrandProductsInLimbs:
+    """The grand products read every column as the [n, 4] limbs the commit
+    made (tests/test_grand_products.py holds them to the int loop they
+    replaced); the proving key's columns are marshalled once a key, off
+    the key itself, so a key pickled before that still loads and proves."""
+
+    PICKLED = os.path.join(os.path.dirname(__file__), "tiny_pk_k6.pickle")
+
+    def test_a_served_prove_marshals_no_column(self, tiny, tiny_cpu_proof):
+        import dataclasses
+        import pickle
+
+        from spectre_tpu.plonk.expressions import perm_column_keys
+        from spectre_tpu.plonk.keygen import ProvingKey
+
+        with open(self.PICKLED, "rb") as f:
+            blob = f.read()
+        pk = pickle.loads(blob)
+        assert pk.vk.digest() == tiny.pk.vk.digest()
+        cfg = pk.vk.config
+        columns = len(perm_column_keys(cfg))
+        bk = B.get_backend("tpu")
+        readings = []
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("SPECTRE_MESH_SHAPE", "1x1")
+            # the key's first prove (a warm-up) and the next (a served one)
+            for _ in range(2):
+                with tracing.trace("limb-grand-products") as tr:
+                    assert _seeded_prove(tiny, pk, bk) == tiny_cpu_proof
+                spans = [s for s in _walk(tr.root)
+                         if s.name == "grand_products/perm_chunk"]
+                assert [s.meta["columns"] for s in spans] == [columns]
+                readings.append(spans[0].meta["from_ints"])
+        # the first marshals the key's fixed and sigma columns, then never
+        assert readings == [cfg.num_fixed + columns, 0]
+        # nothing the prove keeps lands in the key or its pickle
+        assert set(vars(pk)) == {f.name for f in dataclasses.fields(
+            ProvingKey)}
+        assert pickle.dumps(pk) == pickle.dumps(pickle.loads(blob))
 
 
 class TestNttModeProofBytes:

@@ -548,15 +548,16 @@ class TestLookupBoundarySoundness:
             return list(t_vals), list(t_vals)  # A' = T' = table
 
         def evil_grand_product(bk, n, u, a_v, pa_v, pt_v, t_v, beta, gamma):
-            num = bk.mul(bk.add(B.to_arr(a_v), B.to_arr([beta] * n)),
-                         bk.add(B.to_arr(t_v), B.to_arr([gamma] * n)))
-            den = bk.mul(bk.add(B.to_arr(pa_v), B.to_arr([beta] * n)),
-                         bk.add(B.to_arr(pt_v), B.to_arr([gamma] * n)))
+            # the columns come as [n, 4] limb arrays, z goes back as one
+            num = bk.mul(bk.add(a_v, B.to_arr([beta] * n)),
+                         bk.add(t_v, B.to_arr([gamma] * n)))
+            den = bk.mul(bk.add(pa_v, B.to_arr([beta] * n)),
+                         bk.add(pt_v, B.to_arr([gamma] * n)))
             ratio = B.arr_to_ints(bk.mul(num, bk.inv(den)))
             for i in range(u, n):
                 ratio[i] = 1
             prefix = B.arr_to_ints(bk.prefix_prod(B.to_arr(ratio)))
-            return [1] + prefix[:-1]  # telescope assert skipped
+            return B.to_arr([1] + prefix[:-1])  # telescope assert skipped
 
         monkeypatch.setattr(prover_mod, "permute_lookup", evil_permute)
         monkeypatch.setattr(prover_mod, "lookup_grand_product",
