@@ -20,13 +20,55 @@ FQ = 0
 FR = 1
 
 
+_U64P = ctypes.POINTER(ctypes.c_uint64)
+_SIZEP = ctypes.POINTER(ctypes.c_size_t)
+_INT, _SIZE = ctypes.c_int, ctypes.c_size_t
+# every function of the library this module calls, with its argument types
+_SIGNATURES = {
+    "fp_mul_batch": [_INT, _U64P, _U64P, _U64P, _SIZE],
+    "fp_add_batch": [_INT, _U64P, _U64P, _U64P, _SIZE],
+    "fp_sub_batch": [_INT, _U64P, _U64P, _U64P, _SIZE],
+    "fp_inv_batch": [_INT, _U64P, _U64P, _SIZE],
+    "fp_pow_single": [_INT, _U64P, _U64P, _U64P],
+    "fr_ntt": [_U64P, _SIZE, _U64P],
+    "g1_msm": [_U64P, _U64P, _SIZE, _INT, _U64P, ctypes.POINTER(_INT)],
+    "g1_add_affine_batch": [_U64P, _U64P, _U64P, _SIZE],
+    "g1_scalar_powers": [_U64P, _U64P, _SIZE, _U64P],
+    "g1_fixed_base_mul": [_U64P, _U64P, _SIZE, _U64P],
+    "g1_fft": [_U64P, _SIZE, _U64P, _U64P, _INT],
+    "fp_horner": [_INT, _U64P, _U64P, _U64P, _SIZE],
+    "fp_eval_many": [_INT, ctypes.POINTER(ctypes.c_void_p), _SIZEP, _SIZE,
+                     _U64P, _SIZEP, _U64P, _INT],
+    "fp_sum": [_INT, _U64P, _U64P, _SIZE],
+    "fp_scale_batch": [_INT, _U64P, _U64P, _U64P, _SIZE],
+    "fp_add_scalar_batch": [_INT, _U64P, _U64P, _U64P, _SIZE],
+    "fp_axpy_batch": [_INT, _U64P, _U64P, _U64P, _U64P, _SIZE],
+    "fp_combine_sets": [_INT, ctypes.POINTER(ctypes.c_void_p), _SIZEP, _U64P,
+                        _SIZEP, _SIZE, _SIZE, _SIZE, _U64P, _INT],
+    "fp_powers": [_INT, _U64P, _U64P, _SIZE],
+    "fp_prefix_prod": [_INT, _U64P, _U64P, _SIZE],
+}
+
+
+def _exports_all(so: str) -> bool:
+    """Whether the built library names every function of `_SIGNATURES`: a
+    library built from older sources can be newer than the sources it lacks
+    (a checkout that kept it), and make goes by the times alone."""
+    with open(so, "rb") as f:
+        blob = f.read()
+    return all(name.encode() in blob for name in _SIGNATURES)
+
+
 def _build_if_needed() -> bool:
     src = os.path.join(_DIR, "src", "spectre_host.cc")
     if os.path.exists(_SO):
-        if not os.path.exists(src) or os.path.getmtime(_SO) >= os.path.getmtime(src):
+        if not os.path.exists(src):
             return True  # prebuilt .so without sources is fine
+        if os.path.getmtime(_SO) >= os.path.getmtime(src) and _exports_all(_SO):
+            return True
     try:
-        subprocess.run(["make", "-C", _DIR], check=True, capture_output=True)
+        subprocess.run(["make", "-B", "-C", _DIR], check=True,
+                       capture_output=True)
         return True
     except Exception:
         return False
@@ -40,44 +82,11 @@ class HostLib:
             if not _build_if_needed():
                 raise RuntimeError("libspectre_host.so missing and build failed")
             lib = ctypes.CDLL(_SO)
-            u64p = ctypes.POINTER(ctypes.c_uint64)
             lib.spectre_init.restype = None
-            for name in ("fp_mul_batch", "fp_add_batch", "fp_sub_batch"):
+            for name, argtypes in _SIGNATURES.items():
                 fn = getattr(lib, name)
-                fn.argtypes = [ctypes.c_int, u64p, u64p, u64p, ctypes.c_size_t]
+                fn.argtypes = argtypes
                 fn.restype = None
-            lib.fp_inv_batch.argtypes = [ctypes.c_int, u64p, u64p, ctypes.c_size_t]
-            lib.fp_inv_batch.restype = None
-            lib.fp_pow_single.argtypes = [ctypes.c_int, u64p, u64p, u64p]
-            lib.fp_pow_single.restype = None
-            lib.fr_ntt.argtypes = [u64p, ctypes.c_size_t, u64p]
-            lib.fr_ntt.restype = None
-            lib.g1_msm.argtypes = [u64p, u64p, ctypes.c_size_t, ctypes.c_int,
-                                   u64p, ctypes.POINTER(ctypes.c_int)]
-            lib.g1_msm.restype = None
-            lib.g1_add_affine_batch.argtypes = [u64p, u64p, u64p, ctypes.c_size_t]
-            lib.g1_add_affine_batch.restype = None
-            lib.g1_scalar_powers.argtypes = [u64p, u64p, ctypes.c_size_t, u64p]
-            lib.g1_scalar_powers.restype = None
-            lib.g1_fixed_base_mul.argtypes = [u64p, u64p, ctypes.c_size_t, u64p]
-            lib.g1_fixed_base_mul.restype = None
-            lib.g1_fft.argtypes = [u64p, ctypes.c_size_t, u64p, u64p,
-                                   ctypes.c_int]
-            lib.g1_fft.restype = None
-            lib.fp_horner.argtypes = [ctypes.c_int, u64p, u64p, u64p, ctypes.c_size_t]
-            lib.fp_horner.restype = None
-            lib.fp_sum.argtypes = [ctypes.c_int, u64p, u64p, ctypes.c_size_t]
-            lib.fp_sum.restype = None
-            lib.fp_scale_batch.argtypes = [ctypes.c_int, u64p, u64p, u64p, ctypes.c_size_t]
-            lib.fp_scale_batch.restype = None
-            lib.fp_add_scalar_batch.argtypes = [ctypes.c_int, u64p, u64p, u64p, ctypes.c_size_t]
-            lib.fp_add_scalar_batch.restype = None
-            lib.fp_axpy_batch.argtypes = [ctypes.c_int, u64p, u64p, u64p, u64p, ctypes.c_size_t]
-            lib.fp_axpy_batch.restype = None
-            lib.fp_powers.argtypes = [ctypes.c_int, u64p, u64p, ctypes.c_size_t]
-            lib.fp_powers.restype = None
-            lib.fp_prefix_prod.argtypes = [ctypes.c_int, u64p, u64p, ctypes.c_size_t]
-            lib.fp_prefix_prod.restype = None
             lib.spectre_init()
             inst = super().__new__(cls)
             inst.lib = lib
@@ -94,7 +103,26 @@ def available() -> bool:
 
 
 def _u64p(arr: np.ndarray):
-    return arr.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64))
+    return arr.ctypes.data_as(_U64P)
+
+
+def _sizes(vals) -> np.ndarray:
+    return np.asarray(vals, dtype=np.uintp).reshape(-1)
+
+
+def _rows_of(arrays) -> tuple:
+    """The C views of several [n_k, 4] limb arrays: the arrays as they are
+    passed (kept alive by the caller), their pointers and their lengths."""
+    arrays = [np.ascontiguousarray(a, dtype=np.uint64) for a in arrays]
+    if not all(a.ndim == 2 and a.shape[1] == 4 for a in arrays):
+        raise ValueError("every array is [n, 4] limbs")
+    ptrs = (ctypes.c_void_p * len(arrays))(*[a.ctypes.data for a in arrays])
+    return arrays, ptrs, _sizes([a.shape[0] for a in arrays])
+
+
+def threads() -> int:
+    """The threads a multi-threaded call of the library runs on."""
+    return min(8, os.cpu_count() or 1)
 
 
 # ---------------------------------------------------------------------------
@@ -254,7 +282,7 @@ def g1_fft(points: np.ndarray, omega: int, scale: int | None = None,
     assert out.shape == (n, 8) and 1 << logn == n
     sc = None if scale is None else ints_to_limbs([scale])
     if nthreads is None:
-        nthreads = min(8, os.cpu_count() or 1)
+        nthreads = threads()
     lib.g1_fft(_u64p(out), logn, _u64p(ints_to_limbs([omega])),
                None if sc is None else _u64p(sc), nthreads)
     return out
@@ -318,6 +346,53 @@ def fp_horner(field: int, a: np.ndarray, x: int) -> int:
     out = np.zeros(4, dtype=np.uint64)
     lib.fp_horner(field, _u64p(a), _u64p(xl), _u64p(out), a.shape[0])
     return sum(int(out[j]) << (64 * j) for j in range(4))
+
+
+def fp_eval_many(field: int, polys: list, points: list,
+                 nthreads: int | None = None) -> list:
+    """Evaluate each coefficient array polys[k] ([n_k, 4], little index
+    first) at every point of points[k]: -> one list of ints a polynomial, in
+    points[k]'s order. One native call; a polynomial's coefficients are read
+    once for all of its points, and the polynomials are spread over
+    `nthreads` threads (`threads()` by default)."""
+    if len(polys) != len(points):
+        raise ValueError("one list of points a polynomial")
+    if not polys:
+        return []
+    lib = HostLib().lib
+    arrays, ptrs, lens = _rows_of(polys)
+    offs = _sizes(np.cumsum([0] + [len(p) for p in points]))
+    flat = [x for p in points for x in p]
+    xs = ints_to_limbs(flat)
+    out = np.zeros((len(flat), 4), dtype=np.uint64)
+    lib.fp_eval_many(field, ptrs, lens.ctypes.data_as(_SIZEP), len(arrays),
+                     _u64p(xs), offs.ctypes.data_as(_SIZEP), _u64p(out),
+                     nthreads or threads())
+    vals = limbs_to_ints(out)
+    return [vals[offs[k]:offs[k + 1]] for k in range(len(arrays))]
+
+
+def fp_combine_sets(field: int, polys: list, scalars: list, sets: list,
+                    n: int, nthreads: int | None = None) -> np.ndarray:
+    """[max(sets) + 1, n, 4]: row S is the sum of scalars[k] * polys[k] over
+    the k with sets[k] == S, each polys[k] [n_k, 4] with n_k <= n (zeros
+    past its end). One native call, the rows spread over `nthreads`
+    threads (`threads()` by default)."""
+    if not polys or not len(polys) == len(scalars) == len(sets):
+        raise ValueError("one scalar and one set an entry, and an entry")
+    if min(sets) < 0:
+        raise ValueError("a set is a row of the result, from 0")
+    lib = HostLib().lib
+    arrays, ptrs, lens = _rows_of(polys)
+    if int(lens.max()) > n:
+        raise ValueError(f"an entry is longer than the {n} rows of a set")
+    nsets = max(sets) + 1
+    out = np.empty((nsets, n, 4), dtype=np.uint64)
+    sl, setl = ints_to_limbs(scalars), _sizes(sets)
+    lib.fp_combine_sets(field, ptrs, lens.ctypes.data_as(_SIZEP), _u64p(sl),
+                        setl.ctypes.data_as(_SIZEP), len(arrays), nsets, n,
+                        _u64p(out), nthreads or threads())
+    return out
 
 
 def fp_sum(field: int, a: np.ndarray) -> int:

@@ -12,7 +12,9 @@
 // uint64 limbs in standard (non-Montgomery) form at the boundary; points are
 // affine (x, y) limb pairs, infinity flagged separately.
 
+#include <algorithm>
 #include <array>
+#include <atomic>
 #include <cstdint>
 #include <cstring>
 #include <map>
@@ -180,6 +182,26 @@ void ctx_init(FpCtx& C, const u64* mod) {
   Fp r2 = r;
   for (int i = 0; i < 256; ++i) fp_add(r2, r2, r2, C);
   C.r2 = r2;
+}
+
+// fn(i) for every i in [0, count), on up to `nthreads` threads (the calling
+// one among them), each taking the next i from a shared counter so that
+// work units of unequal size still balance.
+template <class Fn>
+void parallel_for(size_t count, int nthreads, Fn&& fn) {
+  const size_t t_n = std::min<size_t>(nthreads > 1 ? nthreads : 1, count);
+  if (t_n <= 1) {
+    for (size_t i = 0; i < count; ++i) fn(i);
+    return;
+  }
+  std::atomic<size_t> next{0};
+  auto work = [&]() {
+    for (size_t i; (i = next.fetch_add(1)) < count;) fn(i);
+  };
+  std::vector<std::thread> pool;
+  for (size_t t = 1; t < t_n; ++t) pool.emplace_back(work);
+  work();
+  for (auto& th : pool) th.join();
 }
 
 // ---------------------------------------------------------------------------
@@ -670,6 +692,56 @@ void fp_horner(int field, const u64* a, const u64* x, u64* out, size_t n) {
   std::memcpy(out, acc.v, 32);
 }
 
+// Polynomial k (lens[k] standard-form coefficients at polys[k]) evaluated at
+// each of its points xs[offs[k]] .. xs[offs[k + 1] - 1], into the same rows
+// of out: ONE pass over a polynomial's coefficients for all of its points.
+// Horner runs in standard form with each point in Montgomery form, since
+// mont(a, s*R) = a*s: one product an element a point, and neither the
+// coefficients nor the accumulators are converted. Coefficients are
+// canonical (< p). A point's Horner is split into kChains independent
+// chains, p(x) = sum_r x^r P_r(x^kChains) with P_r the coefficients r mod
+// kChains, so that the products of a row do not wait on one another. The
+// polynomials are the threads' work units.
+void fp_eval_many(int field, const u64* const* polys, const size_t* lens,
+                  size_t npolys, const u64* xs, const size_t* offs, u64* out,
+                  int nthreads) {
+  constexpr size_t kChains = 4;
+  const FpCtx& C = pick(field);
+  parallel_for(npolys, nthreads, [&](size_t k) {
+    const size_t m = offs[k + 1] - offs[k], n = lens[k];
+    std::vector<Fp> xm(m), ym(m), acc(m * kChains);
+    for (size_t j = 0; j < m; ++j) {
+      std::memcpy(xm[j].v, xs + 4 * (offs[k] + j), 32);
+      to_mont(xm[j], xm[j], C);
+      ym[j] = xm[j];
+      for (size_t c = 1; c < kChains; c <<= 1) fp_sqr(ym[j], ym[j], C);
+    }
+    std::memset(acc.data(), 0, sizeof(Fp) * acc.size());
+    const u64* a = polys[k];
+    for (size_t b = (n + kChains - 1) / kChains; b-- > 0;) {
+      Fp ai[kChains] = {};
+      for (size_t r = 0; r < kChains && b * kChains + r < n; ++r)
+        std::memcpy(ai[r].v, a + 4 * (b * kChains + r), 32);
+      for (size_t j = 0; j < m; ++j) {
+        Fp* aj = acc.data() + j * kChains;
+        for (size_t r = 0; r < kChains; ++r) {
+          fp_mul(aj[r], aj[r], ym[j], C);
+          fp_add(aj[r], aj[r], ai[r], C);
+        }
+      }
+    }
+    for (size_t j = 0; j < m; ++j) {  // (P_3 x + P_2) x + P_1) x + P_0
+      const Fp* aj = acc.data() + j * kChains;
+      Fp v = aj[kChains - 1];
+      for (size_t r = kChains - 1; r-- > 0;) {
+        fp_mul(v, v, xm[j], C);
+        fp_add(v, v, aj[r], C);
+      }
+      std::memcpy(out + 4 * (offs[k] + j), v.v, 32);
+    }
+  });
+}
+
 // sum of all elements
 void fp_sum(int field, const u64* a, u64* out, size_t n) {
   const FpCtx& C = pick(field);
@@ -859,22 +931,9 @@ void g1_fft(u64* points, size_t logn, const u64* omega_std,
     from_mont(tw[j], cur, Cr);
     fp_mul(cur, cur, om, Cr);
   }
-  auto in_threads = [&](size_t count, auto&& fn) {
-    int t_n = nthreads > 1 && count >= 64 ? nthreads : 1;
-    if (t_n == 1) {
-      for (size_t i = 0; i < count; ++i) fn(i);
-      return;
-    }
-    std::vector<std::thread> pool;
-    for (int t = 0; t < t_n; ++t)
-      pool.emplace_back([&, t]() {
-        for (size_t i = t; i < count; i += t_n) fn(i);
-      });
-    for (auto& th : pool) th.join();
-  };
   for (size_t s = 1; s <= logn; ++s) {
     const size_t m = (size_t)1 << s, half = m >> 1, stride = n / m;
-    in_threads(n / 2, [&](size_t idx) {
+    parallel_for(n / 2, nthreads, [&](size_t idx) {
       const size_t k = (idx / half) * m, j = idx % half;
       G1 t = a[k + j + half];
       if (j) g1_mul_var(t, tw[j * stride]);
@@ -888,7 +947,7 @@ void g1_fft(u64* points, size_t logn, const u64* omega_std,
   if (scale_std) {
     Fp sc;
     std::memcpy(sc.v, scale_std, 32);
-    in_threads(n, [&](size_t i) { g1_mul_var(a[i], sc); });
+    parallel_for(n, nthreads, [&](size_t i) { g1_mul_var(a[i], sc); });
   }
   g1_batch_to_affine(a, points);
 }
@@ -913,7 +972,9 @@ void fp_add_scalar_batch(int field, const u64* a, const u64* s /*4 limbs*/,
 }
 
 // out[i] = a[i]*s + b[i] mod p: the quotient's y-combination
-// (acc = acc*y + e) as ONE pass instead of scale-then-add two-pass.
+// (acc = acc*y + e) as ONE pass instead of scale-then-add two-pass, and one
+// product an element: with s in Montgomery form mont(a, s*R) = a*s, already
+// in standard form.
 void fp_axpy_batch(int field, const u64* a, const u64* s /*4 limbs*/,
                    const u64* b, u64* out, size_t n) {
   const FpCtx& C = pick(field);
@@ -924,12 +985,45 @@ void fp_axpy_batch(int field, const u64* a, const u64* s /*4 limbs*/,
     Fp am, bm, r;
     std::memcpy(am.v, a + 4 * i, 32);
     std::memcpy(bm.v, b + 4 * i, 32);
-    to_mont(am, am, C);
     fp_mul(r, am, sm, C);
-    from_mont(r, r, C);
     fp_add(r, r, bm, C);
     std::memcpy(out + 4 * i, r.v, 32);
   }
+}
+
+// P_S = sum of scalars[k] * polys[k] over the entries k with sets[k] == S,
+// for every S < nsets, into out ([nsets, n, 4], standard form). Entry k has
+// lens[k] <= n coefficients; its rows past lens[k] add nothing, so a short
+// polynomial needs no padded copy. One product an element an entry, by
+// mont(a, s*R) = a*s with each scalar in Montgomery form. The rows are cut
+// into blocks, the threads' work units; a block takes the entries in order.
+void fp_combine_sets(int field, const u64* const* polys, const size_t* lens,
+                     const u64* scalars, const size_t* sets, size_t nentries,
+                     size_t nsets, size_t n, u64* out, int nthreads) {
+  const FpCtx& C = pick(field);
+  std::vector<Fp> sm(nentries);
+  for (size_t k = 0; k < nentries; ++k) {
+    std::memcpy(sm[k].v, scalars + 4 * k, 32);
+    to_mont(sm[k], sm[k], C);
+  }
+  constexpr size_t kBlock = 1024;
+  parallel_for((n + kBlock - 1) / kBlock, nthreads, [&](size_t b) {
+    const size_t lo = b * kBlock, hi = std::min(n, lo + kBlock);
+    for (size_t s = 0; s < nsets; ++s)
+      std::memset(out + 4 * (s * n + lo), 0, 32 * (hi - lo));
+    for (size_t k = 0; k < nentries; ++k) {
+      const u64* a = polys[k];
+      u64* dst = out + 4 * sets[k] * n;
+      for (size_t i = lo, end = std::min(hi, lens[k]); i < end; ++i) {
+        Fp ai, acc, r;
+        std::memcpy(ai.v, a + 4 * i, 32);
+        std::memcpy(acc.v, dst + 4 * i, 32);
+        fp_mul(r, ai, sm[k], C);
+        fp_add(acc, acc, r, C);
+        std::memcpy(dst + 4 * i, acc.v, 32);
+      }
+    }
+  });
 }
 
 void fp_scale_batch(int field, const u64* a, const u64* s /*4 limbs*/, u64* out, size_t n) {

@@ -161,8 +161,9 @@ def shplonk_open(srs: SRS, domain: Domain, entries: list[OpenEntry], transcript,
     h = sum_k v^k (p_k - r_k) / Z_{S_k} is linear in the p_k, and the entries
     share a few point sets S (eight in a committee prove of 231 entries), so
     the entries are first combined a set, P_S = sum_{k in S} v^k p_k in
-    coefficient form and R_S = sum v^k r_k as a handful of ints (span
-    `multiopen/h_poly/combine`), and everything that touches the domain
+    coefficient form, every set in one threaded native call, and R_S =
+    sum v^k r_k as a handful of ints (span `multiopen/h_poly/combine`, with
+    `threads`), and everything that touches the domain
     happens once a SET: one NTT, one remainder put on the domain
     (`multiopen/h_poly/remainder`), one division by Z_S. The linearisation
     L = sum_S Z_{T - S}(u) (P_S - R_S(u)) - Z_T(u) h is taken from the same
@@ -183,19 +184,22 @@ def shplonk_open(srs: SRS, domain: Domain, entries: list[OpenEntry], transcript,
                 all_points.append(p)
 
     with span("multiopen/h_poly", entries=len(entries)):
-        with span("multiopen/h_poly/combine"):
-            p_sets: dict = {}   # point set -> P_S, [n,4] coefficients
+        with span("multiopen/h_poly/combine", threads=host.threads()):
             r_sets: dict = {}   # point set -> R_S, as many ints as points
-            zero = B.zeros(n)
+            weights = []
             vk = 1
             for e in entries:
-                p_sets[e.points] = bk.axpy(_pad(e.coeffs, n), vk,
-                                           p_sets.get(e.points, zero))
                 r_sets[e.points] = [
                     (a + c * vk) % R for a, c in zip(
                         r_sets.get(e.points, [0] * len(e.points)),
                         _interp(e.points, e.evals))]
+                weights.append(vk)
                 vk = vk * v % R
+            rows = {s: i for i, s in enumerate(r_sets)}  # in first use
+            stack = host.fp_combine_sets(
+                host.FR, [e.coeffs for e in entries], weights,
+                [rows[e.points] for e in entries], n)
+            p_sets = dict(zip(rows, stack))  # point set -> P_S, [n,4] coeffs
         annotate(sets=len(p_sets))
 
         omegas = bk.powers(domain.omega, n)
@@ -231,14 +235,6 @@ def shplonk_open(srs: SRS, domain: Domain, entries: list[OpenEntry], transcript,
         w2_coeffs = domain.lagrange_to_coeff(w2_evals, bk)
     w2 = commit(srs, w2_coeffs, bk)
     transcript.write_point(w2)
-
-
-def _pad(coeffs, n):
-    if coeffs.shape[0] >= n:
-        return coeffs
-    out = np.zeros((n, 4), dtype=np.uint64)
-    out[:coeffs.shape[0]] = coeffs
-    return out
 
 
 def shplonk_accumulate(srs: SRS, entries: list[OpenEntry], transcript):

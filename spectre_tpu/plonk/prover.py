@@ -381,25 +381,30 @@ def prove(pk: ProvingKey, srs: SRS, assignment: Assignment,
 
     x = tr.challenge()
 
-    # --- 6. evaluations per the query plan ---
+    # --- 6. evaluations per the query plan: each polynomial at all of its
+    #        points in one native call, written in the plan's order ---
     plan = pk.vk.query_plan()
-
-    with phase("prove/evals"), span("evals/horner", queries=len(plan)):
-        evals = {}
-        for key, rot in plan:
-            pt = pk.vk.rotation_point(x, rot)
-            ev = host.fp_horner(host.FR, poly_for(key), pt)
-            evals[(key, rot)] = ev
-            tr.write_scalar(ev)
-
-    # --- 7. SHPLONK multiopen ---
     by_key: dict = {}
     for key, rot in plan:
         by_key.setdefault(key, []).append(rot)
+    rot_points = {rot: pk.vk.rotation_point(x, rot) for _, rot in plan}
+
+    with phase("prove/evals"), span("evals/horner", queries=len(plan),
+                                    polys=len(by_key), threads=host.threads()):
+        values = host.fp_eval_many(
+            host.FR, [poly_for(key) for key in by_key],
+            [[rot_points[r] for r in rots] for rots in by_key.values()])
+        evals = {(key, r): ev
+                 for (key, rots), evs in zip(by_key.items(), values)
+                 for r, ev in zip(rots, evs)}
+        for key, rot in plan:
+            tr.write_scalar(evals[(key, rot)])
+
+    # --- 7. SHPLONK multiopen ---
     with phase("prove/multiopen"):
         entries = []
         for key, rots in by_key.items():
-            pts = tuple(pk.vk.rotation_point(x, r) for r in rots)
+            pts = tuple(rot_points[r] for r in rots)
             evs = tuple(evals[(key, r)] for r in rots)
             entries.append(kzg.OpenEntry(poly_for(key), None, pts, evs))
         kzg.shplonk_open(srs, dom, entries, tr, bk)
