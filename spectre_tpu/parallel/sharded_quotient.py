@@ -447,6 +447,9 @@ class MeshQuotientEngine:
         self.plan = plan
         self.dom = dom
         self.m = dom.n_ext
+        # the resident stores keep this mesh's row-sharded arrays apart
+        # from the single device's and from another mesh's
+        self.placement = (self.name, plan.key)
         self._logm = self.m.bit_length() - 1
         self._row_sh = NamedSharding(plan.batch_mesh,
                                      P(plan.batch_axis, None))

@@ -8,6 +8,7 @@ rotations) is the shared contract between prover and verifier.
 from __future__ import annotations
 
 import hashlib
+import os
 from dataclasses import dataclass
 
 from ..fields import bn254
@@ -22,6 +23,36 @@ R = bn254.R
 
 # rotation tag for the "last usable row" query used by permutation chunk links
 ROT_LAST = "last"
+
+# The kinds of the proving key's own columns (selectors, fixed, sigmas,
+# tables, the SHA region's selectors and round constants): their extended
+# forms are the same on every prove of a key, so both quotient paths keep
+# them extended a key (`prover._quotient_host` on the host,
+# `quotient_device` on the device), under `ext_cache_budget_bytes`.
+KEY_KINDS = ("q", "fix", "sig", "tab", "shq", "shk")
+
+
+def meminfo_total_bytes():
+    try:
+        with open("/proc/meminfo") as f:
+            return int(f.readline().split()[1]) * 1024
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def ext_cache_budget_bytes(memory_bytes=None) -> int:
+    """Bytes a proving key's extended columns may keep resident, in the
+    memory they live in: SPECTRE_EXT_CACHE_MB where set (a fraction of a MB
+    allowed), else 15 % of `memory_bytes` (the device's `bytes_limit` for
+    the device store), else min(16 GB, 15 % of RAM) (the host store, and a
+    device that reports no limit). Past it, columns are extended a prove."""
+    mb = os.environ.get("SPECTRE_EXT_CACHE_MB")
+    if mb is not None:
+        return int(float(mb) * (1 << 20))
+    if memory_bytes:
+        return int(memory_bytes * 0.15)
+    total = meminfo_total_bytes()
+    return 16 << 30 if total is None else min(16 << 30, int(total * 0.15))
 
 
 @dataclass
@@ -196,16 +227,30 @@ class ProvingKey:
     sha_selector_polys: list = None
     sha_k_poly: object = None
 
+    def column_poly(self, key):
+        """Coefficient form of one of the key's own columns (a `KEY_KINDS`
+        key)."""
+        kind, j = key
+        if kind == "shk":
+            return self.sha_k_poly
+        return {"q": self.selector_polys, "fix": self.fixed_polys,
+                "sig": self.sigma_polys, "tab": self.table_polys,
+                "shq": self.sha_selector_polys}[kind][j]
+
     def release_ext_cache(self):
-        """Drop the prover's cached extended-domain forms of the fixed
-        columns (populated lazily by `_quotient_host`, ~GBs at k=21). A
-        service holding several pks calls this on the idle families so
-        peaks don't stack (`prover_service/state.py`); with them its
-        value columns' limb arrays (`prover._key_limbs`)."""
+        """Drop the prover's cached extended-domain forms of the key's
+        columns: the host's (populated lazily by `_quotient_host`, ~GBs at
+        k=21) and the device's (`quotient_device._key_ext`, filled on the
+        key's first device prove). A service holding several pks calls this
+        on the idle families so peaks don't stack
+        (`prover_service/state.py`); with them its value columns' limb
+        arrays (`prover._key_limbs`)."""
         from .prover import release_key_columns
+        from .quotient_device import release_key_ext
 
         self.__dict__.pop("_ext_fixed_cache", None)
         release_key_columns(self)
+        release_key_ext(self)
 
 
 def keygen(srs: SRS, cfg: CircuitConfig, fixed_columns: list, selectors: list,

@@ -23,7 +23,8 @@ from .constraint_system import (Assignment, NUM_H_CHUNKS, PERM_CHUNK,
                                 permute_lookup)
 from .domain import DELTA, Domain
 from .expressions import all_expressions, perm_column_keys
-from .keygen import ProvingKey, ROT_LAST
+from .keygen import (KEY_KINDS, ProvingKey, ROT_LAST, ext_cache_budget_bytes,
+                     meminfo_total_bytes)
 from .srs import SRS
 from .transcript import Blake2bTranscript
 
@@ -337,29 +338,15 @@ def prove(pk: ProvingKey, srs: SRS, assignment: Assignment,
             polys[("inst", j)] = c
 
     def poly_for(key):
-        kind, j = key
-        if key in polys:
-            return polys[key]
-        if kind == "q":
-            return pk.selector_polys[j]
-        if kind == "fix":
-            return pk.fixed_polys[j]
-        if kind == "sig":
-            return pk.sigma_polys[j]
-        if kind == "tab":
-            return pk.table_polys[j]
-        if kind == "shq":
-            return pk.sha_selector_polys[j]
-        if kind == "shk":
-            return pk.sha_k_poly
-        raise KeyError(key)
+        return polys[key] if key in polys else pk.column_poly(key)
 
     if getattr(bk, "device_quotient", False):
-        # device-resident path: the whole identity as one jitted XLA
-        # program (quotient_device.py)
+        # device-resident path (quotient_device.py): the key's columns stay
+        # extended on the device a key
         from .quotient_device import compute_quotient
         with phase("prove/quotient"):
-            h_coeffs = compute_quotient(cfg, dom, poly_for, beta, gamma, y)
+            h_coeffs = compute_quotient(cfg, dom, poly_for, beta, gamma, y,
+                                        pk=pk)
     else:
         h_coeffs = _quotient_host(cfg, dom, bk, pk, polys, beta, gamma, y)
     # deg h <= 3n-4, so the top chunk must vanish. A nonzero tail means the
@@ -492,14 +479,6 @@ class _BudgetedExtLRU:
         return arr
 
 
-def _meminfo_total_bytes():
-    try:
-        with open("/proc/meminfo") as f:
-            return int(f.readline().split()[1]) * 1024
-    except (OSError, ValueError, IndexError):
-        return None
-
-
 def _quotient_budget_bytes(pk_ext_budget: int) -> int:
     """LRU budget: explicit env, else 30% of RAM MINUS the coexisting
     pk-resident fixed-column cache budget — the two caches draw from one
@@ -509,7 +488,7 @@ def _quotient_budget_bytes(pk_ext_budget: int) -> int:
     mb = _os.environ.get("SPECTRE_QUOTIENT_CACHE_MB")
     if mb is not None:
         return int(mb) << 20
-    total = _meminfo_total_bytes()
+    total = meminfo_total_bytes()
     if total is None:
         return 8 << 30
     return max(1 << 30, int(total * 0.30) - pk_ext_budget)
@@ -519,24 +498,16 @@ def _quotient_host(cfg, dom, bk, pk, polys, beta, gamma, y):
     """The original host-orchestrated quotient: per-op backend calls over
     the extended coset (CPU path)."""
     n, u = cfg.n, cfg.usable_rows
-    # Circuit-FIXED columns (selectors, fixed, sigmas, tables) have the same
-    # extended form every prove; their ~n-per-circuit 4n-NTTs were about half
-    # of quotient wall-clock (BASELINE.md r4: quotient 41-49% of prove).
-    # Cache them on the pk object (in-memory only, never persisted): a
-    # prover service re-proving against one pk pays the NTTs once.
-    _FIXED_KINDS = ("q", "fix", "sig", "tab", "shq", "shk")
+    # The key's own columns (KEY_KINDS) have the same extended form every
+    # prove; their ~n-per-circuit 4n-NTTs were about half of quotient
+    # wall-clock (BASELINE.md r4: quotient 41-49% of prove). Cache them on
+    # the pk object (in-memory only, never persisted): a prover service
+    # re-proving against one pk pays the NTTs once.
     pk_ext = pk.__dict__.setdefault("_ext_fixed_cache", {})
     # cap resident bytes per pk (idle-circuit caches stack in a service —
     # see ProvingKey.release_ext_cache); over budget we compute transiently.
-    # Default: min(16 GB, 15% of RAM) — shares one pool with the LRU below
-    import os as _os
-    _mb = _os.environ.get("SPECTRE_EXT_CACHE_MB")
-    if _mb is not None:
-        ext_budget = int(_mb) << 20
-    else:
-        _total = _meminfo_total_bytes()
-        ext_budget = (16 << 30 if _total is None
-                      else min(16 << 30, int(_total * 0.15)))
+    # The budget shares one pool with the LRU below
+    ext_budget = ext_cache_budget_bytes()
     lru = _BudgetedExtLRU(_quotient_budget_bytes(ext_budget))
 
     def _within_budget(arr):
@@ -549,22 +520,10 @@ def _quotient_host(cfg, dom, bk, pk, polys, beta, gamma, y):
             return hit
         if key in polys:
             return lru.put(key, dom.coeff_to_extended(polys[key], bk))
-        if key[0] in _FIXED_KINDS:
+        if key[0] in KEY_KINDS:
             hit = pk_ext.get(key)
             if hit is None:
-                if key[0] == "q":
-                    coeffs = pk.selector_polys[key[1]]
-                elif key[0] == "fix":
-                    coeffs = pk.fixed_polys[key[1]]
-                elif key[0] == "sig":
-                    coeffs = pk.sigma_polys[key[1]]
-                elif key[0] == "tab":
-                    coeffs = pk.table_polys[key[1]]
-                elif key[0] == "shq":
-                    coeffs = pk.sha_selector_polys[key[1]]
-                else:
-                    coeffs = pk.sha_k_poly
-                hit = dom.coeff_to_extended(coeffs, bk)
+                hit = dom.coeff_to_extended(pk.column_poly(key), bk)
                 if _within_budget(hit):
                     pk_ext[key] = hit
                 else:

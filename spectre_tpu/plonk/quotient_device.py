@@ -26,6 +26,21 @@ as shard_map programs over the ShardingPlan mesh. Ineligible shapes or a
 mesh-path failure fall back here VISIBLY — the `quotient_sharded_degraded`
 ServiceHealth counter plus a provenance event — never silently.
 
+What a prove extends and what stays resident: the columns the prove
+itself made (advice, lookup, SHA region, grand products, instance) are
+extended every prove; the proving key's own columns (`keygen.KEY_KINDS`:
+selectors, fixed, sigmas, tables, the SHA region's selectors and round
+constants) are extended on the key's first prove and kept on the device
+for the next (`_key_ext`, a key and an engine placement), and the three
+synthetic rows (l0, l_last, l_blind) beside `xcol` in `_static_cache`, a
+(cfg, k). What stays is bounded by `keygen.ext_cache_budget_bytes`
+(SPECTRE_EXT_CACHE_MB, else 15 % of the device's memory): the synthetic
+rows first, then the key's columns in the expression tree's order while
+they fit; the rest is extended every prove. A fill extends in the same
+chunks as every other column, under `_fill_lock`;
+`ProvingKey.release_ext_cache` drops the key's store and its circuit's
+synthetic rows.
+
 Design note (learned the hard way): tracing the WHOLE tree into one jitted
 XLA program blows up LLVM codegen on the CPU backend (`Cannot allocate
 memory` from the execution engine at ~6k fused scan-heavy ops). The ops are
@@ -34,8 +49,10 @@ therefore dispatched EAGERLY through a small set of jitted primitives
 the device win lives (each op is HBM-bandwidth-bound either way), and
 compile cost stays bounded per primitive shape.
 
-Spans (observability/tracing.py): `quotient/extend` holds the coefficient
-fetch, the stacking (`quotient/extend/encode`: a chunk's columns as their n
+Spans (observability/tracing.py): `quotient/extend` (with `resident`, the
+columns read from the stores, `extended`, the columns extended, and
+`resident_bytes`, what the stores hold) holds the coefficient fetch, the
+stacking (`quotient/extend/encode`: a chunk's columns as their n
 rows of 32 bytes, with the bytes the engine's `lde` then ships; the limb
 split and the zero rows from n to 4n are the engine's first device step,
 `ops/limbs.py:split_limbs16`) and the LDE dispatches
@@ -58,22 +75,36 @@ mesh-vs-host in tests/test_quotient_sharded.py).
 from __future__ import annotations
 
 import os
+import threading
 import time
+import weakref
 
 import numpy as np
 
 from ..fields import bn254
-from ..observability.tracing import on_device, span
+from ..observability.tracing import annotate, on_device, span
 from ..ops.msm import _TableLRU, _record_event
 from .constraint_system import CircuitConfig
 from .domain import COSET_GEN, Domain
 from .expressions import all_expressions, referenced_keys
-from .keygen import ROT_LAST
+from .keygen import KEY_KINDS, ROT_LAST, ext_cache_budget_bytes
 
 R = bn254.R
 
 _jit_helpers: dict = {}
 _static_cache: dict = {}
+
+# The proving key's columns (`KEY_KINDS`) extended on the device, a key:
+# id(pk) -> (a weak reference to the key, {engine placement: {column key:
+# extended array}}). Held here and not on the key, so a pickled key holds
+# what it always held; bounded by the byte budget a prove and as
+# `prover._key_limbs` is, dropped by `ProvingKey.release_ext_cache`.
+_key_ext: dict = {}
+# held by a prove that fills a store: two jobs proving one key extend its
+# columns once
+_fill_lock = threading.Lock()
+
+SYNTHETIC = (("_l0",), ("_llast",), ("_lblind",))
 
 # runner registry (trace-cache hygiene contract, parallel/plan.py):
 # analysis/trace_lint cross-checks these (builder, cache) pairs against
@@ -166,6 +197,35 @@ def _ext_chunk(m: int) -> int:
     return min(8, 1 << (cap.bit_length() - 1))
 
 
+def _key_store(pk, placement) -> dict:
+    """The device store of `pk`'s columns placed as `placement` says (under
+    `_fill_lock`)."""
+    hit = _key_ext.get(id(pk))
+    if hit is None or hit[0]() is not pk:
+        if len(_key_ext) > 4:
+            _key_ext.clear()
+        hit = _key_ext[id(pk)] = (weakref.ref(pk), {})
+    return hit[1].setdefault(placement, {})
+
+
+def release_key_ext(pk):
+    """Drop the key's extended columns and its circuit's synthetic rows
+    (`ProvingKey.release_ext_cache`)."""
+    cfg = pk.vk.config
+    with _fill_lock:
+        _key_ext.pop(id(pk), None)
+        _static_cache.get((cfg, cfg.k), {}).pop("ext", None)
+
+
+def _device_budget_bytes() -> int:
+    """`ext_cache_budget_bytes` of the device's memory, where it reports
+    its limit."""
+    import jax
+
+    stats = jax.devices()[0].memory_stats() or {}
+    return ext_cache_budget_bytes(stats.get("bytes_limit"))
+
+
 class _DeviceCtx:
     """all_expressions context over device-resident [m, 16] Montgomery
     tensors, dispatching through the jitted primitives."""
@@ -225,6 +285,7 @@ class _LocalEngine:
     through the same seam the mesh engine plugs into."""
 
     name = "local"
+    placement = name      # what the resident stores key its arrays by
 
     def __init__(self, dom: Domain):
         self.dom = dom
@@ -314,26 +375,29 @@ def _mesh_engine(dom: Domain):
 
 
 def compute_quotient(cfg: CircuitConfig, dom: Domain, fetch_coeffs,
-                     beta: int, gamma: int, y: int) -> np.ndarray:
+                     beta: int, gamma: int, y: int, pk=None) -> np.ndarray:
     """Device quotient: returns h coefficients as [4n, 4] u64 standard form
     (drop-in for the host path's extended_to_coeff output).
 
     fetch_coeffs(key) -> [<=n, 4] u64 coefficient-form poly for every column
-    key the expression tree reads."""
+    key the expression tree reads. pk: the proving key whose columns the
+    `KEY_KINDS` keys are; given, they stay extended on the device for its
+    next prove (None: every column is extended)."""
     engine = _mesh_engine(dom)
     if engine is not None:
         try:
             return _quotient_impl(cfg, dom, fetch_coeffs, beta, gamma, y,
-                                  engine)
+                                  engine, pk)
         except Exception as e:  # mesh-path failure: fall back, visibly
             _degrade("mesh_exception", error=f"{type(e).__name__}: {e}",
                      n_ext=dom.n_ext)
     return _quotient_impl(cfg, dom, fetch_coeffs, beta, gamma, y,
-                          _LocalEngine(dom))
+                          _LocalEngine(dom), pk)
 
 
 def _quotient_impl(cfg: CircuitConfig, dom: Domain, fetch_coeffs,
-                   beta: int, gamma: int, y: int, engine) -> np.ndarray:
+                   beta: int, gamma: int, y: int, engine,
+                   pk=None) -> np.ndarray:
     import jax.numpy as jnp
 
     from ..ops import limbs as L16
@@ -396,28 +460,57 @@ def _quotient_impl(cfg: CircuitConfig, dom: Domain, fetch_coeffs,
     def ext_of_coeffs(arr_u64):
         return ext_of_many([arr_u64])[0]
 
-    with span("quotient/extend", n_ext=m):
-        # synthetic rows extend as one batched call; real columns prefetch
-        # in fixed-size chunks enumerated from the expression tree
-        l0_e, llast_e, lblind_e = ext_of_many(
-            [st["l0"], st["llast"], st["lblind"]])
-        cols: dict = {
-            ("_l0",): l0_e,
-            ("_llast",): llast_e,
-            ("_lblind",): lblind_e,
-            ("_xcol",): engine.device_col(st["xcol"]),
-        }
-        plan = [k for k in referenced_keys(cfg) if k not in cols]
+    plan = referenced_keys(cfg)      # the synthetic rows are not among them
+
+    def extend(resident: dict):
+        """Every column `resident` lacks, extended; returns them all and
+        how many were (padding not counted). The synthetic rows extend as
+        one batched call, the other columns in fixed-size chunks
+        enumerated from the expression tree."""
+        cols = dict(resident)
+        if SYNTHETIC[0] not in cols:
+            cols.update(zip(SYNTHETIC, ext_of_many(
+                [st["l0"], st["llast"], st["lblind"]])))
+        todo = [k for k in plan if k not in cols]
         chunk_sz = engine.chunk(_ext_chunk(m))
-        for base in range(0, len(plan), chunk_sz):
-            chunk = plan[base:base + chunk_sz]
+        for base in range(0, len(todo), chunk_sz):
+            chunk = todo[base:base + chunk_sz]
             # pad the tail chunk with the first key so the kernel sees one
             # batch shape per domain (duplicates are free — same NTT,
             # sliced)
             padded = chunk + [chunk[0]] * (chunk_sz - len(chunk))
             outs = ext_of_many([fetch_coeffs(k) for k in padded])
-            for k_, o in zip(chunk, outs):
-                cols[k_] = o
+            cols.update(zip(chunk, outs))
+        return cols, len(cols) - len(resident)
+
+    with span("quotient/extend", n_ext=m):
+        # What stays extended on the device: the synthetic rows beside
+        # `xcol` (they depend on (cfg, k) alone), then the key's columns in
+        # the tree's order, while their bytes fit the budget. A store keeps
+        # exactly that: a fill extends what it lacks under `_fill_lock`
+        fits = _device_budget_bytes() // (m * 16 * 4)
+        keys = [k for k in plan if pk is not None and k[0] in KEY_KINDS]
+        wanted = (list(SYNTHETIC) + keys)[:fits] if fits >= 3 else []
+        filled = None
+        with _fill_lock:
+            synth = st.setdefault("ext", {}).setdefault(engine.placement, {})
+            keyed = _key_store(pk, engine.placement) if pk is not None \
+                else {}
+            stores = (synth, keyed)
+            for store in stores:
+                for k in [k for k in store if k not in wanted]:
+                    del store[k]
+            resident = {k: store[k] for store in stores for k in wanted
+                        if k in store}
+            if len(resident) < len(wanted):
+                filled = extend(resident)
+                for k in wanted:
+                    (synth if k in SYNTHETIC else keyed)[k] = filled[0][k]
+            held = sum(a.nbytes for store in stores for a in store.values())
+        cols, extended = filled or extend(resident)
+        annotate(resident=len(resident), extended=extended,
+                 resident_bytes=held)
+        cols[("_xcol",)] = engine.device_col(st["xcol"])
 
     class LazyCols(dict):
         # safety net: any key the recorder missed still materializes

@@ -170,6 +170,9 @@ def tiny_tpu_prove(tiny):
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("SPECTRE_MESH_SHAPE", "1x1")
         pk, bk = _tpu_keygen(tiny)
+        # a process's first prove: nothing of the circuit kept on the
+        # device by an earlier prove of this worker
+        pk.release_ext_cache()
         with tracing.trace("tiny-tpu-prove") as tr:
             proof = _seeded_prove(tiny, pk, bk)
     return types.SimpleNamespace(trace=tr, proof=proof, pk=pk, srs=tiny.srs,

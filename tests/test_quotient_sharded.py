@@ -66,7 +66,7 @@ def _capture_quotient_inputs(mk_fixture):
                     "shq": pk_.sha_selector_polys}[kind][j]
 
         cap.update(cfg=cfg_, dom=dom_, fetch=fetch, beta=beta,
-                   gamma=gamma, y=y, h_host=h_host)
+                   gamma=gamma, y=y, h_host=h_host, pk=pk_)
         return h_host
 
     P._quotient_host = wrapped
@@ -117,9 +117,9 @@ def _captured_k11():
     return _CAPTURED[11]
 
 
-def _run_quotient(cap):
+def _run_quotient(cap, pk=None):
     return QD.compute_quotient(cap["cfg"], cap["dom"], cap["fetch"],
-                               cap["beta"], cap["gamma"], cap["y"])
+                               cap["beta"], cap["gamma"], cap["y"], pk=pk)
 
 
 # ---------------------------------------------------------------------------
@@ -222,6 +222,52 @@ def test_mesh_lde_splits_the_packed_stack_where_it_lies(monkeypatch):
     want[:, :16] = L16.u64limbs_to_u16limbs(
         rows.reshape(-1, 4)).reshape(8, 16, 16)
     assert np.array_equal(np.asarray(got), want)
+
+
+@needs8
+def test_mesh_engine_keeps_its_own_store(monkeypatch):
+    """The proving key's extended columns are kept a key AND a placement:
+    the mesh engine's second quotient reads its own row-sharded arrays,
+    the single device keeps a store of its own, and every h is the host
+    path's."""
+    from spectre_tpu.observability import tracing
+    from spectre_tpu.parallel.plan import current_plan
+    from spectre_tpu.plonk.expressions import referenced_keys
+    from spectre_tpu.plonk.keygen import KEY_KINDS
+
+    cap = _captured_k6()
+    pk = cap["pk"]
+    own = [k for k in referenced_keys(cap["cfg"]) if k[0] in KEY_KINDS]
+    monkeypatch.delenv("SPECTRE_EXT_CACHE_MB", raising=False)
+    monkeypatch.setenv("SPECTRE_MESH_SHAPE", "4x2")
+    mesh = ("sharded", current_plan().key)
+
+    def run():
+        with tracing.trace("mesh-resident") as tr:
+            h = _run_quotient(cap, pk)
+        assert np.array_equal(h, cap["h_host"])
+        (ext,) = [s for s in tr.root.children
+                  if s.name == "quotient/extend"]
+        return ext.meta["resident"]
+
+    before = HEALTH.get("quotient_sharded_degraded")
+    pk.release_ext_cache()
+    try:
+        monkeypatch.setenv("SPECTRE_SHARD_QUOTIENT_MIN_LOGN", "0")
+        assert [run(), run()] == [0, len(own) + 3]
+        stores = QD._key_ext[id(pk)][1]
+        assert set(stores) == {mesh}
+        assert all(len(a.sharding.device_set) == 8
+                   for a in stores[mesh].values())
+        # the single device (below the size gate) fills its own store
+        monkeypatch.delenv("SPECTRE_SHARD_QUOTIENT_MIN_LOGN")
+        assert [run(), run()] == [0, len(own) + 3]
+        assert set(stores) == {mesh, QD._LocalEngine.placement}
+        assert all(len(a.sharding.device_set) == 1
+                   for a in stores[QD._LocalEngine.placement].values())
+    finally:
+        pk.release_ext_cache()
+    assert HEALTH.get("quotient_sharded_degraded") == before
 
 
 @needs8
