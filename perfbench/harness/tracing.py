@@ -1,7 +1,6 @@
 """What only a `--trace 1` run has: a wrapper round every public operation
 of the backend object, and the profiler round whole calls of it. A
-`--trace 0` run uses `hold` and `restore` (through the warm-up's wrapper)
-and nothing else of this file.
+`--trace 0` run uses nothing of this file.
 
 The device tracer of this chip writes every XLA operation of every loop
 iteration (one 2^14 MSM of 0.5 s, as it was in PR 28: a 156 MB file and
@@ -68,21 +67,12 @@ def restore(backend, held: dict):
 
 
 # the wrapped calls this thread is inside of, innermost last: one counter of
-# the wrapped calls made inside each. Shared by every wrapper of this
-# module and by the warm-up's (`warmup.EachShape`), which stacks on top
+# the wrapped calls made inside each
 _LOCAL = threading.local()
 
 
 def _stack() -> list:
     return _LOCAL.__dict__.setdefault("stack", [])
-
-
-def note_inner_call():
-    """A call that another wrapper answered without passing it down still
-    makes the call it came from one that holds calls."""
-    stack = _stack()
-    if stack:
-        stack[-1][0] += 1
 
 
 @dataclass

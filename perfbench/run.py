@@ -26,7 +26,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 sys.path.insert(1, os.path.dirname(HERE))
 
-from harness import cells, device, hostside, loadgen, warmup, workdir  # noqa: E402
+from harness import cells, device, hostside, loadgen, workdir  # noqa: E402
 
 
 EXIT_AFTER_RESULT = False     # set when run as the command
@@ -63,33 +63,32 @@ def run(opts, bench_path=None) -> int:
     reference = cells.load_plugin("reference", config["circuit"])
     served = cells.load_plugin("servers", config.get("server", "single")) \
         .boot(config, traffic, paths)
+    healthy = time.time()         # boot returns once the service says so
     try:
-        return measure(opts, cell, dev, paths, requests, reference, served)
+        return measure(opts, cell, dev, paths, requests, reference, served,
+                       healthy)
     finally:
         served.close()
 
 
 def warm_up(opts, config, requests, served, client, methods):
-    """One served prove of a committee of its own. The cyclic GC is paused
-    for it (tracing and lowering 60 programs allocates tens of millions of
-    objects that hold no cycles), and so is the host verifier, which is
-    plain Python with nothing to warm; and a backend operation the
-    configuration names under `warmup` runs each shape a few times and is
-    answered from its last run after (harness/warmup.py says what that
-    reaches on which backend). All three only shorten set-up, which every
-    run of every later check pays in full."""
+    """One served prove of a committee of its own: the only entry of the
+    program that puts every program the window runs into this process's
+    caches and leaves the proving key's stores as a served prove leaves
+    them. The cyclic GC is paused for it (tracing and lowering 60 programs
+    allocates tens of millions of objects that hold no cycles) and one full
+    collection made after, so that the window's first prove does not pay
+    it; and the host verifier, plain Python with nothing to warm, is off.
+    All of these only shorten set-up, which every run of every later check
+    pays in full."""
     warm = loadgen.Sent(index=-1,
                         request=requests.make(config, opts.seed, "warmup"))
     gc.disable()
     policy = os.environ.get("SPECTRE_SELF_VERIFY")
     os.environ["SPECTRE_SELF_VERIFY"] = "off"
-    shapes = warmup.EachShape(
-        served.backend(), (config.get("warmup") or {}).get("run_each_shape"),
-        when=served.key_ready)
     warm.t_send = time.time()
     try:
-        with shapes:
-            warm.result = client._call(methods[0], warm.request["params"])
+        warm.result = client._call(methods[0], warm.request["params"])
     finally:
         if policy is None:
             del os.environ["SPECTRE_SELF_VERIFY"]
@@ -97,14 +96,17 @@ def warm_up(opts, config, requests, served, client, methods):
             os.environ["SPECTRE_SELF_VERIFY"] = policy
         gc.enable()
     warm.t_done = time.time()
+    t = time.time()
+    gc.collect()
+    log(f"warm-up: a full collection of set-up's objects, "
+        f"{time.time() - t:.2f}s")
     loadgen.attach_records(client, methods[1], [warm])
     if warm.error:
         raise RuntimeError(f"warm-up request: {warm.error}")
     comp = warm.manifest["compile"]
     log(f"warm-up prove {warm.t_done - warm.t_send:.1f}s "
         f"(compile.count={comp['count']} compile.seconds={comp['seconds']} "
-        f"persistent_cache={comp.get('persistent_cache')}); backend calls "
-        f"in it: {json.dumps(shapes.summary())}")
+        f"persistent_cache={comp.get('persistent_cache')})")
     by_kind: dict = {}
     for e in comp.get("events", ()):
         if "seconds" in e:
@@ -112,6 +114,22 @@ def warm_up(opts, config, requests, served, client, methods):
     log("warm-up compile events, seconds by kind: " + json.dumps(by_kind))
     log("warm-up phase seconds: " + json.dumps(warm.manifest["phase_seconds"]))
     return warm
+
+
+# the spans whose counters say what a prove found of the key's state (the
+# quotient's resident columns, the key's columns marshalled from ints):
+# printed a request, so that a window's first prove can be held to its others
+STATE_SPANS = ("quotient/extend", "grand_products/perm_chunk")
+
+
+def warm_state(sent) -> dict:
+    out = {e["name"]: e["args"] for e in sent.spans
+           if e.get("ph") == "X" and e.get("name") in STATE_SPANS
+           and e.get("args")}
+    out["span_counts"] = {k: v for k, v in
+                          sent.manifest.get("span_counts", {}).items()
+                          if k.startswith(STATE_SPANS)}
+    return out
 
 
 def trace_whole_calls(calls, paths, dev) -> tuple:
@@ -152,7 +170,8 @@ def log_trace(est, calls):
         f"calls")
 
 
-def measure(opts, cell, dev, paths, requests, reference, served) -> int:
+def measure(opts, cell, dev, paths, requests, reference, served,
+            healthy) -> int:
     config, traffic = cell.config, cell.traffic
     methods = (requests.METHOD, requests.SUBMIT_METHOD)
     client = served.client()
@@ -193,7 +212,8 @@ def measure(opts, cell, dev, paths, requests, reference, served) -> int:
         if s.manifest:
             log(f"request {s.index}: sent at {s.t_send - window.t_first:.3f}s, "
                 f"{s.t_done - s.t_send:.3f}s; phase "
-                f"seconds: {json.dumps(s.manifest['phase_seconds'])}")
+                f"seconds: {json.dumps(s.manifest['phase_seconds'])}; "
+                f"state: {json.dumps(warm_state(s))}")
 
     session = trace = None
     if traced:
@@ -205,7 +225,7 @@ def measure(opts, cell, dev, paths, requests, reference, served) -> int:
 
     ctx = {"config": config, "traffic": traffic, "cell": cell.name,
            "device": dev, "setup_s": setup_s, "window": window,
-           "warmup": warm, "trace": trace,
+           "healthy": healthy, "warmup": warm, "trace": trace,
            "backend_calls": calls.calls if calls else None,
            "memory_peak_bytes": peak}
     metrics = read_metrics(cell.per_layer if traced else cell.end_to_end,

@@ -56,11 +56,6 @@ class Served:
     def backend(self):
         return self.state.backend
 
-    def key_ready(self) -> bool:
-        """Whether the proving key of the configuration's circuit is built
-        or loaded (the first request does it)."""
-        return self.config["proving_key"] in self.state._pks
-
     def verifying_key(self) -> dict:
         """The verifying key the service checks its proofs against, as plain
         numbers (shape + commitments as affine integer pairs or None). The

@@ -69,9 +69,6 @@ class Served:
     def backend(self):
         return SimpleNamespace()
 
-    def key_ready(self):
-        return True
-
     def verifying_key(self):
         return {}
 
@@ -193,3 +190,23 @@ def test_a_wrong_answer_through_the_fake_shape_is_not_correct(bench_path,
     assert line["correct"] is False
     assert line["checks"]["proofs_rejected_by_reference"][0] \
         == line["attempted"] == line["failed"]
+
+
+def test_warmup_s_runs_from_healthy_through_the_warm_up_to_the_first_send(
+        bench_path, monkeypatch):
+    install(monkeypatch)
+    seen = {}
+    read = run.read_metrics
+
+    def keep(entries, ctx):
+        seen.update(ctx)
+        return read(entries, ctx)
+
+    monkeypatch.setattr(run, "read_metrics", keep)
+    one_run(bench_path, "cu-minimal32.serial")
+    warm, window = seen["warmup"], seen["window"]
+    assert warm.index == -1 and warm.manifest == MANIFEST
+    assert seen["healthy"] <= warm.t_send < warm.t_done <= window.t_first
+    got = cells.load_plugin("metrics", "warmup_s").read(seen)
+    assert got == window.t_first - seen["healthy"]
+    assert got >= warm.t_done - warm.t_send >= 0.03

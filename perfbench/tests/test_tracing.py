@@ -14,7 +14,7 @@ sys.path.insert(0, os.path.dirname(HERE))
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
-from harness import tracing, warmup  # noqa: E402
+from harness import tracing  # noqa: E402
 
 
 class Backend:
@@ -92,26 +92,25 @@ def test_a_method_named_for_msm_is_kind_msm():
 
 
 def test_wrappers_stack_and_unstack():
-    """The warm-up's wrapper goes on top of the traced run's and comes off
-    first; an answered call never reaches the one below."""
+    """A wrapper put on the object earlier is wrapped in turn, passes every
+    call down, and is what the object holds again after."""
     b = Backend()
     pts, one = np.ones((8, 8), np.uint64), np.ones((8, 4), np.uint64)
+    below = []
+
+    def earlier(points, scalars, base_key=None):
+        below.append(1)
+        return Backend.msm(b, points, scalars)
+
+    b.msm = earlier
     with tracing.BackendCalls(b) as calls:
-        with warmup.EachShape(b, {"msm": 1}) as shapes:
-            assert [b.msm(pts, one * i) for i in (1, 2, 3)] == [32, 32, 32]
-        assert shapes.summary() == {"msm": {"ran": 1,
-                                            "answered_from_last": 2}}
-        assert len(calls.calls) == 1
-        assert b.msm(pts, one * 2) == 64      # the lower wrapper is back
-        assert len(calls.calls) == 2
-        # a call whose inner calls were all answered still holds calls:
-        # the window's profiler must never take it for an innermost one
-        calls.reset()
-        with warmup.EachShape(b, {"msm": 0}):
-            b.msm_many(pts, [one, one])
-        assert calls.calls == []
-        assert [c.op for c in calls.composite] == ["msm_many"]
-    assert "msm" not in b.__dict__
+        assert b.msm is not earlier
+        assert [b.msm(pts, one * i) for i in (1, 2)] == [32, 64]
+    assert [c.op for c in calls.calls] == ["msm", "msm"]
+    assert below == [1, 1]
+    assert b.__dict__["msm"] is earlier
+    del b.msm
+    assert b.msm(pts, one) == 32 and "msm_many" not in b.__dict__
 
 
 def test_only_short_innermost_calls_are_kept_for_the_replay(monkeypatch):
